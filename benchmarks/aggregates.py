@@ -6,6 +6,7 @@ import numpy as np
 from repro.graphdata.ldbc import graph_name
 from repro.graphdata.queries import make_workload
 from repro.launch.query import GraniteServer
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import N_QUERIES, bench_graphs, emit, get_graph
 
@@ -34,6 +35,7 @@ def run():
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

@@ -12,6 +12,7 @@ from repro.core.ref_engine import RefEngine
 from repro.graphdata.ldbc import graph_name
 from repro.graphdata.queries import make_workload
 from repro.launch.query import GraniteServer
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import N_QUERIES, bench_graphs, emit, get_graph
 
@@ -43,6 +44,7 @@ def run():
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

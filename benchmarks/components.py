@@ -12,6 +12,7 @@ from repro.core import engine as E
 from repro.core import query as Q
 from repro.graphdata.ldbc import graph_name
 from repro.graphdata.queries import make_workload
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import bench_graphs, emit, get_graph
 
@@ -83,6 +84,7 @@ def run():
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

@@ -15,6 +15,7 @@ from repro.core.planner import Planner
 from repro.core.stats import GraphStats
 from repro.graphdata.ldbc import graph_name
 from repro.graphdata.queries import make_workload
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import N_QUERIES, bench_graphs, emit, get_graph
 
@@ -58,6 +59,7 @@ def run():
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

@@ -26,6 +26,7 @@ from repro.core.planner import fit_linear, load_coeffs, save_coeffs
 from repro.core.stats import GraphStats
 from repro.graphdata.ldbc import LdbcParams, generate_ldbc
 from repro.graphdata.queries import make_workload
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import SCALE, emit, hop_delivery_times
 
@@ -171,6 +172,7 @@ def run(write: bool = True):
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

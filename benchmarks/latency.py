@@ -12,6 +12,7 @@ from repro.core.ref_engine import RefEngine
 from repro.graphdata.ldbc import graph_name
 from repro.graphdata.queries import make_workload
 from repro.launch.query import GraniteServer
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import N_QUERIES, bench_graphs, emit, get_graph
 
@@ -61,6 +62,7 @@ def run(aggregate: bool = False):
 
 
 def main():
+    enable_compile_cache()
     run(aggregate=False)
 
 

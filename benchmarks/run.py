@@ -13,7 +13,9 @@ def main() -> None:
     from . import (aggregates, completion, components, cost_model,
                    fit_cost_model, latency, roofline_report, weak_scaling)
     from .common import ROWS
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     suites = [
         ("fit_cost_model (paper Tbl 3)", fit_cost_model.run),
         ("latency non-aggregate (paper Fig 10/11)", lambda: latency.run(False)),

@@ -92,6 +92,7 @@ from repro.launch.query import GraniteServer
 from repro.serving import (AdmissionPolicy, BatchScheduler, PlanCache,
                            TelemetryBuffer, replay_workload)
 from repro.serving.replay import poisson_arrivals
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import SCALE, emit, hop_delivery_times
 
@@ -851,6 +852,7 @@ def run(out_path: str = "BENCH_serving.json") -> dict:
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

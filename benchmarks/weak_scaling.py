@@ -36,6 +36,7 @@ import numpy as np
 from repro.core import engine_partitioned as EP
 from repro.graphdata.ldbc import LdbcParams, generate_ldbc
 from repro.graphdata.queries import make_workload, to_minmax
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import SCALE, emit
 
@@ -109,6 +110,7 @@ def run(out_path: str = "BENCH_weak_scaling.json") -> dict:
 
 
 def main():
+    enable_compile_cache()
     run()
 
 

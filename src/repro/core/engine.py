@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.hop_scatter import require_impl
 from . import intervals as iv
 from . import query as Q
 from . import superstep as SS
@@ -389,7 +390,7 @@ def _execute_plan_inner(gdev, qry, split, mode, n_buckets, params,
 _JIT_CACHE: Dict[tuple, callable] = {}
 
 #: engine-level implementation axis — the kernels' shared idiom
-#: ('xla' | 'pallas' | 'pallas_interpret'), validated by superstep.check_impl
+#: ('xla' | 'pallas' | 'pallas_interpret'), validated by hop_scatter.require_impl
 from ..kernels.common import IMPLS as HOP_IMPLS  # noqa: E402
 
 
@@ -462,7 +463,7 @@ def execute(
     if use_sliced and not ES.sliceable(qry):
         raise ValueError("query not sliceable (wildcard vertex type)")
     key = (id(graph), qry.shape_key(), split, mode, n_buckets,
-           bool(use_sliced), SS.check_impl(impl))
+           bool(use_sliced), require_impl(impl))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         if use_sliced:
@@ -549,7 +550,7 @@ def batch_executable(
     if use_sliced and not ES.sliceable(qry):
         raise ValueError("query not sliceable (wildcard vertex type)")
     key = ("batch", id(graph), qry.shape_key(), split, mode, n_buckets,
-           bool(use_sliced), SS.check_impl(impl))
+           bool(use_sliced), require_impl(impl))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         if use_sliced:
@@ -588,6 +589,7 @@ def batch_executable(
             per_vertex = full.at[:, lo:hi].set(per_vertex)
         return ExecOutput(total, per_vertex, minmax, [])
 
+    run.fn = fn   # the jitted program: fn(gdev, params, bedges)
     return run
 
 
@@ -625,7 +627,7 @@ def batch_executable_delta(
         iv.bucket_edges(graph.lifespan[0], graph.lifespan[1], n_buckets)
     )
     key = ("batch_delta", id(graph), qry.shape_key(), split, mode, n_buckets,
-           SS.check_impl(impl))
+           require_impl(impl))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         layout = hop_layout_for(graph) if SS.use_pallas(impl) else None

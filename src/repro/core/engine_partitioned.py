@@ -58,6 +58,8 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from ..kernels import hop_scatter as HK
 from . import intervals as iv
@@ -412,35 +414,19 @@ def run_segment_partitioned(
 # =========================================================================
 # shard_map wrapper (whole-plan, one dispatch)
 # =========================================================================
-def _get_shard_map():
-    try:  # moved out of experimental in newer jax
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    # the replication-check kwarg was renamed check_rep → check_vma; detect
-    # from the signature, not from where the import succeeded
-    rep_kw = ("check_vma" if "check_vma" in
-              inspect.signature(shard_map).parameters else "check_rep")
-    return shard_map, rep_kw
-
-
 def _wrap_shard_map(body, n_devices: int):
     """shard_map a whole traced plan ``body(gdev, pdev, params, bedges)``:
     the per-worker tables are sharded over the ``workers`` mesh axis, the
     graph tables/params replicated, the outputs replicated (identical on
     every device after the segment-end psum)."""
-    from jax.sharding import PartitionSpec as P
-
     from ..launch.mesh import make_worker_mesh
 
-    shard_map, rep_kw = _get_shard_map()
     mesh = make_worker_mesh(n_devices)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P("workers"), P(), P()),
         out_specs=P(),
-        **{rep_kw: False},
+        check_vma=False,
     )
 
 
@@ -487,10 +473,39 @@ def _with_hop_layouts(pdev: dict, arrays, impl: str):
     when the kernel path is selected.  The layout tensors have the worker
     axis leading, so they shard over the ``workers`` mesh axis exactly like
     the partitioner's other padded tables."""
-    if not SS.use_pallas(SS.check_impl(impl)):
+    if not SS.use_pallas(HK.require_impl(impl)):
         return pdev, 0
     tables, block_v = arrays.worker_hop_layouts()
     return {**pdev, **tables}, block_v
+
+
+def device_tables(graph: TemporalGraph, n_workers: int, impl: str = "xla",
+                  n_devices: int = 1,
+                  parts_per_type: Optional[int] = None):
+    """(graph tables, per-worker tables, PartitionArrays, hop block_v) as
+    the executor consumes them.  On one device they are the graph's cached
+    device arrays.  Over ``n_devices`` they are placed once where the
+    shard_map program reads them — per-worker tables sharded over the
+    ``workers`` mesh axis, graph tables replicated on every device — and
+    cached on the graph, so no dispatch re-sends them from device 0."""
+    gdev = _prepare_gdev(graph)
+    _, arrays, pdev = partition_for(graph, n_workers, parts_per_type)
+    pdev, hop_block_v = _with_hop_layouts(pdev, arrays, impl)
+    if n_devices > 1:
+        from ..launch.mesh import make_worker_mesh
+
+        cache = getattr(graph, "_placed_tables", None)
+        if cache is None:
+            cache = {}
+            graph._placed_tables = cache
+        key = (n_workers, parts_per_type, impl, n_devices)
+        if key not in cache:
+            mesh = make_worker_mesh(n_devices)
+            cache[key] = (
+                jax.device_put(gdev, NamedSharding(mesh, P())),
+                jax.device_put(pdev, NamedSharding(mesh, P("workers"))))
+        gdev, pdev = cache[key]
+    return gdev, pdev, arrays, hop_block_v
 
 
 def resolve_n_devices(requested: Optional[bool], n_workers: int) -> int:
@@ -550,15 +565,14 @@ def execute(
     """
     if split is None:
         split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
-    gdev = _prepare_gdev(graph)
-    _, arrays, pdev = partition_for(graph, n_workers, parts_per_type)
-    pdev, hop_block_v = _with_hop_layouts(pdev, arrays, impl)
     n_devices = resolve_n_devices(use_shard_map, n_workers)
+    gdev, pdev, arrays, hop_block_v = device_tables(
+        graph, n_workers, impl, n_devices, parts_per_type)
     bedges = jnp.asarray(
         iv.bucket_edges(graph.lifespan[0], graph.lifespan[1], n_buckets)
     )
     key = (id(graph), qry.shape_key(), split, mode, n_buckets, n_workers,
-           arrays.v_max, n_devices, SS.check_impl(impl))
+           arrays.v_max, n_devices, HK.require_impl(impl))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         fn = _plan_fn(qry, split, mode, n_buckets, n_devices, impl=impl,
@@ -629,15 +643,14 @@ def batch_executable(
     """
     if split is None:
         split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
-    gdev = _prepare_gdev(graph)
-    _, arrays, pdev = partition_for(graph, n_workers, parts_per_type)
-    pdev, hop_block_v = _with_hop_layouts(pdev, arrays, impl)
     n_devices = resolve_n_devices(use_shard_map, n_workers)
+    gdev, pdev, arrays, hop_block_v = device_tables(
+        graph, n_workers, impl, n_devices, parts_per_type)
     bedges = jnp.asarray(
         iv.bucket_edges(graph.lifespan[0], graph.lifespan[1], n_buckets)
     )
     key = ("batch", id(graph), qry.shape_key(), split, mode, n_buckets,
-           n_workers, arrays.v_max, n_devices, SS.check_impl(impl))
+           n_workers, arrays.v_max, n_devices, HK.require_impl(impl))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         fn = _plan_fn(qry, split, mode, n_buckets, n_devices, batched=True,
@@ -648,6 +661,7 @@ def batch_executable(
         total, per_vertex, minmax = fn(gdev, pdev, jnp.asarray(params), bedges)
         return ExecOutput(total, per_vertex, minmax, [])
 
+    run.fn = fn   # the jitted program: fn(gdev, pdev, params, bedges)
     return run
 
 
@@ -950,7 +964,7 @@ def measure_supersteps(
 
     fns = _profile_fns(qry, mode, n_buckets, v_max, v_preds, e_preds, pv, pe,
                        backward, want_minmax, qry.agg_op,
-                       impl=SS.check_impl(impl), hop_block_v=hop_block_v)
+                       impl=HK.require_impl(impl), hop_block_v=hop_block_v)
     vpred, hop_masks = fns["vpred"], fns["hop_masks"]
     etr_produce = fns["etr_produce"]
     ranks_w = _ranks_for_produced(gdev, pdev)

@@ -199,8 +199,13 @@ class RefEngine:
         """
         n = qry.n_vertices
         expansions = 0
-        for v0 in range(self.g.n_vertices):
-            m, val = _eval_predicate(self.vertex(v0), qry.v_preds[0].vtype, qry.v_preds[0].clauses)
+        vt0 = qry.v_preds[0].vtype
+        # a typed first predicate matches only vertices of its type: skip
+        # the rest without building their entities
+        starts = (range(self.g.n_vertices) if vt0 < 0 else
+                  np.flatnonzero(np.asarray(self.g.v_type) == vt0).tolist())
+        for v0 in starts:
+            m, val = _eval_predicate(self.vertex(v0), vt0, qry.v_preds[0].clauses)
             if not m:
                 continue
             stack = [([v0], [], val)]

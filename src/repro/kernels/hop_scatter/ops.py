@@ -28,17 +28,72 @@ Three consumers:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+import functools
+from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..bucket_scatter.ops import ScatterLayout, build_layout
-from ..common import resolve_interpret
-from .hop_scatter import scatter_cols_pallas, scatter_extremum_pallas
+from ..common import check_impl, resolve_interpret
+from .hop_scatter import (fused_hop_cols_pallas, scatter_cols_pallas,
+                          scatter_extremum_pallas)
 
 #: keys of the device-table dict the kernels consume (``HopLayout.tables``)
 TABLE_KEYS = ("gather", "valid", "ldst", "sstart", "send")
+
+#: the VMEM-shaped block the compile probe uses (a TPU deployment's shape)
+PROBE_BLOCK_V, PROBE_BLOCK_E = 256, 512
+
+
+@functools.lru_cache(maxsize=None)
+def _compile_probe(backend: str) -> Optional[str]:
+    """Compile the fused hop kernel once for ``backend``; the compiler's
+    refusal (or None when it compiles).  On the CPU the kernel runs in the
+    Pallas interpreter, so there is nothing to compile."""
+    if backend == "cpu":
+        return None
+    nb, bv, be = 2, PROBE_BLOCK_V, PROBE_BLOCK_E
+    i32, f32 = jnp.int32, jnp.float32
+    shapes = (jax.ShapeDtypeStruct((nb * bv + 1, 1), f32),   # state table
+              jax.ShapeDtypeStruct((nb, be), i32),           # src slots
+              jax.ShapeDtypeStruct((nb, be, 1), f32),        # weights
+              jax.ShapeDtypeStruct((nb, bv), i32),           # seg_start
+              jax.ShapeDtypeStruct((nb, bv), i32),           # seg_end
+              jax.ShapeDtypeStruct((nb, be), i32))           # local_dst
+    fn = functools.partial(fused_hop_cols_pallas, block_v=bv, interpret=False)
+    try:
+        jax.jit(fn).lower(*shapes).compile()
+    except Exception as e:  # what the compiler raises differs per backend
+        return f"{type(e).__name__}: {str(e).strip().splitlines()[0]}"
+    return None
+
+
+def kernel_refusal() -> Optional[str]:
+    """Why the fused hop kernel cannot run on the default backend, or None.
+
+    Decided by compiling the kernel at a VMEM-shaped block, once per
+    backend.  Serving consults it so that no fallback hides the device:
+    ``impl='auto'`` and the admission ladder offer the kernel only where
+    it compiled, and an explicit ``impl='pallas'`` raises."""
+    return _compile_probe(jax.default_backend())
+
+
+def available_impls() -> Tuple[str, ...]:
+    """The hop-delivery lowerings a planner may choose between here."""
+    return ("xla",) if kernel_refusal() is not None else ("xla", "pallas")
+
+
+def require_impl(impl: str) -> str:
+    """Validate ``impl``; ``'pallas'`` must compile on this backend."""
+    if check_impl(impl) == "pallas":
+        reason = kernel_refusal()
+        if reason is not None:
+            raise RuntimeError(
+                f"impl='pallas': the fused hop kernel does not compile on "
+                f"the {jax.default_backend()} backend: {reason}")
+    return impl
 
 
 @dataclasses.dataclass(frozen=True)

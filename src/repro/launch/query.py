@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 from typing import List, Optional
 
@@ -23,6 +24,7 @@ from ..core.ref_engine import RefEngine
 from ..core.stats import GraphStats
 from ..graphdata.ldbc import LdbcParams, generate_ldbc, graph_name
 from ..graphdata.queries import QueryInstance, make_workload
+from .compile_cache import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -33,7 +35,6 @@ class QueryResultRecord:
     count: float
     latency_ms: float
     ok: bool = True
-    error: str = ""
 
 
 class GraniteServer:
@@ -69,18 +70,13 @@ class GraniteServer:
                 ) -> QueryResultRecord:
         s = self.plan(inst) if split is None else split
         t0 = time.perf_counter()
-        try:
-            out = E.execute(self.graph, inst.qry, split=s,
-                            mode=self._mode_for(inst), n_buckets=self.n_buckets)
-            total = np.asarray(out.total)
-            count = float(total.sum()) if total.ndim else float(total)
-            dt = (time.perf_counter() - t0) * 1e3
-            ok = dt <= self.budget_s * 1e3
-            return QueryResultRecord(inst.template, s, split is None, count, dt, ok)
-        except Exception as e:  # pragma: no cover
-            dt = (time.perf_counter() - t0) * 1e3
-            return QueryResultRecord(inst.template, s, split is None, -1.0, dt,
-                                     False, str(e))
+        out = E.execute(self.graph, inst.qry, split=s,
+                        mode=self._mode_for(inst), n_buckets=self.n_buckets)
+        total = np.asarray(out.total)
+        count = float(total.sum()) if total.ndim else float(total)
+        dt = (time.perf_counter() - t0) * 1e3
+        ok = dt <= self.budget_s * 1e3
+        return QueryResultRecord(inst.template, s, split is None, count, dt, ok)
 
     def run_workload(self, workload: List[QueryInstance], verbose=False
                      ) -> List[QueryResultRecord]:
@@ -109,6 +105,18 @@ class GraniteServer:
                                budget_s=self.budget_s,
                                tracer=tracer, metrics=metrics)
         return sched.run(workload, warm=warm)
+
+
+def _exit_on_failures(failed: List[tuple], n: int) -> None:
+    """Exit non-zero when any query ended ``failed``.  The CLI injects no
+    faults, so such a query met a real error (a compiler refusal,
+    RESOURCE_EXHAUSTED, ...) that the scheduler recorded per query to keep
+    the drain going; it must not look like a successful run."""
+    if not failed:
+        return
+    for template, error in failed[:10]:
+        print(f"FAILED {template}: {error}", file=sys.stderr)
+    sys.exit(f"{len(failed)} of {n} queries failed")
 
 
 def _serve_live(args, graph, workload, tracer, metrics):
@@ -147,6 +155,8 @@ def _serve_live(args, graph, workload, tracer, metrics):
 
     def drain(tag: str):
         recs = sched.run(workload, warm=True)
+        _exit_on_failures([(r.template, r.error) for r in recs
+                           if r.status == "failed"], len(recs))
         done = sum(1 for r in recs if r.ok)
         lat = np.mean([r.latency_ms for r in recs if r.ok]) if done else 0.0
         print(f"  {tag}: fp={sched.pinned_epoch.fingerprint} "
@@ -207,6 +217,7 @@ def main():
                     help="write the metrics registry at exit (.json = JSON "
                          "snapshot, anything else = Prometheus text format)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     params = LdbcParams(n_persons=args.persons, degree_dist=args.dist,
                         dynamic=args.dynamic)
@@ -246,12 +257,16 @@ def main():
         for k, v in rep.as_dict().items():
             print(f"  {k}: {v}")
         _finish_obs()
+        _exit_on_failures([(f["template"], f["error"]) for f in rep.failures
+                           if f["status"] == "failed"], rep.n_queries)
         return
 
     if args.serve:
         recs = server.run_workload_scheduled(wl, engine=args.engine,
                                              tracer=tracer, metrics=metrics)
         _finish_obs()
+        _exit_on_failures([(r.template, r.error) for r in recs
+                           if r.status == "failed"], len(recs))
     else:
         recs = server.run_workload(wl, verbose=True)
     by_t = {}

@@ -40,6 +40,7 @@ from typing import Optional, Tuple
 
 from ..core import engine_sliced as ES
 from ..core.planner import HOP_IMPL_CHOICES
+from ..kernels.hop_scatter import available_impls
 
 ADMIT, DEGRADE, REJECT = "admit", "degrade", "reject"
 
@@ -55,7 +56,8 @@ class AdmissionPolicy:
     #: hard bound on predicted queued work (seconds); None = deadline-driven
     max_backlog_s: Optional[float] = None
     #: ladder rung 1 — sweep these impls for a cheaper lowering (fitted
-    #: per-impl θ_scatter slopes); () disables the rung
+    #: per-impl θ_scatter slopes); () disables the rung.  Only those the
+    #: backend can run (``hop_scatter.available_impls``) are offered.
     degrade_impls: Tuple[str, ...] = HOP_IMPL_CHOICES
     #: ladder rung 2 — dense→sliced downgrade when the query is sliceable
     allow_engine_downgrade: bool = True
@@ -176,8 +178,9 @@ class AdmissionController:
         best_ms = cost_ms
         rungs = []
         if pol.degrade_impls:
+            offered = available_impls()
             for cand in pol.degrade_impls:
-                if cand == impl:
+                if cand == impl or cand not in offered:
                     continue
                 c = self._cost_ms(sched, qry, engine, split, cand)
                 if c < best_ms:

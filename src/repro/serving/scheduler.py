@@ -25,6 +25,9 @@ Hop-delivery lowering: the ``impl`` knob (``HOP_IMPLS``) pins every group on
 one lowering (``'xla'`` or the fused ``'pallas'`` hop kernel), or
 ``'auto'`` lets the batch-aware planner sweep (split × impl) with the
 fitted per-impl θ_scatter slopes and dispatch each group on the winner.
+Only lowerings the backend can run are offered (``hop_scatter.
+available_impls``: the kernel where it compiles), and an explicit
+``'pallas'`` the backend's compiler refuses raises at construction.
 The chosen impl and its static hop-layout signature are part of the
 compiled-executable key (sharing a graph fingerprint is not enough — a
 kernel executable binds its block layout).
@@ -101,10 +104,11 @@ from ..core import engine as E
 from ..core import engine_partitioned as EP
 from ..core import engine_sliced as ES
 from ..core import query as Q
-from ..core.planner import HOP_IMPL_CHOICES, Planner, coeff_vector
+from ..core.planner import Planner, coeff_vector
 from ..core.stats import GraphStats
 from ..faults_common import backoff_delay
 from ..graphdata.queries import QueryInstance
+from ..kernels.hop_scatter import available_impls, require_impl
 from ..obs.trace import NULL_TRACER
 from .admission import AdmissionController, AdmissionDecision, AdmissionPolicy
 from .cache import (ExecutableCache, PlanCache, graph_fingerprint,
@@ -229,11 +233,16 @@ class BatchScheduler:
         metrics=None,
         fault_plan: Optional[FaultPlan] = None,
         retry: Optional[RetryPolicy] = None,
+        coeffs: Optional[dict] = None,
     ):
         if engine not in ENGINES:
             raise ValueError(f"engine must be one of {ENGINES}")
         if impl not in HOP_IMPLS:
             raise ValueError(f"impl must be one of {HOP_IMPLS}")
+        if impl != "auto":
+            # an explicit kernel impl the backend cannot compile fails here,
+            # with the compiler's reason, never as per-query failed rows
+            require_impl(impl)
         self.graph = graph
         self.engine = engine
         self.impl = impl
@@ -264,8 +273,11 @@ class BatchScheduler:
         self._warmed_delta = set()          # (ekey, capacity) pairs warmed
         self.plan_cache = plan_cache if plan_cache is not None else PlanCache()
         self.exec_cache = exec_cache if exec_cache is not None else ExecutableCache()
+        # cost-model θ: None = planner.load_coeffs() (host-fitted when the
+        # fit has run here); each planner gets its own copy
+        self._coeffs = coeffs
         self._stats = GraphStats(graph, n_time_buckets=n_buckets)
-        self._planner = Planner(graph, self._stats)
+        self._planner = self._new_planner(graph)
         self._planner_part: Optional[Planner] = None   # built on first use
         self._queue: List[QueueEntry] = []
         self.last_dispatches: List[GroupDispatch] = []
@@ -400,6 +412,11 @@ class BatchScheduler:
         return "sliced" if ES.sliceable(qry) else "dense"
 
     # ------------------------------------------------------------- planning
+    def _new_planner(self, graph, partitioning=None) -> Planner:
+        coeffs = None if self._coeffs is None else dict(self._coeffs)
+        return Planner(graph, self._stats, coeffs=coeffs,
+                       partitioning=partitioning)
+
     def _planner_for(self, engine: str) -> Planner:
         if engine != "partitioned":
             return self._planner
@@ -407,8 +424,7 @@ class BatchScheduler:
             # distribution-aware costs: θ_net exchange terms from the same
             # partitioning the executor will run on
             _, arrays, _ = EP.partition_for(self.graph, self.n_workers)
-            self._planner_part = Planner(self.graph, self._stats,
-                                         partitioning=arrays)
+            self._planner_part = self._new_planner(self.graph, arrays)
         return self._planner_part
 
     def _plan_key(self, bucket: tuple, mode: int, engine: str,
@@ -439,7 +455,7 @@ class BatchScheduler:
         plan = self.plan_cache.get(key)
         if plan is not None:
             return plan[0], plan[1], True, None
-        impls = HOP_IMPL_CHOICES if fixed_impl is None else (fixed_impl,)
+        impls = available_impls() if fixed_impl is None else (fixed_impl,)
         est = self._planner_for(engine).choose_batch(queries, impls=impls)
         split, impl = est.split, fixed_impl or est.impl
         self.plan_cache.put(key, (split, impl))
@@ -574,7 +590,7 @@ class BatchScheduler:
             base = epoch.base_graph if epoch.base_graph is not None else epoch.graph
             self.graph = base
             self._stats = GraphStats(base, n_time_buckets=self.n_buckets)
-            self._planner = Planner(base, self._stats)
+            self._planner = self._new_planner(base)
             self._planner_part = None
             self._est_memo.clear()
             self._warmed_delta.clear()

@@ -110,16 +110,6 @@ def make_pipelined_forward(layer_fn, n_stages: int, cfg: PipelineCfg, mesh):
     ``stacked_params`` are sharded over the pipeline axis on dim 0 (stages);
     x is replicated along the pipeline axis (each stage sees the queue).
     """
-    try:  # moved out of experimental in newer jax
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    # replication-check kwarg renamed check_rep → check_vma across jax
-    # versions; detect from the signature, not the import location
-    _rep_kw = ("check_vma" if "check_vma" in
-               inspect.signature(shard_map).parameters else "check_rep")
-
     axis = cfg.axis
 
     def inner(stage_params, x_micro):
@@ -138,12 +128,12 @@ def make_pipelined_forward(layer_fn, n_stages: int, cfg: PipelineCfg, mesh):
         pspec_tree = jax.tree_util.tree_map(
             lambda a: P(axis, *([None] * (a.ndim - 1))), staged)
         xspec = P(*([None] * x.ndim))
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(jax.tree_util.tree_map(
                 lambda a: P(axis, *([None] * (a.ndim - 1))), staged), xspec),
             out_specs=xspec,
-            **{_rep_kw: False},
+            check_vma=False,
         )(staged, x)
 
     return wrapped

@@ -65,15 +65,7 @@ def make_dp_train_step(
     axis: str = "data",
 ):
     """Explicit shard_map DP step with (optionally compressed) psum."""
-    from jax.experimental.shard_map import shard_map
-
     comp = compression or CompressionCfg(kind="none")
-
-    try:
-        from jax import shard_map as _sm  # jax >= 0.8
-        shard_map = _sm
-    except ImportError:
-        pass
 
     def step(params, opt_state, err, batch):
         def shard_fn(params, opt_state, err, batch):
@@ -88,19 +80,12 @@ def make_dp_train_step(
         ospec_rep = jax.tree_util.tree_map(lambda _: P(), opt_state)
         espec_rep = jax.tree_util.tree_map(lambda _: P(), err)
         bspec = jax.tree_util.tree_map(lambda _: P(axis), batch)
-        kw = {}
-        import inspect
-        sig = inspect.signature(shard_map)
-        if "check_vma" in sig.parameters:
-            kw["check_vma"] = False
-        else:  # pragma: no cover — older jax
-            kw["check_rep"] = False
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(pspec_rep, ospec_rep, espec_rep, bspec),
             out_specs=(pspec_rep, ospec_rep, espec_rep,
                        dict(loss=P(), lr=P(), grad_norm=P())),
-            **kw,
+            check_vma=False,
         )(params, opt_state, err, batch)
 
     return jax.jit(step)

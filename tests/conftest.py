@@ -15,13 +15,15 @@ from repro.graphdata.ldbc import LdbcParams, generate_ldbc
 
 @pytest.fixture(autouse=True, scope="module")
 def _bound_jax_jit_footprint():
-    """XLA:CPU's JIT segfaults once enough compiled executables accumulate in
-    one process (reproducible: `pytest -x -q` dies in backend_compile ~175
-    tests in, on a test that passes in isolation — jaxlib 0.4.x, CPU).  Drop
-    executable references at module boundaries so the live code footprint
-    stays bounded; within a module nothing is evicted, so steady-state
-    caching behavior (and everything the serving tests assert about cache
-    hits) is untouched."""
+    """Keep the process's compiled-code footprint bounded: one pytest process
+    compiles hundreds of XLA:CPU executables, and with an older jaxlib
+    (0.4.x) `pytest -x -q` died in backend_compile ~175 tests in, on a test
+    that passes alone.  The installed jax/jaxlib is 0.9; the bound stays
+    because a single process still accumulates every executable it ever
+    compiled.  Executable references drop at module boundaries only;
+    within a module nothing is evicted, so steady-state caching behavior
+    (and everything the serving tests assert about cache hits) is
+    untouched."""
     import jax
     jax.clear_caches()
     gc.collect()
