@@ -37,8 +37,12 @@ _HEADLINE = {
     "admit": ("verdict", "rungs"),
     "plan": ("split", "impl", "plan_cached", "predicted_ms"),
     "compile": ("cache", "key"),
-    "dispatch": ("seq", "batch", "edf_pos", "predicted_ms", "measured_ms"),
-    "superstep": ("hop", "etr", "predicted_ms", "measured_ms"),
+    "dispatch": ("seq", "batch", "edf_pos", "predicted_ms", "measured_ms",
+                 "group_measured_ms"),
+    # scheduler hops carry apportioned_ms (group time split by predicted
+    # shares); measure_supersteps' hops carry a timed measured_ms
+    "superstep": ("hop", "etr", "predicted_ms", "apportioned_ms",
+                  "measured_ms"),
     "exchange": ("state", "extremum", "etr"),
     "measure_supersteps": ("n_workers", "n_hops", "impl"),
 }

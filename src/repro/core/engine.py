@@ -44,6 +44,7 @@ is the partition-sharded entry point with identical semantics.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.hop_scatter import require_impl
+from ..obs.trace import scope
 from . import intervals as iv
 from . import query as Q
 from . import superstep as SS
@@ -127,10 +129,8 @@ def run_segment(
     fused = SS.use_pallas(impl) and layout is not None
 
     # ---- init superstep (first vertex predicate)
-    vm, vv = SS.eval_predicate(
-        gdev["vprops"], gdev["v_type"], gdev["v_life"], v_preds[0].vtype,
-        v_preds[0].clauses, params, pbases_v[0], mode, bedges,
-    )
+    vm, vv = SS.vertex_predicate(gdev, v_preds[0], params, pbases_v[0], mode,
+                                 bedges)
     state_v = SS.init_state(vm, vv, mode, n_buckets)
     stats.append(dict(phase="init", matched=jnp.sum(vm)))
 
@@ -148,10 +148,8 @@ def run_segment(
         )
         if i > 0:
             # apply the intermediate vertex predicate (post-arrival)
-            vm, vv = SS.eval_predicate(
-                gdev["vprops"], gdev["v_type"], gdev["v_life"], v_preds[i].vtype,
-                v_preds[i].clauses, params, pbases_v[i], mode, bedges,
-            )
+            vm, vv = SS.vertex_predicate(gdev, v_preds[i], params,
+                                         pbases_v[i], mode, bedges)
         if ep.etr_op != -1:
             if delta is not None:
                 raise NotImplementedError(
@@ -160,20 +158,23 @@ def run_segment(
             # intermediate vertex predicate at the source gather.
             src_cnt = SS.etr_weighted(gdev, prev_raw_e, ep.etr_op, backward,
                                       use_arr=False)
-            src_match = vm[gdev["t_src"]]
-            if mode == MODE_STATIC:
-                src_val = src_cnt * src_match.astype(jnp.float32)
-            elif mode == MODE_BUCKET:
-                src_val = src_cnt * (vm[:, None] & vv)[gdev["t_src"]].astype(jnp.float32)
-            else:
-                src_val = SS.apply_validity(src_cnt, vm[gdev["t_src"]],
-                                            vv[gdev["t_src"]], mode)
+            with scope("src_gather"):
+                t_src = gdev["t_src"]
+                if mode == MODE_STATIC:
+                    src_val = src_cnt * vm[t_src].astype(jnp.float32)
+                elif mode == MODE_BUCKET:
+                    src_val = src_cnt * (vm[:, None] & vv)[t_src].astype(
+                        jnp.float32)
+                else:
+                    src_val = SS.apply_validity(src_cnt, vm[t_src],
+                                                vv[t_src], mode)
         else:
             if i == 0:
                 sv = state_v
             else:
                 sv = SS.apply_validity(arrivals_v, vm, vv, mode)
-            src_val = sv[gdev["t_src"]]
+            with scope("src_gather"):
+                src_val = sv[gdev["t_src"]]
         cnt_e = SS.apply_edge(src_val, wmask, evalidity, mode)
         arrivals_e = cnt_e
         prev_raw_e = cnt_e
@@ -200,8 +201,9 @@ def run_segment(
             arrivals_v = SS.deliver(cnt_e, gdev["t_dst"], V, impl=impl,
                                     layout=layout)
             if with_minmax:
-                m_e = SS.minmax_edge(mch_v[gdev["t_src"]], cnt_e, minmax_op,
-                                     mode)
+                with scope("src_gather"):
+                    m_src = mch_v[gdev["t_src"]]
+                m_e = SS.minmax_edge(m_src, cnt_e, minmax_op, mode)
                 mch_v = SS.deliver_extremum(m_e, gdev["t_dst"], V, minmax_op,
                                             impl=impl, layout=layout)
         if d_add is not None:
@@ -319,12 +321,20 @@ def _execute_plan_inner(gdev, qry, split, mode, n_buckets, params,
         )
 
     stats = (left.stats if left else []) + (right.stats if right else [])
+    return _join(gdev, qry, split, mode, n_buckets, params, pv[split], bedges,
+                 left, right, stats)
 
-    # ---- join at v_split
-    vm, vv = SS.eval_predicate(
-        gdev["vprops"], gdev["v_type"], gdev["v_life"], qry.v_preds[split].vtype,
-        qry.v_preds[split].clauses, params, pv[split], mode, bedges,
-    )
+
+@scope("join")
+def _join(gdev, qry, split, mode, n_buckets, params, pbase, bedges, left,
+          right, stats) -> ExecOutput:
+    """The join at v_split: its vertex predicate, then the product of the
+    two segments' arrivals (or the ETR-at-join contraction)."""
+    n = qry.n_vertices
+    want_agg = qry.agg_op != Q.AGG_NONE
+    want_minmax = qry.agg_op in (Q.AGG_MIN, Q.AGG_MAX)
+    vm, vv = SS.vertex_predicate(gdev, qry.v_preds[split], params, pbase, mode,
+                                 bedges)
     etr_at_join = split > 0 and split < n - 1 and qry.e_preds[split].etr_op != -1
 
     def vertex_apply(av):
@@ -509,6 +519,23 @@ def count_results(graph, qry, **kw) -> float:
     return float(t.sum()) if t.ndim else float(t)
 
 
+_MODE_NAMES = {MODE_STATIC: "static", MODE_BUCKET: "bucket",
+               MODE_INTERVAL: "interval"}
+_AGG_NAMES = {Q.AGG_NONE: "paths", Q.AGG_COUNT: "count", Q.AGG_MIN: "min",
+              Q.AGG_MAX: "max"}
+
+
+def program_name(engine: str, qry: Q.PathQuery, split: int, mode: int) -> str:
+    """Stable name of one batched device program: engine, temporal mode,
+    hops, aggregate, split, ETR hops, and a CRC-32 of the query shape (the
+    same in every process, unlike ``hash``).  ``jax.jit`` names the program
+    ``jit_<name>``, so a device trace tells the templates apart."""
+    etr = "_etr" if any(e.etr_op != -1 for e in qry.e_preds) else ""
+    crc = zlib.crc32(repr(qry.shape_key()).encode()) & 0xFFFFFFFF
+    return (f"{engine}_{_MODE_NAMES[mode]}_h{len(qry.e_preds)}_"
+            f"{_AGG_NAMES[qry.agg_op]}_s{split}{etr}_{crc:08x}")
+
+
 def check_batch_shape(queries: Sequence[Q.PathQuery]) -> tuple:
     """Validate that a batch shares one template shape; returns the key."""
     assert queries, "empty batch"
@@ -571,6 +598,8 @@ def batch_executable(
                                           layout=layout)
                 return out.total, out.per_vertex, out.minmax
 
+        one.__name__ = program_name("sliced" if use_sliced else "dense", qry,
+                                    split, mode)
         fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None)))
         _JIT_CACHE[key] = fn
 
@@ -638,6 +667,7 @@ def batch_executable_delta(
                                       delta=delta)
             return out.total, out.per_vertex, out.minmax
 
+        one.__name__ = program_name("dense_delta", qry, split, mode)
         fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None, None)))
         _JIT_CACHE[key] = fn
 

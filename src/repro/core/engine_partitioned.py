@@ -65,8 +65,9 @@ from ..kernels import hop_scatter as HK
 from . import intervals as iv
 from . import query as Q
 from . import superstep as SS
+from ..obs.trace import scope
 from .engine import (ExecOutput, SegmentResult, _pbases, _prepare_gdev,
-                     execute_plan_traced)
+                     execute_plan_traced, program_name)
 from .graph import TemporalGraph
 from .superstep import MODE_BUCKET, MODE_INTERVAL, MODE_STATIC
 
@@ -113,6 +114,7 @@ def _shard_rows(global_arr, ids):
     return _zero_pad_rows(global_arr)[ids]
 
 
+@scope("src_gather")
 def _halo_gather(sv_halo, src_halo):
     """Per-edge gather from each worker's halo slice.  A zero sentinel slot
     is appended per worker so ``src_halo`` pads (= Hmax) can never alias a
@@ -346,10 +348,8 @@ def run_segment_partitioned(
     own_ids = pdev["own_ids"]
     Wl, Vmax = own_ids.shape
 
-    vm, vv = SS.eval_predicate(
-        gdev["vprops"], gdev["v_type"], gdev["v_life"], v_preds[0].vtype,
-        v_preds[0].clauses, params, pbases_v[0], mode, bedges,
-    )
+    vm, vv = SS.vertex_predicate(gdev, v_preds[0], params, pbases_v[0], mode,
+                                 bedges)
     vm_w, vv_w = _gather_vpred_w(vm, vv, own_ids)
     state = SS.init_state(vm_w, vv_w, mode, n_buckets)
     state_w = state.reshape((Wl, Vmax) + state.shape[1:])
@@ -369,11 +369,8 @@ def run_segment_partitioned(
         wmask, evalid = SS.edge_predicate_weights(
             gdev, ep, params, pbases_e[i], mode, bedges)
         if i > 0:
-            vm, vv = SS.eval_predicate(
-                gdev["vprops"], gdev["v_type"], gdev["v_life"],
-                v_preds[i].vtype, v_preds[i].clauses, params, pbases_v[i],
-                mode, bedges,
-            )
+            vm, vv = SS.vertex_predicate(gdev, v_preds[i], params,
+                                         pbases_v[i], mode, bedges)
         if ep.etr_op != -1:
             if with_minmax:
                 raise NotImplementedError(
@@ -395,19 +392,20 @@ def run_segment_partitioned(
     # publish the segment's GLOBAL views (the skeleton joins in global
     # space); under shard_map the partial scatters combine with one psum
     # (pmin/pmax for the extremum channel) — once per segment, not per hop.
-    arrivals_e = _scatter_rows(cnt_w, pdev["edge_ids"], n2e)
-    arrivals_v = _scatter_rows(arrivals_w, pdev["own_ids"], V)
-    mch_g = None
-    if mch_w is not None:
-        mch_g = _scatter_rows(mch_w, pdev["own_ids"], V,
-                              fill=SS.minmax_neutral(minmax_op))
-    if axis_name is not None:
-        arrivals_e = jax.lax.psum(arrivals_e, axis_name)
-        arrivals_v = jax.lax.psum(arrivals_v, axis_name)
-        if mch_g is not None:
-            combine = (jax.lax.pmin if minmax_op == Q.AGG_MIN
-                       else jax.lax.pmax)
-            mch_g = combine(mch_g, axis_name)
+    with scope("exchange"):
+        arrivals_e = _scatter_rows(cnt_w, pdev["edge_ids"], n2e)
+        arrivals_v = _scatter_rows(arrivals_w, pdev["own_ids"], V)
+        mch_g = None
+        if mch_w is not None:
+            mch_g = _scatter_rows(mch_w, pdev["own_ids"], V,
+                                  fill=SS.minmax_neutral(minmax_op))
+        if axis_name is not None:
+            arrivals_e = jax.lax.psum(arrivals_e, axis_name)
+            arrivals_v = jax.lax.psum(arrivals_v, axis_name)
+            if mch_g is not None:
+                combine = (jax.lax.pmin if minmax_op == Q.AGG_MIN
+                           else jax.lax.pmax)
+                mch_g = combine(mch_g, axis_name)
     return SegmentResult(arrivals_e, arrivals_v, stats, mch_g)
 
 
@@ -535,7 +533,11 @@ def _plan_fn(qry, split, mode, n_buckets, n_devices, batched: bool = False,
         return out.total, out.per_vertex, out.minmax
 
     axis = None if n_devices <= 1 else "workers"
-    body = lambda gd, pd, p, be: plan(gd, pd, p, be, axis)
+
+    def body(gd, pd, p, be):
+        return plan(gd, pd, p, be, axis)
+
+    body.__name__ = program_name("partitioned", qry, split, mode)
     if batched:
         body = jax.vmap(body, in_axes=(None, None, 0, None))
     if n_devices <= 1:
@@ -733,10 +735,8 @@ def _profile_fns(qry: Q.PathQuery, mode: int, n_buckets: int, v_max: int,
     def vpred(i):
         def f(gd, prm, be):
             with SS.bucket_scope(be):
-                vp = v_preds[i]
-                return SS.eval_predicate(gd["vprops"], gd["v_type"],
-                                         gd["v_life"], vp.vtype, vp.clauses,
-                                         prm, pv[i], mode, be)
+                return SS.vertex_predicate(gd, v_preds[i], prm, pv[i], mode,
+                                           be)
         return jax.jit(f)
 
     def hop_masks(i):

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import scope
 from . import query as Q
 from . import superstep as SS
 from .engine import ExecOutput, _pbases
@@ -92,6 +93,7 @@ def slice_layouts_for(graph: TemporalGraph, qry: Q.PathQuery,
     return layouts
 
 
+@scope("vertex_pred")
 def _vertex_eval_sliced(gdev, vp, params, pbase, mode, bedges, vb):
     lo, hi = vb
     props = {k: (v[0][lo:hi], v[1][lo:hi]) for k, v in gdev["vprops"].items()}
@@ -101,6 +103,7 @@ def _vertex_eval_sliced(gdev, vp, params, pbase, mode, bedges, vb):
     )
 
 
+@scope("edge_pred")
 def _edge_eval_sliced(gdev, ep, params, pbase, mode, bedges, eb):
     lo, hi = eb
     eprops = {k: (v[0][lo:hi], v[1][lo:hi]) for k, v in gdev["eprops_t"].items()}
@@ -119,6 +122,7 @@ def _edge_eval_sliced(gdev, ep, params, pbase, mode, bedges, eb):
     return (match & dmask), validity
 
 
+@scope("etr_prefix")
 def _etr_weighted_sliced(gdev, cnt_prev, op, backward, use_arr,
                          prev_eb, cur_eb, prev_vb):
     """ETR prefix over the previous arrival slice, gathered for the current

@@ -58,6 +58,14 @@ steps shard trivially, the segment steps define the communication pattern
 (and, because arrival segments never straddle workers, they all decompose
 into per-worker segment ops + a boundary exchange).
 
+Predicate evaluation (``vertex_predicate``), edge masking, delivery, the
+extremum channel, the ETR machinery, delta delivery and the exchange each
+run under a ``jax.named_scope`` from ``obs.trace.DEVICE_SCOPES``
+(``vertex_pred``, ``edge_pred``, ``hop_deliver``, ``etr_prefix``,
+``delta_deliver``, ``exchange``; the executors add ``src_gather`` and
+``join``), so a device trace names the phase of each operation.  Where
+scopes nest, the outermost names the phase.
+
 Bucket edges are threaded through traces with the ``bucket_scope`` context
 manager (a trace-scoped stack, not a function argument, so deeply nested
 helpers stay signature-stable).
@@ -75,6 +83,7 @@ from . import intervals as iv
 from . import query as Q
 from ..kernels import hop_scatter as HK
 from ..kernels.common import check_impl, resolve_interpret, use_pallas
+from ..obs.trace import scope
 
 MODE_STATIC = 0
 MODE_BUCKET = 1
@@ -231,6 +240,14 @@ def eval_predicate(
     return match, validity
 
 
+@scope("vertex_pred")
+def vertex_predicate(gdev, vp: Q.VertexPredicate, params, pbase, mode,
+                     bedges):
+    """(match, validity) of one vertex predicate over every vertex."""
+    return eval_predicate(gdev["vprops"], gdev["v_type"], gdev["v_life"],
+                          vp.vtype, vp.clauses, params, pbase, mode, bedges)
+
+
 # =========================================================================
 # edge masking
 # =========================================================================
@@ -243,6 +260,7 @@ def direction_mask(t_isfwd, direction: int):
     return jnp.ones_like(t_isfwd, bool)
 
 
+@scope("edge_pred")
 def edge_predicate_weights(gdev, ep: Q.EdgePredicate, params, pbase, mode, bedges):
     """(weight mask bool[2E], bucket/interval validity) for one hop."""
     t_life = gdev["t_life"]
@@ -366,6 +384,7 @@ def cells_to_buckets(state):
 # =========================================================================
 # point-to-point boundary exchange (the distributed executor's collective)
 # =========================================================================
+@scope("exchange")
 def p2p_exchange(rows_w, local_src, send_slot, recv_slot, n_slots: int,
                  axis_name: Optional[str] = None, fill=0.0):
     """Ragged all-to-all over the worker axis — the boundary exchange.
@@ -425,6 +444,7 @@ def p2p_exchange(rows_w, local_src, send_slot, recv_slot, n_slots: int,
 # =========================================================================
 # delivery
 # =========================================================================
+@scope("hop_deliver")
 def deliver(cnt_e, seg_ids, num_segments: int, indices_are_sorted: bool = True,
             impl: str = "xla", layout=None):
     """Sorted segment-sum of per-edge counts by arrival vertex — the message
@@ -446,6 +466,7 @@ def deliver(cnt_e, seg_ids, num_segments: int, indices_are_sorted: bool = True,
                               layout.block_v, impl=impl)
 
 
+@scope("hop_deliver")
 def fused_hop_deliver(
     state,                       # [N, *TS] source-state table
     src_slot,                    # int32[E] — source row per edge; N = zero row
@@ -550,12 +571,14 @@ def minmax_seed(state, col_vals, op: int, mode: int):
     return jnp.where(state_alive(state, mode), base, minmax_neutral(op))
 
 
+@scope("hop_deliver")
 def minmax_edge(mch_src, cnt_e, op: int, mode: int):
     """Per-edge extremum message: the source channel where the edge carries
     any live count, neutral elsewhere (so dead/pad edges cannot win)."""
     return jnp.where(state_alive(cnt_e, mode), mch_src, minmax_neutral(op))
 
 
+@scope("hop_deliver")
 def deliver_extremum(m_e, seg_ids, num_segments: int, op: int,
                      indices_are_sorted: bool = True, impl: str = "xla",
                      layout=None):
@@ -579,6 +602,7 @@ def deliver_extremum(m_e, seg_ids, num_segments: int, op: int,
 # =========================================================================
 # delta-segment delivery (base-CSR + delta execution, graphdata/ingest.py)
 # =========================================================================
+@scope("delta_deliver")
 def delta_hop_deliver(delta, ep, sv, params, pbase, mode: int, V: int,
                       mch=None, minmax_op=Q.AGG_MIN):
     """One hop's arrival contribution from a padded delta-edge segment.
@@ -614,6 +638,7 @@ def delta_hop_deliver(delta, ep, sv, params, pbase, mode: int, V: int,
 # =========================================================================
 # ETR prefix machinery
 # =========================================================================
+@scope("etr_prefix")
 def etr_weighted(gdev, cnt_e_prev, op: int, backward: bool, use_arr: bool):
     """Per current traversal edge: Σ over accumulated arrivals at its vertex
     of cnt × [ETR condition], via rank tables (exact)."""
@@ -654,6 +679,7 @@ def etr_needs_end(op: int, backward: bool) -> bool:
     return any(t == 3 for _, t in terms)
 
 
+@scope("etr_prefix")
 def etr_local_summaries(cnt_perm_s, cnt_perm_e, base, seg_len, ranks,
                         op: int, backward: bool):
     """Per-edge ETR rank summaries from SEGMENT-LOCAL prefix tables.

@@ -9,8 +9,11 @@ Every query the serving runtime touches leaves one span TREE:
        │                        features·θ (the cost model's commitment)
        ├─ compile               executable-cache hit/miss + dispatch key
        └─ dispatch              group seq, batch size, EDF position,
-          │                     predicted vs measured ms (query and group)
-          └─ superstep (×hop)   per-hop predicted/measured share
+          │                     predicted ms, the group's measured ms, its
+          │                     measured launch/ready stamps
+          └─ superstep (×hop)   per-hop predicted ms and ``apportioned_ms``:
+                                the group's measured time split by predicted
+                                shares (no hop is timed on its own)
              └─ exchange        per-channel structural boundary volumes
                                 (state / extremum / etr — the same rule as
                                 engine_partitioned.query_exchange_volumes)
@@ -31,6 +34,20 @@ Design constraints, in order:
                 floats serialise via repr round-trip, so an offline audit
                 (obs/audit.py) recomputes EXACTLY what the live telemetry
                 saw.
+
+The same module names what lands on the PROFILER's clock, so a device trace
+(``jax.profiler.trace``) can be read by phase:
+
+  SCHED_PHASES   host spans the scheduler opens with ``phase(name)`` (a
+                 ``jax.profiler.TraceAnnotation``, always on: about a
+                 microsecond each with no profiler running) around each step
+                 of ``flush`` — grouping, planning, plan tensors, launch,
+                 waiting on the device, fetching answers, warm-up and
+                 deferred span building;
+  DEVICE_SCOPES  ``jax.named_scope`` names the executors put on the device
+                 program's operations (op_name metadata), one per superstep
+                 phase; each batch program is named after its query shape
+                 (``engine.program_name``) instead of one shared name.
 """
 from __future__ import annotations
 
@@ -40,7 +57,51 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+#: the scheduler's host spans on the profiler's clock, outermost first
+SCHED_PHASES = (
+    "sched.flush",        # the whole flush
+    "sched.group",        # grouping and earliest-deadline-first ordering
+    "sched.plan",         # split/impl choice (plan cache or planner sweep)
+    "sched.plan_tensor",  # stacking the group's parameter rows
+    "sched.launch",       # executable key, cache lookup, the call's return
+    "sched.device_wait",  # block_until_ready on the answers
+    "sched.fetch",        # answers to the host, ServedResults built
+    "sched.warm",         # the untimed first run of a new executable
+    "sched.trace_build",  # deferred flight-recorder spans (Tracer attached)
+)
+
+#: ``jax.named_scope`` names on the device program's operations
+DEVICE_SCOPES = (
+    "vertex_pred",    # vertex predicate evaluation
+    "edge_pred",      # per-edge predicate weights
+    "src_gather",     # source state gathered onto traversal edges
+    "hop_deliver",    # segment delivery to arrival vertices (and extremum)
+    "etr_prefix",     # ETR rank tables and segment prefix sums
+    "join",           # the split join, ETR-at-join included
+    "delta_deliver",  # base+delta: the delta edges' delivery
+    "exchange",       # partitioned boundary exchange and segment publish
+)
+
+
+def phase(name: str) -> TraceAnnotation:
+    """Host span ``name`` (one of ``SCHED_PHASES``) on the profiler's clock:
+    ``with phase("sched.plan"): ...``."""
+    if name not in SCHED_PHASES:
+        raise ValueError(f"unknown scheduler phase {name!r}")
+    return TraceAnnotation(name)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of ``DEVICE_SCOPES``; usable as a
+    context manager or a decorator (metadata only: fusion is unchanged)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"unknown device scope {name!r}")
+    return jax.named_scope(name)
 
 
 def _json_default(o):

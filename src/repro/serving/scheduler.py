@@ -78,17 +78,48 @@ and the planner marks the partitioned path unavailable until a probe
 succeeds).  Without a ``retry`` policy the historical behaviour is
 unchanged: one exception marks the whole unit failed.
 
-Observability (repro.obs): with a ``tracer`` attached every submitted query
-leaves one span tree — query → admit → plan → compile → dispatch →
-superstep (per hop) → exchange (per channel) — carrying the admission
-verdict/rungs, the plan's candidate sweep, cache hits, EDF position, and
-predicted-vs-measured ms at query, group, and hop granularity; a
-``metrics`` registry mirrors the counters (admission verdicts, cache
+Observability (repro.obs), in three layers:
+
+  profiler spans  every flush opens ``obs.trace.phase`` spans
+                  (``jax.profiler.TraceAnnotation``, always on, about a
+                  microsecond each): ``sched.flush`` around it all,
+                  ``sched.group``, and per unit ``sched.plan``,
+                  ``sched.plan_tensor``, ``sched.launch`` (executable key,
+                  cache lookup, the call), ``sched.warm``,
+                  ``sched.device_wait`` and ``sched.fetch``, then
+                  ``sched.trace_build``.  Under ``jax.profiler.trace`` they
+                  land on the device trace's clock, so an idle gap on the
+                  chip can be put down to the host work that held it; the
+                  device programs carry per-shape names and
+                  ``obs.trace.DEVICE_SCOPES`` scopes on their operations;
+  stamps          each ``GroupDispatch`` carries ``t_start``, ``t_launch``,
+                  ``t_ready`` and ``t_end`` on the injected ``clock``
+                  (``t_ready - t_launch`` is the raw dispatch time), so a
+                  caller can split an answer's wait into its own dispatch,
+                  the other units of its flush, and the host work between;
+  flight recorder with a ``tracer`` attached every submitted query leaves
+                  one span tree — query → admit → plan → compile → dispatch
+                  → superstep (per hop) → exchange (per channel) — carrying
+                  the admission verdict/rungs, the plan's candidate sweep,
+                  cache hits, EDF position, predicted ms, the group's
+                  measured ms and stamps, and per hop the group time
+                  apportioned by predicted shares (``apportioned_ms``).
+
+A ``metrics`` registry mirrors the counters (admission verdicts, cache
 events, refits, dispatch latency histogram, queue depth).  The default
-``NULL_TRACER`` makes the disabled path a no-op attribute lookup (overhead
-gated by benchmarks/serving.py + scripts/check_bench.py), and all timing
-flows through the injected ``clock``, so under the FakeDispatcher virtual
-clock the exact span tree is deterministic.
+``NULL_TRACER`` makes the disabled recorder a no-op attribute lookup
+(overhead gated by benchmarks/serving.py + scripts/check_bench.py), and all
+timing flows through the injected ``clock``, so under the FakeDispatcher
+virtual clock the exact span tree and stamps are deterministic.
+
+To capture a device profile around serving::
+
+    with jax.profiler.trace("/tmp/granite-trace"):
+        sched.run(workload)
+
+and read it with ``jax.profiler.ProfileData`` or TensorBoard/Perfetto:
+host planes hold the ``sched.*`` spans, the TPU plane's "XLA Ops" line the
+device operations, whose op names carry the scope names.
 """
 from __future__ import annotations
 
@@ -109,7 +140,7 @@ from ..core.stats import GraphStats
 from ..faults_common import backoff_delay
 from ..graphdata.queries import QueryInstance
 from ..kernels.hop_scatter import available_impls, require_impl
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import NULL_TRACER, phase
 from .admission import AdmissionController, AdmissionDecision, AdmissionPolicy
 from .cache import (ExecutableCache, PlanCache, graph_fingerprint,
                     layout_signature)
@@ -174,6 +205,14 @@ class GroupDispatch:
     n_retries: int = 0           # backoff retries the unit burned
     fallback_from: str = ""      # engine the unit was re-planned away from
     penalty_s: float = 0.0       # accounted retry backoff inside service_s
+    # stamps on the scheduler's clock (NaN: not stamped).  t_ready -
+    # t_launch is the raw dispatch time: measured around the call and
+    # block_until_ready, or t_launch plus an injected dispatcher's (or a
+    # straggler fault's) accounted service time
+    t_start: float = math.nan    # the unit begins, before planning
+    t_launch: float = math.nan   # just before the executable is called
+    t_ready: float = math.nan    # its answers are ready on the device
+    t_end: float = math.nan      # the unit's answers are stored
 
 
 class BatchScheduler:
@@ -305,6 +344,7 @@ class BatchScheduler:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics
         self._dispatch_seq = 0
+        self._stamps = (math.nan, math.nan)   # last dispatch's launch, ready
         # per-query PlanEstimate memo: features are θ-INDEPENDENT structural
         # sums (GraphStats), so entries survive online refits — predictions
         # are recomputed as features @ live θ at use time
@@ -492,48 +532,55 @@ class BatchScheduler:
         every epoch of the window — the scheduler only re-warms when the
         padded delta capacity grows (a jit retrace inside the same entry).
         """
-        use_delta = self._delta_eligible(queries[0], engine)
-        self._last_used_delta = use_delta
-        fp = ("delta", self._base_fp) if use_delta else self.fingerprint
-        lay_graph = self.graph if use_delta else self._serve_graph
-        ekey = (engine, fp, bucket, split, mode,
-                self.n_buckets,
-                self.n_workers if engine == "partitioned" else 0,
-                self.n_devices if engine == "partitioned" else 0,
-                impl,
-                layout_signature(lay_graph, engine, queries[0],
-                                 self.n_workers, impl),
-                pt.params.shape[0])
-        exec_cached = ekey in self.exec_cache
-        if use_delta:
-            run0 = self.exec_cache.get_or_build(
-                ekey, lambda: E.batch_executable_delta(
-                    self.graph, queries[0], split, mode, self.n_buckets,
-                    impl=impl))
-            delta = self._delta
-            run = lambda params: run0(params, delta)  # noqa: E731
-            # a cached delta executable still retraces when the padded
-            # capacity grows — warm per (key, capacity), not per key
-            warm_needed = (ekey, self._delta_capacity) not in self._warmed_delta
+        with phase("sched.launch"):
+            use_delta = self._delta_eligible(queries[0], engine)
+            self._last_used_delta = use_delta
+            fp = ("delta", self._base_fp) if use_delta else self.fingerprint
+            lay_graph = self.graph if use_delta else self._serve_graph
+            ekey = (engine, fp, bucket, split, mode,
+                    self.n_buckets,
+                    self.n_workers if engine == "partitioned" else 0,
+                    self.n_devices if engine == "partitioned" else 0,
+                    impl,
+                    layout_signature(lay_graph, engine, queries[0],
+                                     self.n_workers, impl),
+                    pt.params.shape[0])
+            exec_cached = ekey in self.exec_cache
+            if use_delta:
+                run0 = self.exec_cache.get_or_build(
+                    ekey, lambda: E.batch_executable_delta(
+                        self.graph, queries[0], split, mode, self.n_buckets,
+                        impl=impl))
+                delta = self._delta
+                run = lambda params: run0(params, delta)  # noqa: E731
+                # a cached delta executable still retraces when the padded
+                # capacity grows — warm per (key, capacity), not per key
+                warm_needed = ((ekey, self._delta_capacity)
+                               not in self._warmed_delta)
+                if warm and warm_needed:
+                    self._warmed_delta.add((ekey, self._delta_capacity))
+            else:
+                run = self.exec_cache.get_or_build(
+                    ekey, lambda: self._build_executable(queries[0], split,
+                                                         mode, engine, impl))
+                warm_needed = not exec_cached
             if warm and warm_needed:
-                self._warmed_delta.add((ekey, self._delta_capacity))
-        else:
-            run = self.exec_cache.get_or_build(
-                ekey, lambda: self._build_executable(queries[0], split,
-                                                     mode, engine, impl))
-            warm_needed = not exec_cached
-        if warm and warm_needed:
-            # first dispatch at this key: run once untimed so compile
-            # stays out of latency (a cache-hit executable has already
-            # been traced and run at this key)
-            jax.block_until_ready(run(pt.params).total)
-        # timing goes through the INJECTED clock (default time.perf_counter)
-        # so dispatch durations — and with them telemetry rows and trace
-        # spans — are deterministic under a test-injected step clock
-        t0 = self._clock()
-        res = run(pt.params)
-        jax.block_until_ready(res.total)
-        return res, self._clock() - t0, exec_cached
+                # first dispatch at this key: run once untimed so compile
+                # stays out of latency (a cache-hit executable has already
+                # been traced and run at this key)
+                with phase("sched.warm"):
+                    jax.block_until_ready(run(pt.params).total)
+            # timing goes through the INJECTED clock (default
+            # time.perf_counter) so dispatch durations — and with them
+            # telemetry rows and trace spans — are deterministic under a
+            # test-injected step clock
+            t0 = self._clock()
+            res = run(pt.params)
+        with phase("sched.device_wait"):
+            jax.block_until_ready(res.total)
+        t1 = self._clock()
+        self._stamps = (t0, t1)
+        return res, t1 - t0, exec_cached
 
     def _dispatch(self, queries: List[Q.PathQuery], split: int, mode: int,
                   engine: str, impl: str, bucket: tuple, pt, warm: bool):
@@ -563,14 +610,21 @@ class BatchScheduler:
                 raise TransientDispatchError(
                     "injected transient dispatch error")
         if self.dispatcher is not None:
+            t_launch = self._clock()
             res, dt = self.dispatcher.dispatch(
                 self, queries, split, mode, engine, impl, pt, warm)
             exec_cached = True
+            t_ready = t_launch + dt      # the dispatcher's accounted time
         else:
             res, dt, exec_cached = self._dispatch_jax(
                 queries, split, mode, engine, impl, bucket, pt, warm)
+            t_launch, t_ready = self._stamps
         if plan is not None:
-            dt *= plan.straggle()
+            factor = plan.straggle()
+            if factor != 1.0:
+                dt *= factor
+                t_ready = t_launch + dt  # accounted, not slept
+        self._stamps = (t_launch, t_ready)
         return res, dt, exec_cached
 
     # ------------------------------------------------------------ epochs
@@ -663,15 +717,18 @@ class BatchScheduler:
 
     def _trace_group(self, queue, idxs, ests, feats, split, engine, impl,
                      pt, dt, plan_cached, exec_cached, candidates, seq,
-                     edf_pos, group_deadline, predicted_ms, out):
+                     edf_pos, group_deadline, predicted_ms, t_launch,
+                     t_ready, out):
         """Emit one dispatched group's span set: for EVERY member query a
         plan → compile → dispatch → superstep (per hop) → exchange chain
         under its root, so each query's tree is complete on its own.
         Group-shared quantities (the telemetry row: batch-summed features,
-        group predicted/measured ms) repeat on each member's dispatch span
-        keyed by ``seq`` — obs/audit dedupes them back to one row per
-        dispatch.  Measured group time is apportioned to members (and to
-        hops within a member) by predicted fractions."""
+        group predicted/measured ms, the measured launch/ready stamps)
+        repeat on each member's dispatch span keyed by ``seq`` — obs/audit
+        dedupes them back to one row per dispatch.  Measured group time is
+        apportioned to members, and to hops within a member (the superstep
+        span's ``apportioned_ms``: no hop is timed), by predicted
+        fractions."""
         tr = self.tracer
         theta = coeff_vector(self._planner_for(engine).coeffs)
         group_pred = (predicted_ms if self.telemetry is not None
@@ -716,7 +773,8 @@ class BatchScheduler:
                           else group_deadline),
                 predicted_ms=q_preds[j], measured_ms=q_meas,
                 features=est.features, group_features=feats,
-                group_predicted_ms=group_pred, group_measured_ms=group_ms)
+                group_predicted_ms=group_pred, group_measured_ms=group_ms,
+                t_launch=t_launch, t_ready=t_ready)
             hop_steps = [s for s in est.steps if s.channels is not None]
             hop_preds = [float(s.features @ theta) for s in hop_steps]
             hp_sum = sum(hop_preds)
@@ -725,7 +783,7 @@ class BatchScheduler:
                           else 1.0 / len(hop_steps))
                 ss = tr.start("superstep", parent=disp, hop=h, etr=s.etr,
                               predicted_ms=hop_preds[h],
-                              measured_ms=q_meas * hshare)
+                              apportioned_ms=q_meas * hshare)
                 ex = tr.start("exchange", parent=ss, hop=h,
                               state=s.channels[0],
                               extremum=s.channels[1], etr=s.channels[2])
@@ -743,6 +801,10 @@ class BatchScheduler:
         arrival order is preserved); results return in submission order.
         ``warm=True`` runs each executable once untimed first (compile
         excluded from latency, as the paper excludes load time)."""
+        with phase("sched.flush"):
+            return self._flush(warm)
+
+    def _flush(self, warm: bool) -> List[ServedResult]:
         queue, self._queue = self._queue, []
         if self.admission is not None:
             self.admission.on_flush()
@@ -751,6 +813,33 @@ class BatchScheduler:
         if not queue:
             self.last_dispatches = []
             return []
+        with phase("sched.group"):
+            units = self._units(queue)
+
+        out: List[Optional[ServedResult]] = [None] * len(queue)
+        dispatches: List[GroupDispatch] = []
+        traced_groups: List[tuple] = []
+        self._flush_count += 1
+        # the retry state machine runs on the flush's VIRTUAL now: arrival
+        # frame (what submit's ``now`` used) + accounted service so far —
+        # deadline-aware retry budgets compare in the deadline's own frame
+        flush_now = max((e.arrival for e in queue), default=0.0)
+        retry_rng = self.retry.rng() if self.retry is not None else None
+        for edf_pos, (group_deadline, _, key, idxs) in enumerate(units):
+            self._serve_unit(queue, out, key, list(idxs), warm, edf_pos,
+                             group_deadline, dispatches, traced_groups,
+                             flush_now, retry_rng)
+        if traced_groups:
+            with phase("sched.trace_build"):
+                for grp in traced_groups:
+                    self._trace_group(queue, *grp, out)
+        self.last_dispatches = dispatches
+        self.n_dispatched += len(queue)
+        return out  # type: ignore[return-value]
+
+    def _units(self, queue: List[QueueEntry]) -> List[tuple]:
+        """Group the queue, then order the dispatch units: (deadline, seq,
+        group key, queue positions), earliest deadline first."""
         groups: Dict[tuple, List[int]] = {}
         for i, entry in enumerate(queue):
             qry = entry.inst.qry
@@ -775,25 +864,7 @@ class BatchScheduler:
                               key, chunk))
                 seq += 1
         units.sort(key=lambda u: (u[0], u[1]))
-
-        out: List[Optional[ServedResult]] = [None] * len(queue)
-        dispatches: List[GroupDispatch] = []
-        traced_groups: List[tuple] = []
-        self._flush_count += 1
-        # the retry state machine runs on the flush's VIRTUAL now: arrival
-        # frame (what submit's ``now`` used) + accounted service so far —
-        # deadline-aware retry budgets compare in the deadline's own frame
-        flush_now = max((e.arrival for e in queue), default=0.0)
-        retry_rng = self.retry.rng() if self.retry is not None else None
-        for edf_pos, (group_deadline, _, key, idxs) in enumerate(units):
-            self._serve_unit(queue, out, key, list(idxs), warm, edf_pos,
-                             group_deadline, dispatches, traced_groups,
-                             flush_now, retry_rng)
-        for grp in traced_groups:
-            self._trace_group(queue, *grp, out)
-        self.last_dispatches = dispatches
-        self.n_dispatched += len(queue)
-        return out  # type: ignore[return-value]
+        return units
 
     # ------------------------------------------------------- fault handling
     def _mark_unit(self, queue, out, idxs, engine: str, err,
@@ -844,6 +915,7 @@ class BatchScheduler:
         """Serve one EDF dispatch unit through the retry/quarantine state
         machine (the historical one-attempt behaviour when no ``retry``
         policy is attached)."""
+        t_start = self._clock()
         bucket, mode, engine, impl_over = key
         fallback_from = ""
         # partitioned-path availability: while the planner holds the path
@@ -865,9 +937,12 @@ class BatchScheduler:
         readmitted = False
         while True:
             try:
-                split, impl, plan_cached, candidates = self._plan_group(
-                    queries, bucket, mode, engine, impl_override=impl_over)
-                pt = compile_plan_tensor(queries, pad=self.pad_batches)
+                with phase("sched.plan"):
+                    split, impl, plan_cached, candidates = self._plan_group(
+                        queries, bucket, mode, engine,
+                        impl_override=impl_over)
+                with phase("sched.plan_tensor"):
+                    pt = compile_plan_tensor(queries, pad=self.pad_batches)
                 res, dt_raw, exec_cached = self._dispatch(
                     queries, split, mode, engine, impl, bucket, pt, warm)
                 break
@@ -953,6 +1028,7 @@ class BatchScheduler:
                 # must not take the rest of the flush with it
                 self._mark_unit(queue, out, idxs, engine, e, "failed")
                 return
+        t_launch, t_ready = self._stamps
         if (engine == "partitioned"
                 and not self._planner.engine_available("partitioned")):
             self._planner.mark_available("partitioned")  # probe succeeded
@@ -979,22 +1055,25 @@ class BatchScheduler:
         per_query_ms = dt_total * 1e3 / pt.n_real
         ok = per_query_ms <= self.budget_s * 1e3
 
-        total = np.asarray(res.total)
-        pv = None if res.per_vertex is None else np.asarray(res.per_vertex)
-        mm = None if res.minmax is None else np.asarray(res.minmax)
-        for j, i in enumerate(idxs):
-            t_j = total[j]
-            out[i] = ServedResult(
-                template=insts[j].template, engine=engine, split=split,
-                count=float(t_j.sum()) if t_j.ndim else float(t_j),
-                latency_ms=per_query_ms, ok=ok, batch_size=pt.n_real,
-                total=t_j if self.keep_outputs else None,
-                per_vertex=(pv[j] if self.keep_outputs and pv is not None
+        with phase("sched.fetch"):
+            total = np.asarray(res.total)
+            pv = (None if res.per_vertex is None
+                  else np.asarray(res.per_vertex))
+            mm = None if res.minmax is None else np.asarray(res.minmax)
+            for j, i in enumerate(idxs):
+                t_j = total[j]
+                out[i] = ServedResult(
+                    template=insts[j].template, engine=engine, split=split,
+                    count=float(t_j.sum()) if t_j.ndim else float(t_j),
+                    latency_ms=per_query_ms, ok=ok, batch_size=pt.n_real,
+                    total=t_j if self.keep_outputs else None,
+                    per_vertex=(pv[j] if self.keep_outputs and pv is not None
+                                else None),
+                    minmax=(mm[j] if self.keep_outputs and mm is not None
                             else None),
-                minmax=(mm[j] if self.keep_outputs and mm is not None
-                        else None),
-                deadline=queue[i].deadline,
-            )
+                    deadline=queue[i].deadline,
+                )
+        t_end = self._clock()
         if self.tracer.enabled:
             # span construction is DEFERRED to after the dispatch loop:
             # building hundreds of record dicts between two ~ms timed
@@ -1003,12 +1082,14 @@ class BatchScheduler:
             traced_groups.append(
                 (idxs, ests, feats, split, engine, impl, pt, dt_raw,
                  plan_cached, exec_cached, candidates, seq, edf_pos,
-                 group_deadline, predicted_ms))
+                 group_deadline, predicted_ms, t_launch, t_ready))
         dispatches.append(GroupDispatch(
             key, engine, split, pt.n_real, pt.n_pad, dt_total, list(idxs),
             plan_cached, exec_cached, impl, group_deadline, predicted_ms,
             delta=self._last_used_delta, n_retries=n_retries,
-            fallback_from=fallback_from, penalty_s=penalty_s))
+            fallback_from=fallback_from, penalty_s=penalty_s,
+            t_start=t_start, t_launch=t_launch, t_ready=t_ready,
+            t_end=t_end))
 
     def run(self, workload: Sequence[Union[QueryInstance, Q.PathQuery]],
             warm: bool = False) -> List[ServedResult]:

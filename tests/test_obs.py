@@ -217,7 +217,7 @@ def test_every_query_gets_one_complete_span_tree(medium_static_graph):
         a = d[0]["attrs"]
         for k in ("seq", "batch", "edf_pos", "predicted_ms", "measured_ms",
                   "group_features", "group_predicted_ms",
-                  "group_measured_ms"):
+                  "group_measured_ms", "t_launch", "t_ready"):
             assert a.get(k) is not None, k
         assert a["predicted_ms"] > 0 and a["measured_ms"] > 0
     assert n_rej == rep.n_rejected and n_done == rep.n_completed
@@ -264,9 +264,15 @@ def test_span_tree_pinned_exactly_on_virtual_clock(medium_static_graph):
     a = recs[4]["attrs"]
     assert a["measured_ms"] == a["group_measured_ms"] == 1.0
     assert a["batch"] == 1 and a["edf_pos"] == 0 and a["seq"] == 0
-    # hop shares sum back to the query's measured time exactly
-    hops = [recs[5 + 2 * h]["attrs"]["measured_ms"] for h in range(n_hops)]
+    # the group's launch/ready stamps ride on the dispatch span
+    assert a["t_ready"] - a["t_launch"] == pytest.approx(1e-3)
+    # hop shares sum back to the query's measured time exactly; they are
+    # apportioned, never reported as measured
+    hops = [recs[5 + 2 * h]["attrs"]["apportioned_ms"]
+            for h in range(n_hops)]
     assert sum(hops) == pytest.approx(1.0)
+    assert all("measured_ms" not in recs[5 + 2 * h]["attrs"]
+               for h in range(n_hops))
 
 
 def test_failed_group_seals_root_spans(medium_static_graph):
@@ -681,3 +687,151 @@ def test_traced_results_bit_identical_real_dispatch(small_static_graph):
             assert np.array_equal(a.total, b.total)
         roots = [r for r in tr.records() if r["name"] == "query"]
         assert len(roots) == len(wl)
+
+
+# ======================================== profiler spans, stamps, scopes
+@pytest.mark.parametrize("fault", [None, "straggler"])
+def test_dispatch_stamps_on_virtual_clock(medium_static_graph, fault):
+    """FakeDispatcher + StepClock: each unit's stamps are ordered, units
+    follow each other, and t_ready - t_launch is the raw dispatch time, a
+    straggler's accounted inflation included.  Service times are dyadic
+    and the clock's ticks whole, so the float sums are exact."""
+    from repro.serving import FaultPlan
+
+    wl = make_workload(medium_static_graph, templates=("Q2", "Q4"),
+                       n_per_template=3, seed=70)
+    plan = (None if fault is None else
+            FaultPlan(schedule={"straggler": {0}}, straggler_factor=2.0))
+    sched = _fake_sched(
+        medium_static_graph, clock=StepClock(step=1.0), fault_plan=plan,
+        dispatcher=FakeDispatcher(service_model=constant_service_model(
+            2.0 ** -6)))
+    res = sched.run(wl)
+    assert all(r.ok for r in res)
+    ds = sched.last_dispatches
+    assert len(ds) == 2
+    for d in ds:
+        assert d.t_start <= d.t_launch <= d.t_ready <= d.t_end
+        assert d.t_ready - d.t_launch == d.service_s - d.penalty_s
+    assert ds[0].t_end <= ds[1].t_start
+    want0 = 2.0 ** -6 * (ds[0].n_real + ds[0].n_pad)
+    assert ds[0].service_s == (want0 if fault is None else 2 * want0)
+
+
+def test_dispatch_stamps_measure_real_dispatch(small_static_graph):
+    """Real dispatch on the default clock: t_ready - t_launch is exactly
+    the measured service time, inside the unit's start and end."""
+    wl = make_workload(small_static_graph, templates=("Q2", "Q4"),
+                       n_per_template=2, seed=71)
+    sched = BatchScheduler(small_static_graph, keep_outputs=True)
+    sched.run(wl, warm=True)
+    for d in sched.last_dispatches:
+        assert d.t_start <= d.t_launch < d.t_ready <= d.t_end
+        assert d.t_ready - d.t_launch == d.service_s
+
+
+def test_flush_phases_on_the_profiler_trace(small_static_graph, tmp_path):
+    """One flush under ``jax.profiler``: the host plane holds
+    ``sched.flush`` enclosing ``sched.launch`` and ``sched.device_wait``,
+    all named from the one vocabulary."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs.trace import SCHED_PHASES
+
+    wl = make_workload(small_static_graph, templates=("Q2",),
+                       n_per_template=2, seed=72)
+    sched = BatchScheduler(small_static_graph)
+    sched.run(wl, warm=True)                     # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        sched.run(wl)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                          for ev in line.events
+                          if ev.name.startswith("sched.")]
+    names = {n for n, _, _ in spans}
+    assert {"sched.flush", "sched.group", "sched.plan", "sched.plan_tensor",
+            "sched.launch", "sched.device_wait", "sched.fetch"} <= names
+    assert names <= set(SCHED_PHASES)
+    (_, f0, f1), = [s for s in spans if s[0] == "sched.flush"]
+    for n, a, b in spans:
+        if n in ("sched.launch", "sched.device_wait"):
+            assert f0 <= a <= b <= f1
+
+
+def test_program_names_follow_the_shape(small_static_graph):
+    """Each batch program is named after its shape: two templates give two
+    names, and the name is the lowered module's."""
+    import jax.numpy as jnp
+
+    from repro.core import engine as E
+    from repro.core import intervals as iv
+    from repro.core import query as Q
+
+    q2, q4 = (make_workload(small_static_graph, templates=(t,),
+                            n_per_template=1, seed=73)[0].qry
+              for t in ("Q2", "Q4"))
+    n2 = E.program_name("dense", q2, 1, E.MODE_STATIC)
+    n4 = E.program_name("dense", q4, 1, E.MODE_STATIC)
+    assert n2 != n4
+    assert n2.startswith("dense_static_h2_paths_s1_")
+    assert n4.startswith("dense_static_h4_paths_s1_etr_")
+    run = E.batch_executable(small_static_graph, q4, 1, sliced=False)
+    g = small_static_graph
+    be = jnp.asarray(iv.bucket_edges(g.lifespan[0], g.lifespan[1], 16))
+    txt = run.fn.lower(E._prepare_gdev(g),
+                       jnp.asarray(np.stack([Q.query_params(q4)] * 2)),
+                       be).as_text()
+    assert f"module @jit_{n4} " in txt
+
+
+def test_program_name_is_the_same_in_every_process():
+    """The name's digest is a CRC of the shape, not Python's salted
+    ``hash``: two interpreters with different hash seeds agree."""
+    import os
+
+    code = ("from repro.core import engine as E, query as Q\n"
+            "q = Q.PathQuery(v_preds=(Q.VertexPredicate(0), "
+            "Q.VertexPredicate(1)), e_preds=(Q.EdgePredicate(2),))\n"
+            "print(E.program_name('dense', q, 1, E.MODE_BUCKET))\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = set()
+    for seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.path.join(repo, "src"))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr
+        names.add(out.stdout.strip())
+    assert len(names) == 1
+    assert names.pop().startswith("dense_bucket_h1_paths_s1_")
+
+
+def test_device_scopes_reach_the_lowered_program(small_static_graph):
+    """The superstep phases' ``jax.named_scope`` names are in the lowered
+    program's debug locations (op_name metadata on the device)."""
+    import jax.numpy as jnp
+
+    from repro.core import engine as E
+    from repro.core import intervals as iv
+    from repro.core import query as Q
+    from repro.obs.trace import DEVICE_SCOPES
+
+    q4 = make_workload(small_static_graph, templates=("Q4",),
+                       n_per_template=1, seed=74)[0].qry
+    run = E.batch_executable(small_static_graph, q4, 2, sliced=False)
+    g = small_static_graph
+    be = jnp.asarray(iv.bucket_edges(g.lifespan[0], g.lifespan[1], 16))
+    txt = run.fn.lower(E._prepare_gdev(g),
+                       jnp.asarray(np.stack([Q.query_params(q4)] * 2)),
+                       be).as_text(debug_info=True)
+    for name in ("hop_deliver", "edge_pred", "etr_prefix", "vertex_pred",
+                 "src_gather", "join"):
+        assert name in DEVICE_SCOPES
+        assert f"({name})/" in txt or f"/{name}/" in txt, name
