@@ -8,7 +8,8 @@ superstep is a dense tensor program over the 2E traversal-edge arrays:
 
   vertex step : vectorised predicate eval over property columns  → match mask
   edge step   : gather source counts → edge predicate mask → per-edge counts
-  delivery    : sorted segment-sum of per-edge counts by arrival vertex
+  delivery    : per-arrival-vertex sums of per-edge counts, as differences
+                of a running sum at the arrival CSR offsets (no scatter)
 
 Path multiplicity is carried as float32 *counts* (the tensor form of the
 paper's result-tree message compression: per-hop DP state instead of per-path
@@ -28,7 +29,7 @@ prefix sums (see graph.EtrTables): exact, O(E) per hop, no ragged state.
 Three-layer architecture
 ------------------------
 The hop primitives (predicate eval, edge masking, ETR rank application,
-segment-sum delivery, state algebra, joins) live in ``superstep.py``; this
+delivery, state algebra, joins) live in ``superstep.py``; this
 module adds the DENSE executor (``run_segment``) plus the split-point plan
 skeleton (``execute_plan_traced``) that all executors share via the
 ``segment_runner`` hook:
@@ -199,13 +200,14 @@ def run_segment(
                 mch_v = mch_new
         else:
             arrivals_v = SS.deliver(cnt_e, gdev["t_dst"], V, impl=impl,
-                                    layout=layout)
+                                    layout=layout, ptr=gdev["arr_ptr"])
             if with_minmax:
                 with scope("src_gather"):
                     m_src = mch_v[gdev["t_src"]]
                 m_e = SS.minmax_edge(m_src, cnt_e, minmax_op, mode)
                 mch_v = SS.deliver_extremum(m_e, gdev["t_dst"], V, minmax_op,
-                                            impl=impl, layout=layout)
+                                            impl=impl, layout=layout,
+                                            ptr=gdev["arr_ptr"])
         if d_add is not None:
             arrivals_v = arrivals_v + d_add
             if with_minmax:

@@ -236,8 +236,10 @@ def _run_segment_sliced(gdev, v_preds, e_preds, params, pv, pe, mode,
                 nvhi - nvlo, impl=impl)
         else:
             seg = gdev["t_dst"][lo:hi] - nvlo
+            # the slice's CSR offsets, rebased: arr_ptr[nvlo] == lo
+            ptr = gdev["arr_ptr"][nvlo:nvhi + 1] - lo
             arrivals_v = SS.deliver(cnt_e, seg, nvhi - nvlo, impl=impl,
-                                    layout=lay)
+                                    layout=lay, ptr=ptr)
         arrivals_e = cnt_e
         prev_raw = cnt_e
         prev_eb = cur_eb

@@ -28,8 +28,14 @@ all three:
                                                    entries move (the
                                                    partitioned executor's
                                                    exchange, all channels)
-  delivery               deliver()               — sorted segment-sum of
-                                                   per-edge counts by arrival
+  delivery               deliver()               — per-arrival-vertex sums
+                                                   of per-edge counts: int32
+                                                   prefix differences at the
+                                                   arrival CSR offsets
+                                                   (dense, sliced); segment-
+                                                   sum scatter where edges
+                                                   are unsorted or padded
+                                                   (delta, partitioned)
                          fused_hop_deliver()     — the fused kernel hop
                                                    (gather → temporal mask →
                                                    segment-reduce in VMEM via
@@ -38,8 +44,11 @@ all three:
                                                    every plain hop)
   extremum channel       minmax_seed(), minmax_edge(), deliver_extremum()
                          — the MIN/MAX aggregate's per-hop DP channel
-                           (segment_min/segment_max delivery; the partitioned
-                           executor exchanges it alongside the count state)
+                           (a segmented min/max scan read at segment ends
+                           in the dense executor; segment_min/segment_max
+                           where edges are unsorted or padded; the
+                           partitioned executor exchanges it alongside the
+                           count state)
   joins                  join_interval_counts(), join_interval_counts_edges()
 
 Temporal modes (shared by all executors):
@@ -446,24 +455,42 @@ def p2p_exchange(rows_w, local_src, send_slot, recv_slot, n_slots: int,
 # =========================================================================
 @scope("hop_deliver")
 def deliver(cnt_e, seg_ids, num_segments: int, indices_are_sorted: bool = True,
-            impl: str = "xla", layout=None):
-    """Sorted segment-sum of per-edge counts by arrival vertex — the message
-    delivery of one superstep.  Summation order is the canonical (arrival-
-    sorted) edge order, which is what makes the partitioned executor's
-    per-worker deliveries bit-identical to the dense one.
+            impl: str = "xla", layout=None, ptr=None):
+    """Per-arrival-vertex sum of per-edge counts — the message delivery of
+    one superstep.
 
-    ``impl`` selects the lowering: ``'xla'`` is the segment-sum scatter;
-    ``'pallas'``/``'pallas_interpret'`` with a ``kernels.hop_scatter``
-    ``HopLayout`` over the same (static, sorted) seg_ids runs the blocked
-    scatter-as-matmul kernel instead — identical sums (bit-identical while
-    counts are exact integers in float32, the engine's invariant)."""
-    if not use_pallas(check_impl(impl)) or layout is None:
-        return jax.ops.segment_sum(
-            cnt_e, seg_ids, num_segments=num_segments,
-            indices_are_sorted=indices_are_sorted,
-        )
-    return HK.scatter_deliver(cnt_e, layout.tables, num_segments,
-                              layout.block_v, impl=impl)
+    ``impl`` selects the lowering.  ``'pallas'``/``'pallas_interpret'`` with
+    a ``kernels.hop_scatter`` ``HopLayout`` over the same (static, sorted)
+    seg_ids runs the blocked scatter-as-matmul kernel.  Otherwise, given
+    ``ptr`` (int32 ``[num_segments + 1]``, the CSR offsets of arrival-sorted
+    edges), the sums are prefix differences with no scatter: an int32
+    running sum along the edge axis read at the segment bounds.  Without
+    ``ptr`` (unsorted delta edges, padded per-worker slots) it is the XLA
+    segment-sum scatter.  All three agree bit for bit while counts are
+    exact integers in float32, the engine's invariant: int32 addition wraps
+    exactly, so each segment's difference is exact whenever its sum fits
+    int32, which covers every sum float32 holds exactly."""
+    if use_pallas(check_impl(impl)) and layout is not None:
+        return HK.scatter_deliver(cnt_e, layout.tables, num_segments,
+                                  layout.block_v, impl=impl)
+    if ptr is not None:
+        return _prefix_deliver(cnt_e, ptr)
+    return jax.ops.segment_sum(
+        cnt_e, seg_ids, num_segments=num_segments,
+        indices_are_sorted=indices_are_sorted,
+    )
+
+
+def _prefix_deliver(cnt_e, ptr):
+    """Segment sums over CSR offsets ``ptr`` as differences of an int32
+    running sum: ``S[ptr[v+1]] - S[ptr[v]]`` with ``S[0] = 0``."""
+    ts = cnt_e.shape[1:]
+    if cnt_e.shape[0] == 0:
+        return jnp.zeros((ptr.shape[0] - 1,) + ts, cnt_e.dtype)
+    run = jax.lax.cumsum(cnt_e.astype(jnp.int32), axis=0)
+    at = run[jnp.maximum(ptr - 1, 0)]            # S[ptr], less the zero row
+    at = jnp.where((ptr > 0).reshape((-1,) + (1,) * len(ts)), at, 0)
+    return (at[1:] - at[:-1]).astype(cnt_e.dtype)
 
 
 @scope("hop_deliver")
@@ -581,22 +608,52 @@ def minmax_edge(mch_src, cnt_e, op: int, mode: int):
 @scope("hop_deliver")
 def deliver_extremum(m_e, seg_ids, num_segments: int, op: int,
                      indices_are_sorted: bool = True, impl: str = "xla",
-                     layout=None):
-    """Extremum twin of ``deliver``: sorted segment_min/segment_max of the
-    per-edge channel by arrival vertex.  Min/max is order-independent, so
-    per-worker deliveries over owned segments match the dense delivery
-    exactly.  The ``impl`` axis mirrors ``deliver``'s: with a layout, the
-    blocked masked-extremum kernel replaces the XLA segment reduce (same
-    ±inf identity on empty segments)."""
-    if not use_pallas(check_impl(impl)) or layout is None:
-        seg = jax.ops.segment_min if op == Q.AGG_MIN else jax.ops.segment_max
-        return seg(m_e, seg_ids, num_segments=num_segments,
-                   indices_are_sorted=indices_are_sorted)
-    # m_e is already liveness-gated by minmax_edge, so every slot is "alive"
-    return HK.scatter_extremum(
-        m_e, jnp.ones_like(m_e), layout.tables, num_segments, layout.block_v,
-        neutral=float(minmax_neutral(op)), op_is_min=(op == Q.AGG_MIN),
-        impl=impl)
+                     layout=None, ptr=None):
+    """Extremum twin of ``deliver``: per-arrival-vertex min/max of the
+    per-edge channel, the neutral ±inf on empty segments.  Min/max is
+    order-independent, so every lowering, and per-worker deliveries over
+    owned segments, match exactly.  The lowerings mirror ``deliver``'s: the
+    blocked masked-extremum kernel with a layout; given ``ptr``, a
+    segmented scan over the arrival-sorted edges read at each segment's
+    last edge; else the XLA segment_min/segment_max scatter."""
+    if use_pallas(check_impl(impl)) and layout is not None:
+        # m_e is already liveness-gated by minmax_edge: every slot is "alive"
+        return HK.scatter_extremum(
+            m_e, jnp.ones_like(m_e), layout.tables, num_segments,
+            layout.block_v, neutral=float(minmax_neutral(op)),
+            op_is_min=(op == Q.AGG_MIN), impl=impl)
+    if ptr is not None:
+        return _scan_extremum(m_e, seg_ids, ptr, op)
+    seg = jax.ops.segment_min if op == Q.AGG_MIN else jax.ops.segment_max
+    return seg(m_e, seg_ids, num_segments=num_segments,
+               indices_are_sorted=indices_are_sorted)
+
+
+def _scan_extremum(m_e, seg_ids, ptr, op: int):
+    """Segmented inclusive min/max scan of ``m_e [E]`` over sorted
+    ``seg_ids``, read at the segment ends ``ptr[1:] - 1``.
+
+    The scan doubles its reach each step (step d folds in the value d edges
+    back while that edge lies in the same segment), so it takes
+    ceil(log2(longest segment)) steps of elementwise work."""
+    neutral = minmax_neutral(op)
+    if m_e.shape[0] == 0:
+        return jnp.full((ptr.shape[0] - 1,), neutral, m_e.dtype)
+    comb = jnp.minimum if op == Q.AGG_MIN else jnp.maximum
+    idx = jnp.arange(m_e.shape[0], dtype=jnp.int32)
+    start = jnp.concatenate([jnp.ones((1,), bool), seg_ids[1:] != seg_ids[:-1]])
+    first = jax.lax.cummax(jnp.where(start, idx, 0))   # own segment's 1st edge
+    longest = jnp.max(ptr[1:] - ptr[:-1])
+
+    def step(carry):
+        d, v = carry
+        return 2 * d, jnp.where(idx - d >= first,
+                                comb(v, jnp.roll(v, d, axis=0)), v)
+
+    _, scan = jax.lax.while_loop(lambda c: c[0] < longest, step,
+                                 (jnp.int32(1), m_e))
+    end = scan[jnp.maximum(ptr[1:] - 1, 0)]
+    return jnp.where(ptr[1:] > ptr[:-1], end, neutral)
 
 
 # =========================================================================

@@ -835,3 +835,43 @@ def test_device_scopes_reach_the_lowered_program(small_static_graph):
                  "src_gather", "join"):
         assert name in DEVICE_SCOPES
         assert f"({name})/" in txt or f"/{name}/" in txt, name
+
+
+def _hop_deliver_scatters(compiled_text: str) -> list:
+    """op names of the compiled program's scatters under ``hop_deliver``."""
+    import re
+
+    names = [re.search(r'op_name="([^"]*)"', ln)
+             for ln in compiled_text.splitlines()
+             if re.search(r"\bscatter\(", ln)]
+    return [m.group(1) for m in names if m and "hop_deliver" in m.group(1)]
+
+
+@pytest.mark.parametrize("agg", ["paths", "min"])
+def test_dense_hop_delivery_does_not_scatter(small_static_graph, agg):
+    """The dense batch program delivers hops by prefix differences and
+    segmented scans over the arrival CSR (Q2, and Q2-min's extremum
+    channel): no scatter under ``hop_deliver``.  The partitioned program,
+    whose per-worker slots are padded, still scatters there."""
+    import jax.numpy as jnp
+
+    from repro.core import engine as E
+    from repro.core import engine_partitioned as EP
+    from repro.core import intervals as iv
+    from repro.core import query as Q
+    from repro.graphdata.queries import to_minmax
+
+    g = small_static_graph
+    inst = make_workload(g, templates=("Q2",), n_per_template=1, seed=74)[0]
+    q = inst.qry if agg == "paths" else to_minmax(inst, g).qry
+    split = 0 if q.agg_op != Q.AGG_NONE else q.n_vertices - 1
+    params = jnp.asarray(np.stack([Q.query_params(q)] * 2))
+    be = jnp.asarray(iv.bucket_edges(g.lifespan[0], g.lifespan[1], 16))
+    dense = E.batch_executable(g, q, split, sliced=False).fn.lower(
+        E._prepare_gdev(g), params, be).compile().as_text()
+    assert "hop_deliver" in dense
+    assert _hop_deliver_scatters(dense) == []
+    gd, pd, _, _ = EP.device_tables(g, 2)
+    part = EP.batch_executable(g, q, split, n_workers=2).fn.lower(
+        gd, pd, params, be).compile().as_text()
+    assert _hop_deliver_scatters(part)

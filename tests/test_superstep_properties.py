@@ -12,6 +12,7 @@ Intervals are drawn INSIDE the bucketed span, mirroring the engine invariant
 that every entity lifespan lies within the graph lifespan the bucket edges
 cover (out-of-span intervals would be clipped into the edge buckets).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -134,3 +135,125 @@ def test_deliver_matches_numpy(seg_spec, data):
     want = np.zeros(nseg, np.float32)
     np.add.at(want, seg, vals)
     assert np.array_equal(got, want)
+
+
+# -------------------------------------------------------------------------
+# the sorted-CSR lowerings (``ptr=``) against numpy and the scatter
+# -------------------------------------------------------------------------
+def _csr(lengths):
+    """(sorted seg ids int32[E], CSR offsets int32[len + 1])."""
+    lengths = np.asarray(lengths, np.int64)
+    ptr = np.zeros(len(lengths) + 1, np.int32)
+    np.cumsum(lengths, out=ptr[1:])
+    return np.repeat(np.arange(len(lengths), dtype=np.int32), lengths), ptr
+
+
+#: segment lengths per case: empty segments (first, inner and last), no
+#: edges at all, one-edge segments, and hubs just below, at and above a
+#: power of two
+SEGMENT_CASES = {
+    "empty": [0, 3, 0, 0, 2, 0],
+    "no_edges": [0, 0, 0],
+    "single_edge": [1, 1, 0, 1],
+    "hub": [2, 0, 300, 1, 0, 256, 257],
+}
+#: trailing state shapes: static [E], bucket [E, B], interval [E, B, B+1]
+TRAILING = {"static": (), "bucket": (B,), "interval": (B, B + 1)}
+BATCH = 3
+
+
+def _numpy_sum(vals, seg, nseg):
+    want = np.zeros((nseg,) + vals.shape[1:], np.float32)
+    np.add.at(want, seg, vals)
+    return want
+
+
+def _check_sum_lowerings(vals, seg, ptr):
+    """ptr lowering == numpy == segment_sum scatter, bit for bit, alone and
+    under a batch vmap (``vals`` carries the batch axis first)."""
+    nseg = len(ptr) - 1
+    seg_j, ptr_j = jnp.asarray(seg), jnp.asarray(ptr)
+    by_ptr = jax.vmap(lambda c: SS.deliver(c, seg_j, nseg, ptr=ptr_j))
+    by_scatter = jax.vmap(lambda c: SS.deliver(c, seg_j, nseg))
+    want = np.stack([_numpy_sum(v, seg, nseg) for v in vals])
+    assert np.array_equal(np.asarray(by_ptr(jnp.asarray(vals))), want)
+    assert np.array_equal(np.asarray(by_scatter(jnp.asarray(vals))), want)
+    one = SS.deliver(jnp.asarray(vals[0]), seg_j, nseg, ptr=ptr_j)
+    assert np.array_equal(np.asarray(one), want[0])
+
+
+@pytest.mark.parametrize("trailing", sorted(TRAILING))
+@pytest.mark.parametrize("case", sorted(SEGMENT_CASES))
+def test_deliver_ptr_matches_numpy_and_scatter(case, trailing):
+    seg, ptr = _csr(SEGMENT_CASES[case])
+    rng = np.random.default_rng(len(seg))
+    shape = (BATCH, len(seg)) + TRAILING[trailing]
+    vals = (rng.integers(0, 50, shape) * (rng.random(shape) < 0.7)).astype(
+        np.float32)
+    _check_sum_lowerings(vals, seg, ptr)
+
+
+@pytest.mark.parametrize("trailing", sorted(TRAILING))
+def test_deliver_ptr_exact_past_float32_running_total(trailing):
+    """Each segment sums to 2^24 - 4 (exact in float32, and exact for the
+    scatter's in-segment partial sums) while the running total over all
+    edges reaches 2^27: a float32 prefix difference rounds, the int32 one
+    must not."""
+    seg, ptr = _csr([4] * 8)
+    shape = (BATCH, len(seg)) + TRAILING[trailing]
+    vals = np.full(shape, 2.0**22 - 1, np.float32)
+    nseg = len(ptr) - 1
+    S = np.concatenate([np.zeros((1,) + shape[2:], np.float32),
+                        np.cumsum(vals[0], axis=0, dtype=np.float32)])
+    assert not np.array_equal(S[ptr[1:]] - S[ptr[:-1]],
+                              _numpy_sum(vals[0], seg, nseg))
+    _check_sum_lowerings(vals, seg, ptr)
+
+
+def _numpy_extremum(vals, seg, nseg, op):
+    pick = np.minimum if op == Q.AGG_MIN else np.maximum
+    want = np.full(nseg, np.asarray(SS.minmax_neutral(op)), np.float32)
+    for s, v in zip(seg, vals):
+        want[s] = pick(want[s], v)
+    return want
+
+
+@pytest.mark.parametrize("op", [Q.AGG_MIN, Q.AGG_MAX])
+@pytest.mark.parametrize("case", sorted(SEGMENT_CASES))
+def test_deliver_extremum_ptr_matches_numpy_and_scatter(case, op):
+    """The segmented scan equals numpy and segment_min/segment_max bit for
+    bit, with neutral (liveness-gated) edges mixed in, alone and under a
+    batch vmap."""
+    seg, ptr = _csr(SEGMENT_CASES[case])
+    nseg = len(ptr) - 1
+    rng = np.random.default_rng(len(seg) + op)
+    vals = rng.integers(-10**6, 10**6, (BATCH, len(seg))).astype(np.float32)
+    vals[rng.random(vals.shape) < 0.3] = np.asarray(SS.minmax_neutral(op))
+    seg_j, ptr_j = jnp.asarray(seg), jnp.asarray(ptr)
+    want = np.stack([_numpy_extremum(v, seg, nseg, op) for v in vals])
+    by_ptr = jax.vmap(lambda m: SS.deliver_extremum(m, seg_j, nseg, op,
+                                                    ptr=ptr_j))
+    by_scatter = jax.vmap(lambda m: SS.deliver_extremum(m, seg_j, nseg, op))
+    assert np.array_equal(np.asarray(by_ptr(jnp.asarray(vals))), want)
+    assert np.array_equal(np.asarray(by_scatter(jnp.asarray(vals))), want)
+    one = SS.deliver_extremum(jnp.asarray(vals[0]), seg_j, nseg, op, ptr=ptr_j)
+    assert np.array_equal(np.asarray(one), want[0])
+
+
+@settings(max_examples=50, deadline=None)
+@given(st.lists(st.integers(0, 40), min_size=1, max_size=12), st.data())
+def test_deliver_ptr_lowerings_match_numpy(lengths, data):
+    """Random CSR layouts (any mix of empty, short and long segments)."""
+    seg, ptr = _csr(lengths)
+    nseg = len(ptr) - 1
+    vals = np.asarray(data.draw(st.lists(
+        st.integers(-50, 50), min_size=len(seg), max_size=len(seg))),
+        np.float32)
+    got = SS.deliver(jnp.asarray(vals), jnp.asarray(seg), nseg,
+                     ptr=jnp.asarray(ptr))
+    assert np.array_equal(np.asarray(got), _numpy_sum(vals, seg, nseg))
+    for op in (Q.AGG_MIN, Q.AGG_MAX):
+        got = SS.deliver_extremum(jnp.asarray(vals), jnp.asarray(seg), nseg,
+                                  op, ptr=jnp.asarray(ptr))
+        assert np.array_equal(np.asarray(got),
+                              _numpy_extremum(vals, seg, nseg, op)), op
