@@ -51,10 +51,6 @@ class SliceBounds:
         return SliceBounds(v, e)
 
 
-def _vslice(arr, lo, hi):
-    return arr[lo:hi]
-
-
 def slice_layouts_for(graph: TemporalGraph, qry: Q.PathQuery,
                       sb: SliceBounds, impl: str = "xla",
                       block_v: Optional[int] = None,
@@ -201,20 +197,22 @@ def _run_segment_sliced(gdev, v_preds, e_preds, params, pv, pe, mode,
         if ep.etr_op != -1:
             src_cnt = _etr_weighted_sliced(gdev, prev_raw, ep.etr_op, backward,
                                            False, prev_eb, cur_eb, cur_vb)
-            if mode == MODE_STATIC:
-                src_val = src_cnt * (vm[src_local] & src_in).astype(jnp.float32)
-            elif mode == MODE_BUCKET:
-                mk = (vm[:, None] & vv)
-                src_val = src_cnt * (mk[src_local] & src_in[:, None]).astype(jnp.float32)
-            else:
-                src_val = SS.apply_validity(src_cnt, vm[src_local] & src_in,
-                                          vv[src_local], mode)
+            with scope("src_gather"):
+                if mode == MODE_STATIC:
+                    src_val = src_cnt * (vm[src_local] & src_in).astype(jnp.float32)
+                elif mode == MODE_BUCKET:
+                    mk = (vm[:, None] & vv)
+                    src_val = src_cnt * (mk[src_local] & src_in[:, None]).astype(jnp.float32)
+                else:
+                    src_val = SS.apply_validity(src_cnt, vm[src_local] & src_in,
+                                                vv[src_local], mode)
         else:
             if i == 0:
                 sv = state_v
             else:
                 sv = SS.apply_validity(arrivals_v, vm, vv, mode)
-            gathered = sv[src_local]
+            with scope("src_gather"):
+                gathered = sv[src_local]
             m = src_in
             for _ in sv.shape[1:]:
                 m = m[..., None]
@@ -309,61 +307,62 @@ def _inner(gdev, qry, split, mode, n_buckets, params, sb, impl: str = "xla",
                                     rpv[: m_hops + 1], rpe[:m_hops], mode,
                                     n_buckets, True, sb, impl, layouts)
 
-    vb = sb.v[qry.v_preds[split].vtype]
-    vm, vv = _vertex_eval_sliced(gdev, qry.v_preds[split], params, pv[split],
-                                 mode, bedges, vb)
-    etr_at_join = 0 < split < n - 1 and qry.e_preds[split].etr_op != -1
+    with scope("join"):
+        vb = sb.v[qry.v_preds[split].vtype]
+        vm, vv = _vertex_eval_sliced(gdev, qry.v_preds[split], params, pv[split],
+                                     mode, bedges, vb)
+        etr_at_join = 0 < split < n - 1 and qry.e_preds[split].etr_op != -1
 
-    def vapply(av):
-        return SS.apply_validity(av, vm, vv, mode)
+        def vapply(av):
+            return SS.apply_validity(av, vm, vv, mode)
 
-    if n == 1:
-        st = SS.init_state(vm, vv, mode, n_buckets)
-        pv = None
-        if want_agg:
-            pv = st if mode != MODE_INTERVAL else SS.cells_to_buckets(st)
-        return ExecOutput(SS.state_total(st, mode), pv, None, [])
-
-    if not etr_at_join:
-        if left is None:
-            Rv = vapply(right.arrivals_v)
+        if n == 1:
+            st = SS.init_state(vm, vv, mode, n_buckets)
+            pv = None
             if want_agg:
-                total = SS.state_total(Rv, mode)
-                # interval cells flatten to per-bucket series, as dense does
-                pv = Rv if mode != MODE_INTERVAL else SS.cells_to_buckets(Rv)
-                return ExecOutput(total, pv, None, [])
-            return ExecOutput(SS.state_total(Rv, mode), None, None, [])
-        if right is None:
-            Lv = vapply(left.arrivals_v)
-            return ExecOutput(SS.state_total(Lv, mode), None, None, [])
-        Lv = vapply(left.arrivals_v)
-        Rv = right.arrivals_v
-        if mode == MODE_STATIC:
-            total = jnp.sum(Lv * Rv)
-        elif mode == MODE_BUCKET:
-            total = jnp.sum(Lv * Rv, axis=0)
-        else:
-            total = jnp.sum(SS.join_interval_counts(Lv, Rv))
-        return ExecOutput(total, None, None, [])
+                pv = st if mode != MODE_INTERVAL else SS.cells_to_buckets(st)
+            return ExecOutput(SS.state_total(st, mode), pv, None, [])
 
-    # ETR at join: left/right final arrivals share the split-type edge slice
-    op = qry.e_preds[split].etr_op
-    eb = sb.e[qry.v_preds[split].vtype]
-    W = _etr_weighted_sliced(gdev, left.arrivals_e, op, False, True,
-                             eb, eb, vb)
-    lo, hi = eb
-    vlo, _ = vb
-    dst_local = gdev["t_dst"][lo:hi] - vlo
-    if mode == MODE_STATIC:
-        w_v = vm[dst_local].astype(jnp.float32)
-        total = jnp.sum(W * right.arrivals_e * w_v)
-    elif mode == MODE_BUCKET:
-        mk = (vm[:, None] & vv).astype(jnp.float32)[dst_local]
-        total = jnp.sum(W * right.arrivals_e * mk, axis=0)
-    else:
-        Wc = SS.apply_validity(W, vm[dst_local], vv[dst_local], mode)
-        total = jnp.sum(SS.join_interval_counts_edges(Wc, right.arrivals_e))
-    return ExecOutput(total, None, None, [])
+        if not etr_at_join:
+            if left is None:
+                Rv = vapply(right.arrivals_v)
+                if want_agg:
+                    total = SS.state_total(Rv, mode)
+                    # interval cells flatten to per-bucket series, as dense does
+                    pv = Rv if mode != MODE_INTERVAL else SS.cells_to_buckets(Rv)
+                    return ExecOutput(total, pv, None, [])
+                return ExecOutput(SS.state_total(Rv, mode), None, None, [])
+            if right is None:
+                Lv = vapply(left.arrivals_v)
+                return ExecOutput(SS.state_total(Lv, mode), None, None, [])
+            Lv = vapply(left.arrivals_v)
+            Rv = right.arrivals_v
+            if mode == MODE_STATIC:
+                total = jnp.sum(Lv * Rv)
+            elif mode == MODE_BUCKET:
+                total = jnp.sum(Lv * Rv, axis=0)
+            else:
+                total = jnp.sum(SS.join_interval_counts(Lv, Rv))
+            return ExecOutput(total, None, None, [])
+
+        # ETR at join: left/right final arrivals share the split-type edge slice
+        op = qry.e_preds[split].etr_op
+        eb = sb.e[qry.v_preds[split].vtype]
+        W = _etr_weighted_sliced(gdev, left.arrivals_e, op, False, True,
+                                 eb, eb, vb)
+        lo, hi = eb
+        vlo, _ = vb
+        dst_local = gdev["t_dst"][lo:hi] - vlo
+        if mode == MODE_STATIC:
+            w_v = vm[dst_local].astype(jnp.float32)
+            total = jnp.sum(W * right.arrivals_e * w_v)
+        elif mode == MODE_BUCKET:
+            mk = (vm[:, None] & vv).astype(jnp.float32)[dst_local]
+            total = jnp.sum(W * right.arrivals_e * mk, axis=0)
+        else:
+            Wc = SS.apply_validity(W, vm[dst_local], vv[dst_local], mode)
+            total = jnp.sum(SS.join_interval_counts_edges(Wc, right.arrivals_e))
+        return ExecOutput(total, None, None, [])
 
 
 def sliceable(qry: Q.PathQuery) -> bool:

@@ -19,7 +19,13 @@ moves:
 Engines: ``dense`` / ``sliced`` (engine.batch_executable), ``partitioned``
 (engine_partitioned.batch_executable), or ``auto`` (sliced when the query
 qualifies, dense otherwise — resolved at admission so the group key is
-concrete).
+concrete).  On one chip a group runs on typed arrival extents (the sliced
+executable: each hop reads only the traversal edges that arrive at its
+next vertex type) whenever its query allows it (``engine_sliced.
+sliceable``: every vertex typed, aggregate NONE or COUNT): under
+``sliced`` always, under ``dense`` unless the group is served base+delta.
+MIN/MAX groups, wildcard-typed queries, base+delta groups and the
+partitioned engine read the whole graph (``GroupDispatch.extents``).
 
 Hop-delivery lowering: the ``impl`` knob (``HOP_IMPLS``) pins every group on
 one lowering (``'xla'`` or the fused ``'pallas'`` hop kernel), or
@@ -213,6 +219,7 @@ class GroupDispatch:
     t_launch: float = math.nan   # just before the executable is called
     t_ready: float = math.nan    # its answers are ready on the device
     t_end: float = math.nan      # the unit's answers are stored
+    extents: bool = False        # ran on typed arrival extents (sliced)
 
 
 class BatchScheduler:
@@ -513,6 +520,15 @@ class BatchScheduler:
                                   self.n_buckets,
                                   sliced=(engine == "sliced"), impl=impl)
 
+    def _extents(self, qry: Q.PathQuery, engine: str) -> bool:
+        """Does this group run on typed arrival extents?  ``sliced`` forces
+        them; ``dense`` takes them whenever the query allows it, except on
+        the base+delta path, which delivers into the whole graph."""
+        if engine == "sliced":
+            return True
+        return (engine == "dense" and ES.sliceable(qry)
+                and not self._delta_eligible(qry, engine))
+
     def _delta_eligible(self, qry: Q.PathQuery, engine: str) -> bool:
         """Can this group run on the base+delta executable?  Needs a pinned
         pure-append delta window, the dense engine (sliced/partitioned bind
@@ -537,6 +553,10 @@ class BatchScheduler:
             self._last_used_delta = use_delta
             fp = ("delta", self._base_fp) if use_delta else self.fingerprint
             lay_graph = self.graph if use_delta else self._serve_graph
+            # the executor the group runs on: dense groups whose query
+            # allows it share the sliced executable
+            if self._extents(queries[0], engine):
+                engine = "sliced"
             ekey = (engine, fp, bucket, split, mode,
                     self.n_buckets,
                     self.n_workers if engine == "partitioned" else 0,
@@ -718,7 +738,7 @@ class BatchScheduler:
     def _trace_group(self, queue, idxs, ests, feats, split, engine, impl,
                      pt, dt, plan_cached, exec_cached, candidates, seq,
                      edf_pos, group_deadline, predicted_ms, t_launch,
-                     t_ready, out):
+                     t_ready, extents, out):
         """Emit one dispatched group's span set: for EVERY member query a
         plan → compile → dispatch → superstep (per hop) → exchange chain
         under its root, so each query's tree is complete on its own.
@@ -774,7 +794,7 @@ class BatchScheduler:
                 predicted_ms=q_preds[j], measured_ms=q_meas,
                 features=est.features, group_features=feats,
                 group_predicted_ms=group_pred, group_measured_ms=group_ms,
-                t_launch=t_launch, t_ready=t_ready)
+                t_launch=t_launch, t_ready=t_ready, extents=extents)
             hop_steps = [s for s in est.steps if s.channels is not None]
             hop_preds = [float(s.features @ theta) for s in hop_steps]
             hp_sum = sum(hop_preds)
@@ -1029,6 +1049,7 @@ class BatchScheduler:
                 self._mark_unit(queue, out, idxs, engine, e, "failed")
                 return
         t_launch, t_ready = self._stamps
+        extents = self._extents(queries[0], engine)
         if (engine == "partitioned"
                 and not self._planner.engine_available("partitioned")):
             self._planner.mark_available("partitioned")  # probe succeeded
@@ -1082,14 +1103,15 @@ class BatchScheduler:
             traced_groups.append(
                 (idxs, ests, feats, split, engine, impl, pt, dt_raw,
                  plan_cached, exec_cached, candidates, seq, edf_pos,
-                 group_deadline, predicted_ms, t_launch, t_ready))
+                 group_deadline, predicted_ms, t_launch, t_ready,
+                 extents))
         dispatches.append(GroupDispatch(
             key, engine, split, pt.n_real, pt.n_pad, dt_total, list(idxs),
             plan_cached, exec_cached, impl, group_deadline, predicted_ms,
             delta=self._last_used_delta, n_retries=n_retries,
             fallback_from=fallback_from, penalty_s=penalty_s,
             t_start=t_start, t_launch=t_launch, t_ready=t_ready,
-            t_end=t_end))
+            t_end=t_end, extents=extents))
 
     def run(self, workload: Sequence[Union[QueryInstance, Q.PathQuery]],
             warm: bool = False) -> List[ServedResult]:

@@ -129,6 +129,99 @@ def test_scheduler_failing_group_isolated(medium_static_graph):
                                           split=r.split)
 
 
+# ------------------------------------------ typed extents on one chip
+def _program_names(sched) -> set:
+    """``jit_<name>`` of every batched program the scheduler built."""
+    return {f"jit_{run.fn.__name__}"
+            for run in sched.exec_cache._fns.values()}
+
+
+def test_dense_runs_typed_groups_on_extents(medium_static_graph):
+    """Under ``engine="dense"`` a typed COUNT/NONE group runs the extent
+    (sliced) executable and records it; a MIN group of the same template
+    stays on the whole graph."""
+    from repro.graphdata.queries import to_minmax
+
+    g = medium_static_graph
+    wl = make_workload(g, templates=("Q2", "Q4"), n_per_template=3, seed=80)
+    wl += make_workload(g, templates=("Q2",), n_per_template=2, seed=81,
+                        aggregate=True)
+    mins = [to_minmax(i, g) for i in make_workload(
+        g, templates=("Q2",), n_per_template=2, seed=82)]
+    sched = BatchScheduler(g, engine="dense")
+    res = sched.run(wl + mins)
+    assert all(r.ok and r.engine == "dense" for r in res)
+    by_agg = {}
+    for d in sched.last_dispatches:
+        qry = (wl + mins)[d.indices[0]].qry
+        by_agg.setdefault(qry.agg_op, set()).add(d.extents)
+    assert by_agg == {Q.AGG_NONE: {True}, Q.AGG_COUNT: {True},
+                      Q.AGG_MIN: {False}}
+    names = _program_names(sched)
+    assert len(names) == 4
+    assert sum(n.startswith("jit_sliced_static_") for n in names) == 3
+    assert [n for n in names if n.startswith("jit_dense_")] == [
+        next(n for n in names if "_min_" in n)]
+
+
+def test_dense_keeps_delta_groups_whole_graph(small_dynamic_graph):
+    """With a pinned pure-append delta window, ETR-free dense groups run the
+    base+delta executable (whole graph, ``extents`` false); ETR groups,
+    which cannot run base+delta, take the extents on the epoch graph."""
+    from repro.graphdata.ingest import log_from_graph
+    from repro.serving import EpochManager
+
+    g = small_dynamic_graph
+    log, held = log_from_graph(g, holdout_edges=20, seed=7)
+    mgr = EpochManager(log, compact_every=10)
+    e0 = mgr.seal()
+    wl = make_workload(e0.graph, templates=("Q2", "Q4"), n_per_template=2,
+                       seed=83)
+    sched = BatchScheduler(e0.graph, engine="dense")
+    mgr.attach(sched)
+    mgr.ingest(held)
+    assert mgr.advance(sched).delta is not None
+    res = sched.run(wl)
+    assert all(r.ok for r in res)
+    rows = {any(e.etr_op != -1 for e in wl[d.indices[0]].qry.e_preds):
+            (d.delta, d.extents) for d in sched.last_dispatches}
+    assert rows == {False: (True, False), True: (False, True)}
+
+
+@pytest.mark.parametrize("graph", ["small_static_graph",
+                                   "small_dynamic_graph"])
+def test_dense_extents_bit_identical_to_whole_graph(request, graph):
+    """Every LDBC template, plain and COUNT, static and bucket mode: the
+    dense scheduler's answers (on extents) equal the whole-graph batch
+    executable's bit for bit."""
+    g = request.getfixturevalue(graph)
+    wl = make_workload(g, n_per_template=2, seed=84)
+    wl += make_workload(g, n_per_template=1, seed=85, aggregate=True)
+    sched = BatchScheduler(g, engine="dense", keep_outputs=True)
+    res = sched.run(wl)
+    assert all(d.extents for d in sched.last_dispatches)
+    for inst, r in zip(wl, res):
+        assert r.ok, (inst.template, r.error)
+        want = E.execute_batch_out(g, [inst.qry], r.split,
+                                   sched._mode_for(inst.qry),
+                                   sched.n_buckets, sliced=False)
+        assert np.array_equal(np.asarray(want.total)[0], r.total), \
+            inst.template
+        if inst.qry.agg_op != Q.AGG_NONE:
+            assert np.array_equal(np.asarray(want.per_vertex)[0],
+                                  r.per_vertex), inst.template
+    assert any(r.count > 0 for r in res)
+
+
+def test_sliced_engine_still_forces_extents(medium_static_graph):
+    wl = make_workload(medium_static_graph, templates=("Q2",),
+                       n_per_template=2, seed=86)
+    sched = BatchScheduler(medium_static_graph, engine="sliced")
+    assert all(r.ok for r in sched.run(wl))
+    assert [d.extents for d in sched.last_dispatches] == [True]
+    assert all(n.startswith("jit_sliced_") for n in _program_names(sched))
+
+
 # ---------------------------------------------------- batch-aware planning
 def test_planner_choose_batch_costs_whole_batch(medium_static_graph):
     """choose_batch must minimise the batch-summed cost; estimate_batch sums
