@@ -8,8 +8,6 @@ from typing import Dict, List
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import numpy as np
-
 from repro.graphdata.ldbc import LdbcParams, generate_ldbc, graph_name
 
 # BENCH_SCALE=full uses larger graphs (minutes); default is CI-sized.
@@ -53,66 +51,3 @@ def timeit(fn, repeat: int = 3, warmup: int = 1) -> float:
     for _ in range(repeat):
         fn()
     return (time.perf_counter() - t0) / repeat * 1e6   # µs
-
-
-def hop_delivery_times(g, mode: int, n_buckets: int = 8,
-                       repeats: int = 5) -> dict:
-    """Measured one-hop delivery cost per impl on ``g``'s traversal arrays.
-
-    Times exactly the step the θ_scatter coefficients model and the fused
-    kernel replaces: gather source state at ``t_src`` → apply an edge mask →
-    segment-reduce by ``t_dst`` — as the materialize+segment_sum XLA path
-    and as the fused hop kernel over the graph's static block layout.
-    Integer-valued state (the engine's count invariant) keeps the two paths
-    bit-identical, asserted here so the timing can never drift off a broken
-    kernel.  Returns {'xla_ms', 'pallas_ms', 'speedup', 'edges'}.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import engine as E
-    from repro.core import intervals as iv
-    from repro.core import superstep as SS
-
-    rng = np.random.default_rng(7)
-    gdev = E._prepare_gdev(g)
-    t_src, t_dst = gdev["t_src"], gdev["t_dst"]
-    V, E2 = g.n_vertices, int(t_src.shape[0])
-    bedges = jnp.asarray(iv.bucket_edges(g.lifespan[0], g.lifespan[1],
-                                         n_buckets))
-    ts = () if mode == SS.MODE_STATIC else (n_buckets,)
-    state = jnp.asarray(rng.integers(0, 8, (V,) + ts).astype(np.float32))
-    wmask = jnp.asarray(rng.random(E2) < 0.6)
-    evalid = (None if mode == SS.MODE_STATIC
-              else jnp.asarray(rng.random((E2, n_buckets)) < 0.7))
-    layout = E.hop_layout_for(g)
-
-    def xla_hop(state, wmask, evalid, seg):
-        cnt = SS.apply_edge(state[t_src], wmask, evalid, mode)
-        return SS.deliver(cnt, seg, V)
-
-    def pallas_hop(state, wmask, evalid):
-        with SS.bucket_scope(bedges):
-            return SS.fused_hop_deliver(state, t_src, wmask, evalid, mode,
-                                        layout.tables, layout.block_v, V,
-                                        impl="pallas")[0]
-
-    fx = jax.jit(xla_hop)
-    fp = jax.jit(pallas_hop)
-    a = fx(state, wmask, evalid, t_dst)
-    b = fp(state, wmask, evalid)
-    assert np.array_equal(np.asarray(a), np.asarray(b)), \
-        "fused hop drifted off the XLA delivery"
-
-    def best_of(fn, *args):
-        t_best = np.inf
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            t_best = min(t_best, time.perf_counter() - t0)
-        return t_best * 1e3
-
-    t_x = best_of(fx, state, wmask, evalid, t_dst)
-    t_p = best_of(fp, state, wmask, evalid)
-    return dict(xla_ms=t_x, pallas_ms=t_p, speedup=t_x / max(t_p, 1e-9),
-                edges=E2)

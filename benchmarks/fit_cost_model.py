@@ -28,7 +28,7 @@ from repro.graphdata.ldbc import LdbcParams, generate_ldbc
 from repro.graphdata.queries import make_workload
 from repro.launch.compile_cache import enable_compile_cache
 
-from .common import SCALE, emit, hop_delivery_times
+from .common import SCALE, emit
 
 
 def _trav_by_type(g):
@@ -136,32 +136,12 @@ def run(write: bool = True):
     theta_net_pair = np.maximum(fit_linear(M, resid), 0.0)
     theta = np.concatenate([theta_c, theta_net_pair])
 
-    # ---- per-impl hop-delivery slopes (θ_scatter): the same one-hop
-    # delivery timed as the materialize+segment_sum XLA path and as the
-    # fused hop kernel, over both micro-bench graphs and both cheap modes;
-    # an origin-constrained least squares gives ms-per-edge per impl.  These
-    # are the coefficients choose(impls=...) discriminates on, so they are
-    # fitted from the exact step the impl axis swaps.
-    edges, t_xla, t_pal = [], [], []
-    for g_ in graphs:
-        for md in (E.MODE_STATIC, E.MODE_BUCKET):
-            r = hop_delivery_times(g_, md, n_buckets=8)
-            edges.append(float(r["edges"]))
-            t_xla.append(r["xla_ms"])
-            t_pal.append(r["pallas_ms"])
-    ee = np.asarray(edges)
-    denom = max(float(np.sum(ee * ee)), 1e-9)
-    scatter_xla = float(np.sum(np.asarray(t_xla) * ee) / denom)
-    scatter_pal = float(np.sum(np.asarray(t_pal) * ee) / denom)
-
     coeffs = dict(
         theta0=float(theta[0]), theta_init=float(theta[1]),
         theta_v=float(theta[1]), theta_e=float(theta[2]),
         theta_etr=float(theta[3]), theta_m=float(theta[4]),
         theta_net=float(theta_net_pair[0]),
         theta_net_etr=float(theta_net_pair[1]),
-        theta_scatter_xla=scatter_xla,
-        theta_scatter_pallas=scatter_pal,
     )
     pred = X @ theta
     r2 = 1 - np.sum((y - pred) ** 2) / max(np.sum((y - y.mean()) ** 2), 1e-9)

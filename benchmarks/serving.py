@@ -64,13 +64,6 @@ Three measurements, one JSON artifact (``BENCH_serving.json``):
                answers_identical, recovery_identical, and non-zero
                retry/quarantine counts; check_bench pins the counters and a
                goodput floor vs fault-free.
-  hop_delivery xla-vs-pallas hop timings: ONE traversal-hop delivery
-               (gather → mask → segment-reduce) timed as the
-               materialize+segment_sum path and as the fused hop_scatter
-               kernel, static and bucket mode, on a serving-scale graph
-               (bit-identity asserted inside the measurement).  BENCH_ENFORCE
-               requires a speedup on both legs; check_bench pins the ratios
-               against the committed baselines.
 
 Workload and arrivals are seeded → reproducible run-to-run; wall-clock
 numbers vary with the host, ratios are the stable signal.  Compile time is
@@ -94,15 +87,11 @@ from repro.serving import (AdmissionPolicy, BatchScheduler, PlanCache,
 from repro.serving.replay import poisson_arrivals
 from repro.launch.compile_cache import enable_compile_cache
 
-from .common import SCALE, emit, hop_delivery_times
+from .common import SCALE, emit
 
 SEED = 33
 N_PER_TEMPLATE = {"ci": 8, "full": 50}[SCALE]
 N_PERSONS = {"ci": 150, "full": 1000}[SCALE]
-# the hop-delivery micro runs at production-ish edge counts (the regime the
-# fused kernel targets), independent of the serving workload's graph size
-HOP_N_PERSONS = {"ci": 1000, "full": 4000}[SCALE]
-HOP_N_BUCKETS = 8
 BUDGET_S = 600.0
 
 
@@ -173,24 +162,6 @@ def partitioned_leg(g, wl, seq_drain_s: float, n_workers: int = 4) -> dict:
     )
 
 
-def hop_delivery_leg() -> dict:
-    """Per-impl hop-delivery timings (the fused-kernel acceptance number).
-
-    Times the exact step the impl axis swaps — gather source state → apply
-    the temporal edge mask → segment-reduce by arrival — on a dedicated
-    serving-scale graph, in static and bucket mode.  The helper asserts
-    bit-identity between the two paths before timing, so the reported
-    speedup can never come from a diverged kernel."""
-    from repro.core import superstep as SS
-
-    g = generate_ldbc(LdbcParams(n_persons=HOP_N_PERSONS,
-                                 degree_dist="facebook", seed=2))
-    out = dict(n_persons=HOP_N_PERSONS, n_buckets=HOP_N_BUCKETS)
-    for mode, name in ((SS.MODE_STATIC, "static"), (SS.MODE_BUCKET, "bucket")):
-        out[name] = hop_delivery_times(g, mode, n_buckets=HOP_N_BUCKETS)
-    return out
-
-
 def slo_leg(g, wl, exec_cache, bat_drain_s: float, bat_tput: float,
             n_disp: int) -> dict:
     """The SLO serving experiment: online θ refit, deadline admission under
@@ -258,7 +229,7 @@ def slo_leg(g, wl, exec_cache, bat_drain_s: float, bat_tput: float,
     # noise, and a query can queue one full wave before it is even
     # submitted, so the structural margin has to absorb both.
     wl_ov = list(wl) * 3
-    policy = AdmissionPolicy(headroom=0.25, degrade_impls=(),
+    policy = AdmissionPolicy(headroom=0.25,
                              allow_engine_downgrade=False)
 
     def admitted_p99(rep) -> float:
@@ -644,9 +615,6 @@ def chaos_leg(g, wl, n_workers: int = 4,
 
 
 def run(out_path: str = "BENCH_serving.json") -> dict:
-    # the hop micro runs FIRST: it times a single kernel-vs-scatter step, so
-    # it must not inherit the heap/caches the workload legs accumulate
-    hop = hop_delivery_leg()
     params = LdbcParams(n_persons=N_PERSONS, degree_dist="facebook",
                         dynamic=False, seed=2)
     g = generate_ldbc(params)
@@ -723,7 +691,6 @@ def run(out_path: str = "BENCH_serving.json") -> dict:
         obs=obs,
         partitioned=partitioned_leg(g, wl, seq_drain_s),
         dynamic_leg=dynamic_leg(),
-        hop_delivery=hop,
         ingest=ing,
         chaos=chaos,
     )
@@ -736,10 +703,6 @@ def run(out_path: str = "BENCH_serving.json") -> dict:
     emit("serving/replay_p95_us", rep.latency_ms_p95 * 1e3,
          f"rate={rate:.1f}qps;completion={rep.completion_rate:.3f};"
          f"seq_sim_p95_ms={seq_sim['latency_ms_p95']:.1f}")
-    emit("serving/hop_delivery_bucket_us", hop["bucket"]["pallas_ms"] * 1e3,
-         f"speedup={hop['bucket']['speedup']:.2f}x;"
-         f"static_speedup={hop['static']['speedup']:.2f}x;"
-         f"edges={hop['bucket']['edges']}")
     emit("serving/slo_admitted_p99_us", slo["overload"]["admitted_p99_ms"]
          * 1e3,
          f"hit={slo['overload']['admitted_hit_rate']:.3f};"
@@ -766,21 +729,10 @@ def run(out_path: str = "BENCH_serving.json") -> dict:
          f"recovered={chaos['recovery']['n_recovered_epochs']}ep")
     print(f"# batched drain throughput {bat_tput:.1f} qps vs sequential "
           f"{seq_tput:.1f} qps → {ratio:.2f}x", flush=True)
-    print(f"# fused hop kernel: static {hop['static']['speedup']:.2f}x, "
-          f"bucket {hop['bucket']['speedup']:.2f}x vs materialize+segment_sum",
-          flush=True)
     print(f"# wrote {out_path}", flush=True)
     if os.environ.get("BENCH_ENFORCE") == "1":
         if ratio < 2.0:
             print(f"# FAIL: throughput ratio {ratio:.2f}x < 2x", flush=True)
-            sys.exit(1)
-        # the fused-kernel acceptance floor: a real measured hop-delivery
-        # speedup on both legs (thresholds leave slack for host jitter;
-        # typical measured values are ~3-6x static, ~1.5-1.8x bucket)
-        if hop["static"]["speedup"] < 1.5 or hop["bucket"]["speedup"] < 1.1:
-            print(f"# FAIL: fused hop speedup static "
-                  f"{hop['static']['speedup']:.2f}x (<1.5) or bucket "
-                  f"{hop['bucket']['speedup']:.2f}x (<1.1)", flush=True)
             sys.exit(1)
         # SLO acceptance: at 3x capacity the plain open loop must blow past
         # the deadline while admission holds its admitted queries inside

@@ -17,15 +17,11 @@ reported quantities are measured wall-clock:
     on (benchmarks/fit_cost_model) — keeping the cost model's accuracy claim
     checkable against the executor's real traffic.  The workload includes a
     MIN leg (queries.to_minmax) so the extremum channel is EXERCISED, not
-    structurally zero — all three channels carry measured volume;
-  * hop impl: the same representative superstep timed under both
-    hop-delivery lowerings (xla materialize+segment_sum vs the fused
-    hop_scatter kernel), reported as ``hop_makespan_ms`` per impl and the
-    ``hop_speedup_pallas`` ratio the bench gate pins.
+    structurally zero — all three channels carry measured volume.
 
 Writes ``BENCH_weak_scaling.json`` (per-worker-count rows); the CI bench
 gate (scripts/check_bench.py) pins the structural exchange volumes exactly
-and the efficiency/speedup ratios within a tolerance band.
+and the efficiency ratios within a tolerance band.
 """
 from __future__ import annotations
 
@@ -74,15 +70,6 @@ def run(out_path: str = "BENCH_weak_scaling.json") -> dict:
         weak_eff = min(1.0, (ref / per_edge)) * balance_eff
         xchg = {name: int(channels[i]) // len(wl)
                 for i, name in enumerate(EP.CHANNELS)}
-        # xla-vs-pallas hop timings: the same representative query's
-        # supersteps under both delivery lowerings (bit-identical results;
-        # what differs is the measured per-worker makespan)
-        hop_ms = {}
-        for impl in ("xla", "pallas"):
-            prof_i = EP.measure_supersteps(g, q2.qry, n_workers=w, repeats=2,
-                                           impl=impl)
-            hop_ms[impl] = float(prof_i.makespan_s.sum()) * 1e3
-        hop_speedup = hop_ms["xla"] / max(hop_ms["pallas"], 1e-12)
         rows.append(dict(
             n_workers=w,
             n_persons=BASE * w,
@@ -94,14 +81,12 @@ def run(out_path: str = "BENCH_weak_scaling.json") -> dict:
             exchange_per_query=xchg,
             exchange_volume=arrays.exchange_volume(),
             etr_exchange_volume=arrays.etr_exchange_volume(),
-            hop_makespan_ms=hop_ms,
-            hop_speedup_pallas=hop_speedup,
         ))
         emit(f"weak_scaling/w{w}", makespan * 1e6,
              f"persons={BASE*w};balance_eff={balance_eff*100:.0f}%;"
              f"weak_eff={weak_eff*100:.0f}%;edge_cut={part.stats['edge_cut']*100:.1f}%;"
              f"xchg_state={xchg['state']};xchg_extremum={xchg['extremum']};"
-             f"xchg_etr={xchg['etr']};hop_pallas={hop_speedup:.2f}x")
+             f"xchg_etr={xchg['etr']}")
     report = dict(scale=SCALE, base_persons=BASE, rows=rows)
     with open(out_path, "w") as f:
         json.dump(report, f, indent=2)

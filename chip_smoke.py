@@ -3,7 +3,7 @@
 One chip (the default):
 
   phase A  a 100k-person LDBC-SNB F-S graph (static properties), served
-           through ``BatchScheduler(engine="dense", impl="xla")``: every
+           through ``BatchScheduler(engine="dense")``: every
            static template Q1-Q7 plus the MIN variant of Q2 in one drain,
            then a short open-loop replay.  Every query must end ``done``,
            and one instance per template is checked against the
@@ -120,7 +120,7 @@ def _scheduler(g, **kw):
     from repro.core.planner import DEFAULT_COEFFS
     from repro.serving import BatchScheduler
 
-    return BatchScheduler(g, impl="xla", n_buckets=N_BUCKETS,
+    return BatchScheduler(g, n_buckets=N_BUCKETS,
                           keep_outputs=True, coeffs=dict(DEFAULT_COEFFS), **kw)
 
 
@@ -319,7 +319,7 @@ def phase_partitioned(seed: int, n_persons: int = PHASE_A_PERSONS,
                                    f"{field} differs from the dense engine")
     _log(f"{tag}: all {len(wl)} answers bit-identical to the dense engine")
 
-    gdev, pdev, _, _ = EP.device_tables(g, n_workers, "xla", part.n_devices)
+    gdev, pdev, _ = EP.device_tables(g, n_workers, part.n_devices)
     mesh_devs = set(jax.devices()[:n_workers])
     for name, tree, sharded in (("per-worker", pdev, True),
                                 ("graph", gdev, False)):

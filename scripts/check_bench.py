@@ -111,14 +111,6 @@ CHECKS = [
     ("BENCH_serving.json", "chaos.recovery.recovery_identical", "exact", 0),
     ("BENCH_serving.json", "chaos.recovery.n_recovered_epochs", "exact", 0),
     ("BENCH_serving.json", "chaos.recovery.n_open_survivors", "exact", 0),
-    # ---- fused hop kernel vs materialize+segment_sum: the per-impl hop
-    # timings.  Structural edge counts exact (same seed → same graph); the
-    # speedup ratios in a band (benchmarks/serving.py separately enforces
-    # the ABSOLUTE >1x floor via BENCH_ENFORCE, so the gate only catches a
-    # collapse of the kernel path, not host jitter).
-    ("BENCH_serving.json", "hop_delivery.static.edges", "exact", 0),
-    ("BENCH_serving.json", "hop_delivery.static.speedup", "min_frac", 0.50),
-    ("BENCH_serving.json", "hop_delivery.bucket.speedup", "min_frac", 0.50),
     # ---- weak scaling: efficiency band + structural exchange per row
     ("BENCH_weak_scaling.json", "rows[*].balance_eff", "min_frac", 0.70),
     ("BENCH_weak_scaling.json", "rows[*].weak_eff", "min_frac", 0.55),
@@ -130,8 +122,6 @@ CHECKS = [
     ("BENCH_weak_scaling.json", "rows[*].exchange_per_query.extremum",
      "exact", 0),
     ("BENCH_weak_scaling.json", "rows[*].exchange_per_query.etr", "exact", 0),
-    ("BENCH_weak_scaling.json", "rows[*].hop_speedup_pallas",
-     "min_frac", 0.50),
 ]
 
 _TOKEN = re.compile(r"([A-Za-z0-9_]+)|\[(\*|\d+)\]")

@@ -5,9 +5,6 @@
 #   ./scripts/ci.sh --fast     # tier-1 only (every-push leg)
 #
 # Heavy legs (full gate only):
-#   kernels      the kernel-layer equivalence leg (`-m kernels`): fused hop
-#                kernel vs the XLA hop across modes × aggregates, layout
-#                property tests
 #   serving      the SLO serving layer (`-m serving`): deadline EDF,
 #                admission control, online θ refit, and both replay modes on
 #                the FakeDispatcher virtual clock (tier-1 also runs these;
@@ -27,9 +24,8 @@
 #                docs/*.md parses, the cheap ```bash run blocks execute,
 #                and every file:line anchor points at a real line
 #   conformance  the four-way differential matrix at CONFORMANCE_SCALE=ci
-#                (full worker sweep + all ETR operators + the pallas impl
-#                axis), selected with `-m conformance` — tier-1 already runs
-#                it at smoke scale
+#                (full worker sweep + all ETR operators), selected with
+#                `-m conformance` — tier-1 already runs it at smoke scale
 #   multidevice  shard_map-native batched serving on 8 forced host devices
 #                (XLA_FLAGS), bit-identity vs the vmap simulation
 #   smokes       engine-vs-oracle and workload/scheduler sweeps
@@ -47,8 +43,6 @@ echo "== tier-1: pytest (markers 'slow'/'multidevice' deselected by pytest.ini) 
 python -m pytest -x -q
 
 if [[ "${1:-}" != "--fast" ]]; then
-  echo "== kernels: fused hop kernel vs XLA hop equivalence (-m kernels) =="
-  python -m pytest -m kernels -x -q
   echo "== serving SLO: deadlines/EDF, admission, online refit, replay (-m serving) =="
   python -m pytest -m serving -x -q
   echo "== obs: flight recorder spans, metrics, cost-model audit (-m obs) =="

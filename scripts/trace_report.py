@@ -35,7 +35,7 @@ BAR_W = 32
 _HEADLINE = {
     "query": ("template", "status", "latency_ms"),
     "admit": ("verdict", "rungs"),
-    "plan": ("split", "impl", "plan_cached", "predicted_ms"),
+    "plan": ("split", "plan_cached", "predicted_ms"),
     "compile": ("cache", "key"),
     "dispatch": ("seq", "batch", "edf_pos", "predicted_ms", "measured_ms",
                  "group_measured_ms"),
@@ -44,7 +44,7 @@ _HEADLINE = {
     "superstep": ("hop", "etr", "predicted_ms", "apportioned_ms",
                   "measured_ms"),
     "exchange": ("state", "extremum", "etr"),
-    "measure_supersteps": ("n_workers", "n_hops", "impl"),
+    "measure_supersteps": ("n_workers", "n_hops", "mode"),
 }
 
 

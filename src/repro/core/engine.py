@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.hop_scatter import require_impl
 from ..obs.trace import scope
 from . import intervals as iv
 from . import query as Q
@@ -97,8 +96,6 @@ def run_segment(
     with_minmax: bool = False,
     minmax_op: int = Q.AGG_MIN,
     minmax_col=None,
-    impl: str = "xla",
-    layout=None,
     delta=None,
 ) -> SegmentResult:
     """Run one path segment.  v_preds has one more entry than e_preds; the
@@ -108,26 +105,15 @@ def run_segment(
     base+delta execution path: every plain hop also evaluates the edge
     predicate over the delta-edge slots and merges their (unsorted)
     delivery into the base arrivals — bit-identical to running on the
-    merged epoch graph, with the base graph's compiled layout untouched.
+    merged epoch graph, with the base graph's compiled executables untouched.
     ETR hops read global rank tables and are delta-incompatible (callers
     gate on query shape; ``batch_executable_delta`` refuses them).
-
-    ``impl``/``layout`` select the delivery lowering: with a
-    ``kernels.hop_scatter.HopLayout`` over the graph's arrival-sorted
-    traversal edges, every plain hop runs the FUSED gather → temporal mask →
-    segment-reduce kernel (``superstep.fused_hop_deliver``; the extremum
-    channel rides the same call) and ETR-hop deliveries run the blocked
-    scatter kernel.  The per-edge count chain is still traced for the
-    consumers that need per-edge state (ETR prefix sums, the ETR-at-join
-    contraction) — when nothing reads it, jit DCE drops it, which is what
-    makes the fused path materialisation-free end to end.
 
     Returns raw arrivals (per-edge and per-vertex) at the final vertex.
     """
     V = gdev["v_life"].shape[0]
     stats: List[dict] = []
     bedges = SS.current_bedges()
-    fused = SS.use_pallas(impl) and layout is not None
 
     # ---- init superstep (first vertex predicate)
     vm, vv = SS.vertex_predicate(gdev, v_preds[0], params, pbases_v[0], mode,
@@ -188,38 +174,22 @@ def run_segment(
             d_add, d_mm = SS.delta_hop_deliver(
                 delta, ep, sv, params, pbases_e[i], mode, V,
                 mch=(mch_v if with_minmax else None), minmax_op=minmax_op)
-        if fused and ep.etr_op == -1:
-            # fused kernel hop: arrivals (and the extremum channel) come from
-            # ONE VMEM pass over the state table — cnt_e above stays traced
-            # only for per-edge consumers (ETR, join) and is DCE'd otherwise
-            arrivals_v, mch_new = SS.fused_hop_deliver(
-                sv, gdev["t_src"], wmask, evalidity, mode, layout.tables,
-                layout.block_v, V, impl=impl,
-                mch=(mch_v if with_minmax else None), minmax_op=minmax_op)
-            if with_minmax:
-                mch_v = mch_new
-        else:
-            arrivals_v = SS.deliver(cnt_e, gdev["t_dst"], V, impl=impl,
-                                    layout=layout, ptr=gdev["arr_ptr"])
-            if with_minmax:
-                with scope("src_gather"):
-                    m_src = mch_v[gdev["t_src"]]
-                m_e = SS.minmax_edge(m_src, cnt_e, minmax_op, mode)
-                mch_v = SS.deliver_extremum(m_e, gdev["t_dst"], V, minmax_op,
-                                            impl=impl, layout=layout,
-                                            ptr=gdev["arr_ptr"])
+        arrivals_v = SS.deliver(cnt_e, gdev["t_dst"], V, ptr=gdev["arr_ptr"])
+        if with_minmax:
+            with scope("src_gather"):
+                m_src = mch_v[gdev["t_src"]]
+            m_e = SS.minmax_edge(m_src, cnt_e, minmax_op, mode)
+            mch_v = SS.deliver_extremum(m_e, gdev["t_dst"], V, minmax_op,
+                                        ptr=gdev["arr_ptr"])
         if d_add is not None:
             arrivals_v = arrivals_v + d_add
             if with_minmax:
                 comb = jnp.minimum if minmax_op == Q.AGG_MIN else jnp.maximum
                 mch_v = comb(mch_v, d_mm)
         stat = dict(phase=f"hop{i}", matched_edges=jnp.sum(wmask))
-        if not fused:
-            # per-edge activity would force the materialisation the fused
-            # path exists to avoid; report it on the XLA path only
-            stat["active_edges"] = jnp.sum(
-                (src_val if mode == MODE_STATIC else src_val.sum(
-                    axis=tuple(range(1, src_val.ndim)))) > 0)
+        stat["active_edges"] = jnp.sum(
+            (src_val if mode == MODE_STATIC else src_val.sum(
+                axis=tuple(range(1, src_val.ndim)))) > 0)
         stats.append(stat)
 
     return SegmentResult(arrivals_e, arrivals_v, stats, mch_v)
@@ -245,8 +215,6 @@ def execute_plan_traced(
     params,
     bedges,
     segment_runner=None,
-    impl: str = "xla",
-    layout=None,
     delta=None,
 ):
     """Traceable plan execution.  All query structure is Python-static.
@@ -254,14 +222,11 @@ def execute_plan_traced(
     ``segment_runner`` (defaults to the dense ``run_segment``) lets other
     executors reuse the split/join skeleton: it must return a SegmentResult
     whose arrivals live in GLOBAL vertex/traversal-edge space.
-    ``impl``/``layout``/``delta`` only parameterise the DEFAULT dense
-    runner — other executors thread their own delivery lowering through
-    their runner.
+    ``delta`` only parameterises the DEFAULT dense runner.
     """
     with SS.bucket_scope(bedges):
         return _execute_plan_inner(gdev, qry, split, mode, n_buckets, params,
-                                   segment_runner, impl=impl, layout=layout,
-                                   delta=delta)
+                                   segment_runner, delta=delta)
 
 
 def _pbases(qry: Q.PathQuery):
@@ -278,8 +243,7 @@ def _pbases(qry: Q.PathQuery):
 
 
 def _execute_plan_inner(gdev, qry, split, mode, n_buckets, params,
-                        segment_runner=None, impl: str = "xla", layout=None,
-                        delta=None):
+                        segment_runner=None, delta=None):
     n = qry.n_vertices
     assert 0 <= split < n
     pv, pe = _pbases(qry)
@@ -287,8 +251,7 @@ def _execute_plan_inner(gdev, qry, split, mode, n_buckets, params,
     runner = segment_runner
     if runner is None:
         def runner(*a, **kw):
-            return run_segment(gdev, *a, impl=impl, layout=layout,
-                               delta=delta, **kw)
+            return run_segment(gdev, *a, delta=delta, **kw)
 
     want_agg = qry.agg_op != Q.AGG_NONE
     want_minmax = qry.agg_op in (Q.AGG_MIN, Q.AGG_MAX)
@@ -401,34 +364,6 @@ def _join(gdev, qry, split, mode, n_buckets, params, pbase, bedges, left,
 # =========================================================================
 _JIT_CACHE: Dict[tuple, callable] = {}
 
-#: engine-level implementation axis — the kernels' shared idiom
-#: ('xla' | 'pallas' | 'pallas_interpret'), validated by hop_scatter.require_impl
-from ..kernels.common import IMPLS as HOP_IMPLS  # noqa: E402
-
-
-def hop_layout_for(graph: TemporalGraph, block_v: Optional[int] = None,
-                   block_e_mult: int = 512):
-    """The dense executor's static HopLayout (whole-graph arrival-sorted
-    traversal edges → destination blocks), cached ON the graph object like
-    its device-array cache so the layout's lifetime is tied to the graph.
-    ``block_v=None`` auto-sizes (one block on the CPU interpreter; TPU
-    deployments pass an explicit VMEM-shaped block)."""
-    from ..kernels.hop_scatter import build_hop_layout
-
-    cache = getattr(graph, "_hop_layout_cache", None)
-    if cache is None:
-        cache = {}
-        graph._hop_layout_cache = cache
-    key = ("dense", block_v, block_e_mult)
-    lay = cache.get(key)
-    if lay is None:
-        seg = np.asarray(graph.traversal["t_dst"])
-        lay = build_hop_layout(seg, graph.n_vertices, block_v=block_v,
-                               block_e_mult=block_e_mult)
-        cache[key] = lay
-    return lay
-
-
 def _prepare_gdev(graph: TemporalGraph) -> dict:
     g = dict(graph.device_arrays())
     # edge property columns gathered into traversal space (2E), lazily cached
@@ -449,17 +384,13 @@ def execute(
     mode: int = MODE_STATIC,
     n_buckets: int = 16,
     sliced: Optional[bool] = None,
-    impl: str = "xla",
 ) -> ExecOutput:
     """Execute a path query with the given plan (split point).
 
     split=None defaults to left-to-right (split = n-1) for plain queries and
     right-to-left (split = 0) for aggregates.  ``sliced`` selects the
-    type-sliced optimised path (engine_sliced.py); None = auto.  ``impl``
-    selects the hop-delivery lowering (``HOP_IMPLS``): ``'pallas'`` runs the
-    fused hop kernel over the graph's static block layout (interpreter mode
-    auto-selected on CPU backends only).  For the partition-sharded
-    distributed path use ``engine_partitioned.execute``.
+    type-sliced optimised path (engine_sliced.py); None = auto.  For the
+    partition-sharded distributed path use ``engine_partitioned.execute``.
     """
     if split is None:
         split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
@@ -475,25 +406,20 @@ def execute(
     if use_sliced and not ES.sliceable(qry):
         raise ValueError("query not sliceable (wildcard vertex type)")
     key = (id(graph), qry.shape_key(), split, mode, n_buckets,
-           bool(use_sliced), require_impl(impl))
+           bool(use_sliced))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         if use_sliced:
             sb = ES.SliceBounds.from_graph(graph)
-            layouts = ES.slice_layouts_for(graph, qry, sb, impl)
 
             def traced(gd, params, be):
                 out = ES.execute_plan_sliced(gd, qry, split, mode, n_buckets,
-                                             params, be, sb, impl=impl,
-                                             layouts=layouts)
+                                             params, be, sb)
                 return out.total, out.per_vertex, out.minmax, []
         else:
-            layout = hop_layout_for(graph) if SS.use_pallas(impl) else None
-
             def traced(gd, params, be):
                 out = execute_plan_traced(gd, qry, split, mode, n_buckets,
-                                          params, be, impl=impl,
-                                          layout=layout)
+                                          params, be)
                 return (
                     out.total,
                     out.per_vertex,
@@ -555,7 +481,6 @@ def batch_executable(
     mode: int = MODE_STATIC,
     n_buckets: int = 16,
     sliced: Optional[bool] = None,
-    impl: str = "xla",
 ):
     """Compiled batched entry for one query shape (the serving runtime's
     executable unit).
@@ -579,25 +504,20 @@ def batch_executable(
     if use_sliced and not ES.sliceable(qry):
         raise ValueError("query not sliceable (wildcard vertex type)")
     key = ("batch", id(graph), qry.shape_key(), split, mode, n_buckets,
-           bool(use_sliced), require_impl(impl))
+           bool(use_sliced))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         if use_sliced:
             sb = ES.SliceBounds.from_graph(graph)
-            layouts = ES.slice_layouts_for(graph, qry, sb, impl)
 
             def one(gd, params, be):
                 out = ES.execute_plan_sliced(gd, qry, split, mode, n_buckets,
-                                             params, be, sb, impl=impl,
-                                             layouts=layouts)
+                                             params, be, sb)
                 return out.total, out.per_vertex, out.minmax
         else:
-            layout = hop_layout_for(graph) if SS.use_pallas(impl) else None
-
             def one(gd, params, be):
                 out = execute_plan_traced(gd, qry, split, mode, n_buckets,
-                                          params, be, impl=impl,
-                                          layout=layout)
+                                          params, be)
                 return out.total, out.per_vertex, out.minmax
 
         one.__name__ = program_name("sliced" if use_sliced else "dense", qry,
@@ -630,7 +550,6 @@ def batch_executable_delta(
     split: Optional[int] = None,
     mode: int = MODE_STATIC,
     n_buckets: int = 16,
-    impl: str = "xla",
 ):
     """Base+delta twin of ``batch_executable`` for live-graph serving.
 
@@ -657,16 +576,12 @@ def batch_executable_delta(
     bedges = jnp.asarray(
         iv.bucket_edges(graph.lifespan[0], graph.lifespan[1], n_buckets)
     )
-    key = ("batch_delta", id(graph), qry.shape_key(), split, mode, n_buckets,
-           require_impl(impl))
+    key = ("batch_delta", id(graph), qry.shape_key(), split, mode, n_buckets)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        layout = hop_layout_for(graph) if SS.use_pallas(impl) else None
-
         def one(gd, params, be, delta):
             out = execute_plan_traced(gd, qry, split, mode, n_buckets,
-                                      params, be, impl=impl, layout=layout,
-                                      delta=delta)
+                                      params, be, delta=delta)
             return out.total, out.per_vertex, out.minmax
 
         one.__name__ = program_name("dense_delta", qry, split, mode)
@@ -688,13 +603,11 @@ def execute_batch_out(
     mode: int = MODE_STATIC,
     n_buckets: int = 16,
     sliced: Optional[bool] = None,
-    impl: str = "xla",
 ) -> ExecOutput:
     """Batched execution of same-shape instances; full ExecOutput with a
     leading query axis on every field (aggregates included)."""
     check_batch_shape(queries)
-    run = batch_executable(graph, queries[0], split, mode, n_buckets, sliced,
-                           impl=impl)
+    run = batch_executable(graph, queries[0], split, mode, n_buckets, sliced)
     params = np.stack([Q.query_params(q) for q in queries])
     return run(params)
 
@@ -706,7 +619,6 @@ def execute_batch(
     mode: int = MODE_STATIC,
     n_buckets: int = 16,
     sliced: Optional[bool] = None,
-    impl: str = "xla",
 ) -> np.ndarray:
     """Batched execution of query instances sharing one template shape.
 
@@ -720,6 +632,6 @@ def execute_batch(
     For aggregates / per-vertex outputs use ``execute_batch_out``.
     """
     return np.asarray(
-        execute_batch_out(graph, queries, split, mode, n_buckets, sliced,
-                          impl=impl).total
+        execute_batch_out(graph, queries, split, mode, n_buckets,
+                          sliced).total
     )

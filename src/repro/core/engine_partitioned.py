@@ -61,7 +61,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..kernels import hop_scatter as HK
 from . import intervals as iv
 from . import query as Q
 from . import superstep as SS
@@ -162,22 +161,13 @@ def _exchange_state(state_w, pdev, axis_name, fill=0.0):
 
 
 def _local_hop_p2p(state_w, wmask, evalid, pdev, mode: int, axis_name,
-                   mch_w=None, minmax_op: int = Q.AGG_MIN, impl: str = "xla",
-                   hop_block_v: int = 256):
+                   mch_w=None, minmax_op: int = Q.AGG_MIN):
     """One superstep on owner-local state.
 
     state_w [Wl, Vmax, *TS] is the owned-vertex state; ``wmask``/``evalid``
     are the (replicated) global edge-predicate results, gathered at owned
     edges.  When ``mch_w`` [Wl, Vmax] is given, the extremum channel is
     exchanged and delivered alongside on the same lanes.
-
-    With ``impl='pallas'`` (per-worker layout tables ``hop_*`` in ``pdev``)
-    each worker's local compute is the FUSED hop kernel mapped over the
-    worker axis: gather from the exchanged halo slice → edge apply →
-    blocked segment-reduce in VMEM, the extremum channel riding the same
-    kernel call.  The per-edge count chain is still traced for the
-    publishers that need it (segment-end arrivals_e, next-hop ETR prefix
-    sums) and DCE'd when nothing does.
 
     Returns (cnt_w [Wl, Emax, *TS], arrivals_w [Wl, Vmax, *TS], mch or None).
     """
@@ -193,25 +183,6 @@ def _local_hop_p2p(state_w, wmask, evalid, pdev, mode: int, axis_name,
     ev_flat = None if evalid is None else flat(_shard_rows(evalid, edge_ids))
     cnt = SS.apply_edge(flat(src_val), flat(wmask_w), ev_flat, mode)
     cnt_w = cnt.reshape((Wl, Emax) + cnt.shape[1:])
-    if SS.use_pallas(impl) and "hop_gather" in pdev:
-        neutral = SS.minmax_neutral(minmax_op)
-        nul = jnp.zeros((), jnp.float32)
-        ev_arg = nul if evalid is None else _shard_rows(evalid, edge_ids)
-        mh_arg = (nul if mch_w is None else
-                  _exchange_state(mch_w, pdev, axis_name, fill=neutral))
-
-        def one(h, s, wm, ev, lt, mh):
-            return SS.fused_hop_deliver(
-                h, s, wm, ev, mode, lt, hop_block_v, v_max + 1,
-                impl=impl, mch=mh, minmax_op=minmax_op)
-
-        arr, mch_out = jax.vmap(
-            one, in_axes=(0, 0, 0, 0 if evalid is not None else None,
-                          0, 0 if mch_w is not None else None),
-        )(halo, pdev["src_halo"], wmask_w, ev_arg, HK.worker_tables(pdev),
-          mh_arg)
-        return cnt_w, arr[:, :v_max], (
-            None if mch_out is None else mch_out[:, :v_max])
     # local delivery: per-worker sorted segment-sum (pad edges hit the trash
     # segment v_max, sliced off)
     arrivals_w = jax.vmap(
@@ -284,12 +255,9 @@ def _etr_apply_sources(summ_flat, vm, vv, tsrc_flat, mode: int):
 
 
 def _etr_hop_p2p(gdev, pdev, cnt_prev_w, vm, vv, wmask, evalid, op: int,
-                 backward: bool, mode: int, axis_name, impl: str = "xla",
-                 hop_block_v: int = 256):
+                 backward: bool, mode: int, axis_name):
     """One ETR superstep on owner-local state: produce → exchange →
-    consumer edge apply + local delivery.  The per-edge counts exist here by
-    construction (the rank summaries are per-edge), so the kernel path uses
-    the delivery-only blocked scatter, not the fused hop."""
+    consumer edge apply + local delivery."""
     edge_ids = pdev["edge_ids"]
     Wl, Emax = edge_ids.shape
     v_max = pdev["own_ids"].shape[1]
@@ -301,15 +269,9 @@ def _etr_hop_p2p(gdev, pdev, cnt_prev_w, vm, vv, wmask, evalid, op: int,
     ev_flat = None if evalid is None else flat(_shard_rows(evalid, edge_ids))
     cnt = SS.apply_edge(sv, flat(_shard_rows(wmask, edge_ids)), ev_flat, mode)
     cnt_w = cnt.reshape((Wl, Emax) + cnt.shape[1:])
-    if SS.use_pallas(impl) and "hop_gather" in pdev:
-        arrivals_w = jax.vmap(
-            lambda c, lt: HK.scatter_deliver(
-                c, lt, v_max + 1, hop_block_v, impl=impl)
-        )(cnt_w, HK.worker_tables(pdev))[:, :v_max]
-    else:
-        arrivals_w = jax.vmap(
-            lambda c, d: SS.deliver(c, d, v_max + 1)
-        )(cnt_w, pdev["dst_local"])[:, :v_max]
+    arrivals_w = jax.vmap(
+        lambda c, d: SS.deliver(c, d, v_max + 1)
+    )(cnt_w, pdev["dst_local"])[:, :v_max]
     return cnt_w, arrivals_w
 
 
@@ -320,8 +282,6 @@ def run_segment_partitioned(
     gdev: dict,
     pdev: dict,
     axis_name: Optional[str],
-    impl: str,
-    hop_block_v: int,
     v_preds: Sequence[Q.VertexPredicate],
     e_preds: Sequence[Q.EdgePredicate],
     params,
@@ -377,7 +337,7 @@ def run_segment_partitioned(
                     "min/max aggregation across ETR hops")
             cnt_w, arrivals_w = _etr_hop_p2p(
                 gdev, pdev, cnt_w, vm, vv, wmask, evalid, ep.etr_op,
-                backward, mode, axis_name, impl, hop_block_v)
+                backward, mode, axis_name)
         else:
             if i > 0:
                 vm_w, vv_w = _gather_vpred_w(vm, vv, own_ids)
@@ -386,7 +346,7 @@ def run_segment_partitioned(
                 state_w = state.reshape((Wl, Vmax) + state.shape[1:])
             cnt_w, arrivals_w, mch_w = _local_hop_p2p(
                 state_w, wmask, evalid, pdev, mode, axis_name,
-                mch_w, minmax_op, impl, hop_block_v)
+                mch_w, minmax_op)
         stats.append(dict(phase=f"hop{i}", matched_edges=jnp.sum(wmask)))
 
     # publish the segment's GLOBAL views (the skeleton joins in global
@@ -466,29 +426,16 @@ def partition_for(graph: TemporalGraph, n_workers: int,
     return hit
 
 
-def _with_hop_layouts(pdev: dict, arrays, impl: str):
-    """Merge the per-worker hop-kernel layout tables into the device tables
-    when the kernel path is selected.  The layout tensors have the worker
-    axis leading, so they shard over the ``workers`` mesh axis exactly like
-    the partitioner's other padded tables."""
-    if not SS.use_pallas(HK.require_impl(impl)):
-        return pdev, 0
-    tables, block_v = arrays.worker_hop_layouts()
-    return {**pdev, **tables}, block_v
-
-
-def device_tables(graph: TemporalGraph, n_workers: int, impl: str = "xla",
-                  n_devices: int = 1,
+def device_tables(graph: TemporalGraph, n_workers: int, n_devices: int = 1,
                   parts_per_type: Optional[int] = None):
-    """(graph tables, per-worker tables, PartitionArrays, hop block_v) as
-    the executor consumes them.  On one device they are the graph's cached
+    """(graph tables, per-worker tables, PartitionArrays) as the executor
+    consumes them.  On one device they are the graph's cached
     device arrays.  Over ``n_devices`` they are placed once where the
     shard_map program reads them — per-worker tables sharded over the
     ``workers`` mesh axis, graph tables replicated on every device — and
     cached on the graph, so no dispatch re-sends them from device 0."""
     gdev = _prepare_gdev(graph)
     _, arrays, pdev = partition_for(graph, n_workers, parts_per_type)
-    pdev, hop_block_v = _with_hop_layouts(pdev, arrays, impl)
     if n_devices > 1:
         from ..launch.mesh import make_worker_mesh
 
@@ -496,14 +443,14 @@ def device_tables(graph: TemporalGraph, n_workers: int, impl: str = "xla",
         if cache is None:
             cache = {}
             graph._placed_tables = cache
-        key = (n_workers, parts_per_type, impl, n_devices)
+        key = (n_workers, parts_per_type, n_devices)
         if key not in cache:
             mesh = make_worker_mesh(n_devices)
             cache[key] = (
                 jax.device_put(gdev, NamedSharding(mesh, P())),
                 jax.device_put(pdev, NamedSharding(mesh, P("workers"))))
         gdev, pdev = cache[key]
-    return gdev, pdev, arrays, hop_block_v
+    return gdev, pdev, arrays
 
 
 def resolve_n_devices(requested: Optional[bool], n_workers: int) -> int:
@@ -516,18 +463,14 @@ def resolve_n_devices(requested: Optional[bool], n_workers: int) -> int:
     return nd
 
 
-def _plan_fn(qry, split, mode, n_buckets, n_devices, batched: bool = False,
-             impl: str = "xla", hop_block_v: int = 256):
+def _plan_fn(qry, split, mode, n_buckets, n_devices, batched: bool = False):
     """Build the jitted (possibly shard_mapped) plan callable — the ONE
     construction both the sequential ``execute`` and the serving
     ``batch_executable`` entries share.  ``batched`` vmaps the params axis;
     on the sharded path that vmap sits INSIDE the shard_map body, so one
-    dispatch runs (batch × workers) on the device mesh.  ``impl`` selects
-    the per-worker delivery lowering (the fused hop kernel reads the
-    ``hop_*`` layout tables riding in ``pd``)."""
+    dispatch runs (batch × workers) on the device mesh."""
     def plan(gd, pd, params, be, axis_name):
-        runner = partial(run_segment_partitioned, gd, pd, axis_name, impl,
-                         hop_block_v)
+        runner = partial(run_segment_partitioned, gd, pd, axis_name)
         out = execute_plan_traced(gd, qry, split, mode, n_buckets, params,
                                   be, segment_runner=runner)
         return out.total, out.per_vertex, out.minmax
@@ -554,7 +497,6 @@ def execute(
     n_workers: int = 4,
     parts_per_type: Optional[int] = None,
     use_shard_map: Optional[bool] = None,
-    impl: str = "xla",
 ) -> ExecOutput:
     """Partition-sharded execution; identical results to ``engine.execute``.
 
@@ -562,23 +504,20 @@ def execute(
     When >1 JAX devices exist and divide ``n_workers``, the whole plan runs
     under shard_map on a ``workers`` device mesh (point-to-point exchange
     between supersteps); otherwise the worker axis is vmapped on one device.
-    ``impl='pallas'`` runs each worker's local hop through the fused kernel
-    over its shard's block layout (``PartitionArrays.worker_hop_layouts``).
     """
     if split is None:
         split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
     n_devices = resolve_n_devices(use_shard_map, n_workers)
-    gdev, pdev, arrays, hop_block_v = device_tables(
-        graph, n_workers, impl, n_devices, parts_per_type)
+    gdev, pdev, arrays = device_tables(graph, n_workers, n_devices,
+                                       parts_per_type)
     bedges = jnp.asarray(
         iv.bucket_edges(graph.lifespan[0], graph.lifespan[1], n_buckets)
     )
     key = (id(graph), qry.shape_key(), split, mode, n_buckets, n_workers,
-           arrays.v_max, n_devices, HK.require_impl(impl))
+           arrays.v_max, n_devices)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = _plan_fn(qry, split, mode, n_buckets, n_devices, impl=impl,
-                      hop_block_v=hop_block_v)
+        fn = _plan_fn(qry, split, mode, n_buckets, n_devices)
         _JIT_CACHE[key] = fn
     params = jnp.asarray(Q.query_params(qry))
     total, per_vertex, minmax = fn(gdev, pdev, params, bedges)
@@ -630,7 +569,6 @@ def batch_executable(
     n_workers: int = 4,
     parts_per_type: Optional[int] = None,
     use_shard_map: Optional[bool] = None,
-    impl: str = "xla",
 ):
     """Compiled batched entry on the DISTRIBUTED path: the whole superstep
     pipeline (p2p halo exchange → local delivery → segment-end publish) runs
@@ -646,17 +584,16 @@ def batch_executable(
     if split is None:
         split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
     n_devices = resolve_n_devices(use_shard_map, n_workers)
-    gdev, pdev, arrays, hop_block_v = device_tables(
-        graph, n_workers, impl, n_devices, parts_per_type)
+    gdev, pdev, arrays = device_tables(graph, n_workers, n_devices,
+                                       parts_per_type)
     bedges = jnp.asarray(
         iv.bucket_edges(graph.lifespan[0], graph.lifespan[1], n_buckets)
     )
     key = ("batch", id(graph), qry.shape_key(), split, mode, n_buckets,
-           n_workers, arrays.v_max, n_devices, HK.require_impl(impl))
+           n_workers, arrays.v_max, n_devices)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = _plan_fn(qry, split, mode, n_buckets, n_devices, batched=True,
-                      impl=impl, hop_block_v=hop_block_v)
+        fn = _plan_fn(qry, split, mode, n_buckets, n_devices, batched=True)
         _JIT_CACHE[key] = fn
 
     def run(params) -> ExecOutput:
@@ -676,14 +613,12 @@ def execute_batch_out(
     n_workers: int = 4,
     parts_per_type: Optional[int] = None,
     use_shard_map: Optional[bool] = None,
-    impl: str = "xla",
 ) -> ExecOutput:
     """Batched partitioned execution of same-shape instances."""
     from .engine import check_batch_shape
     check_batch_shape(queries)
     run = batch_executable(graph, queries[0], split, mode, n_buckets,
-                           n_workers, parts_per_type, use_shard_map,
-                           impl=impl)
+                           n_workers, parts_per_type, use_shard_map)
     params = np.stack([Q.query_params(q) for q in queries])
     return run(params)
 
@@ -719,18 +654,16 @@ _PROFILE_CACHE: Dict[tuple, dict] = {}
 
 def _profile_fns(qry: Q.PathQuery, mode: int, n_buckets: int, v_max: int,
                  v_preds, e_preds, pv, pe, backward: bool,
-                 with_minmax: bool, minmax_op: int, impl: str = "xla",
-                 hop_block_v: int = 0) -> dict:
+                 with_minmax: bool, minmax_op: int) -> dict:
     """Jitted helpers for measure_supersteps, cached per (query shape, mode,
-    buckets, padded worker extent, impl) so repeated profiling of one
+    buckets, padded worker extent) so repeated profiling of one
     template (weak_scaling, fit_cost_model) re-traces nothing.  All graph
     data is passed as arguments; only static query structure is baked in."""
     # shape_key() covers agg_op/agg_key, i.e. the full profiled structure
-    key = (qry.shape_key(), mode, n_buckets, v_max, impl)
+    key = (qry.shape_key(), mode, n_buckets, v_max)
     fns = _PROFILE_CACHE.get(key)
     if fns is not None:
         return fns
-    fused = SS.use_pallas(impl)
 
     def vpred(i):
         def f(gd, prm, be):
@@ -786,52 +719,25 @@ def _profile_fns(qry: Q.PathQuery, mode: int, n_buckets: int, v_max: int,
     # worker's tables arrive with a leading axis of 1 so shapes agree.  The
     # halo buffer arrives pre-exchanged; the TIMED work is the local gather,
     # edge apply and delivery — the per-worker compute a real deployment's
-    # straggler/makespan comes from.  On the kernel path that work is ONE
-    # fused hop-kernel call; the per-edge counts are produced only by the
-    # ``with_cnt`` variant, selected per hop by whether the NEXT hop's ETR
-    # producer actually consumes them (so the timing never pays for a chain
-    # the executor's jit would have DCE'd).
-    def make_one_worker_hop(with_cnt: bool):
-        @jax.jit
-        def one_worker_hop(halo_1, wm, ev, eids, dloc, lt, shalo, mch_halo,
-                           be):
-            with SS.bucket_scope(be):
-                e_max = eids.shape[1]
-                flatten = lambda a: a.reshape((e_max,) + a.shape[2:])
-                evf = None if not ev.ndim else flatten(_shard_rows(ev, eids))
-                if fused:
-                    mh = mch_halo[0] if mch_halo.ndim else None
-                    ev_w = None if not ev.ndim else _shard_rows(ev, eids)[0]
-                    arr, mch = SS.fused_hop_deliver(
-                        halo_1[0], shalo[0], _shard_rows(wm, eids)[0], ev_w,
-                        mode, {k: v[0] for k, v in lt.items()}, hop_block_v,
-                        v_max + 1, impl=impl, mch=mh, minmax_op=minmax_op)
-                    arr = arr[:v_max]
-                    mch = (mch[:v_max][None] if mch is not None
-                           else jnp.zeros((), jnp.float32))
-                else:
-                    src_val = _halo_gather(halo_1, shalo)
-                    cnt = SS.apply_edge(flatten(src_val),
-                                        flatten(_shard_rows(wm, eids)), evf,
-                                        mode)
-                    arr = SS.deliver(cnt, dloc[0], v_max + 1)[:v_max]
-                    if mch_halo.ndim:
-                        m_src = _halo_gather(mch_halo, shalo)
-                        m_e = SS.minmax_edge(flatten(m_src), cnt, minmax_op,
-                                             mode)
-                        mch = SS.deliver_extremum(m_e, dloc[0], v_max + 1,
-                                                  minmax_op)[:v_max][None]
-                    else:
-                        mch = jnp.zeros((), jnp.float32)
-                if not with_cnt:
-                    return jnp.zeros((), jnp.float32), arr[None], mch
-                if fused:
-                    src_val = _halo_gather(halo_1, shalo)
-                    cnt = SS.apply_edge(flatten(src_val),
-                                        flatten(_shard_rows(wm, eids)), evf,
-                                        mode)
-                return cnt[None], arr[None], mch
-        return one_worker_hop
+    # straggler/makespan comes from.
+    @jax.jit
+    def one_worker_hop(halo_1, wm, ev, eids, dloc, shalo, mch_halo, be):
+        with SS.bucket_scope(be):
+            e_max = eids.shape[1]
+            flatten = lambda a: a.reshape((e_max,) + a.shape[2:])
+            evf = None if not ev.ndim else flatten(_shard_rows(ev, eids))
+            src_val = _halo_gather(halo_1, shalo)
+            cnt = SS.apply_edge(flatten(src_val),
+                                flatten(_shard_rows(wm, eids)), evf, mode)
+            arr = SS.deliver(cnt, dloc[0], v_max + 1)[:v_max]
+            if mch_halo.ndim:
+                m_src = _halo_gather(mch_halo, shalo)
+                m_e = SS.minmax_edge(flatten(m_src), cnt, minmax_op, mode)
+                mch = SS.deliver_extremum(m_e, dloc[0], v_max + 1,
+                                          minmax_op)[:v_max][None]
+            else:
+                mch = jnp.zeros((), jnp.float32)
+            return cnt[None], arr[None], mch
 
     # ETR producer body: segment-local prefix tables over the worker's owned
     # prev-hop counts → rank summaries for the edges whose source it owns.
@@ -850,11 +756,9 @@ def _profile_fns(qry: Q.PathQuery, mode: int, n_buckets: int, v_max: int,
         return _exchange_etr(out_w, pd, None)
 
     # ETR consumer body: the received summaries are the exchanged state; the
-    # local part is source-predicate apply + edge apply + delivery (counts
-    # are per-edge by construction here, so the kernel path is the blocked
-    # delivery-only scatter).
+    # local part is source-predicate apply + edge apply + delivery.
     @jax.jit
-    def one_worker_etr(summ_1, m, v, tsrc, wm, ev, eids, dloc, lt, be):
+    def one_worker_etr(summ_1, m, v, tsrc, wm, ev, eids, dloc, be):
         with SS.bucket_scope(be):
             e_max = eids.shape[1]
             flatten = lambda a: a.reshape((e_max,) + a.shape[2:])
@@ -863,13 +767,7 @@ def _profile_fns(qry: Q.PathQuery, mode: int, n_buckets: int, v_max: int,
                                     _shard_rows(tsrc, eids).reshape(-1), mode)
             evf = None if not ev.ndim else flatten(_shard_rows(ev, eids))
             cnt = SS.apply_edge(sv, flatten(_shard_rows(wm, eids)), evf, mode)
-            if fused:
-                arr = HK.scatter_deliver(cnt, {k: x[0] for k, x in
-                                               lt.items()},
-                                         v_max + 1, hop_block_v,
-                                         impl=impl)[:v_max]
-            else:
-                arr = SS.deliver(cnt, dloc[0], v_max + 1)[:v_max]
+            arr = SS.deliver(cnt, dloc[0], v_max + 1)[:v_max]
             return cnt[None], arr[None]
 
     @jax.jit
@@ -891,8 +789,7 @@ def _profile_fns(qry: Q.PathQuery, mode: int, n_buckets: int, v_max: int,
         exchange_state_fn=exchange_state_fn,
         exchange_mch_fn=exchange_mch_fn,
         exchange_etr_fn=exchange_etr_fn,
-        one_worker_hop=make_one_worker_hop(with_cnt=True),
-        one_worker_hop_light=make_one_worker_hop(with_cnt=False),
+        one_worker_hop=one_worker_hop,
         one_worker_etr=one_worker_etr,
         total_fn=total_fn,
     )
@@ -908,7 +805,6 @@ def measure_supersteps(
     n_buckets: int = 16,
     parts_per_type: Optional[int] = None,
     repeats: int = 2,
-    impl: str = "xla",
     tracer=None,
 ) -> SuperstepProfile:
     """Measured (not modelled) per-worker superstep times.
@@ -918,10 +814,6 @@ def measure_supersteps(
     the per-worker measured times) → exchange (per-channel boundary
     volumes) — the same schema the serving flight recorder emits, so
     trace_report renders profiler runs and served queries alike.
-
-    ``impl`` selects the timed local-hop lowering (the xla-vs-pallas hop
-    timings benchmarks/weak_scaling reports): ``'pallas'`` times the fused
-    hop kernel per worker; the boundary-exchange volumes are impl-invariant.
 
     Plain-count queries profile the left-to-right plan (split = n−1); COUNT
     and MIN/MAX aggregates profile the reversed segment (split = 0, the plan
@@ -942,7 +834,6 @@ def measure_supersteps(
     backward = qry.agg_op != Q.AGG_NONE
     gdev = _prepare_gdev(graph)
     _, arrays, pdev = partition_for(graph, n_workers, parts_per_type)
-    pdev, hop_block_v = _with_hop_layouts(pdev, arrays, impl)
     W = arrays.n_workers
     v_max = arrays.v_max
     bedges = jnp.asarray(
@@ -963,8 +854,7 @@ def measure_supersteps(
     n_hops = len(e_preds)
 
     fns = _profile_fns(qry, mode, n_buckets, v_max, v_preds, e_preds, pv, pe,
-                       backward, want_minmax, qry.agg_op,
-                       impl=HK.require_impl(impl), hop_block_v=hop_block_v)
+                       backward, want_minmax, qry.agg_op)
     vpred, hop_masks = fns["vpred"], fns["hop_masks"]
     etr_produce = fns["etr_produce"]
     ranks_w = _ranks_for_produced(gdev, pdev)
@@ -980,12 +870,6 @@ def measure_supersteps(
 
     # ev/vv=None can't cross jit; encode "absent" as a 0-d placeholder.
     nul = jnp.zeros((), jnp.float32)
-    if SS.use_pallas(impl):
-        def hop_tabs(w):
-            return HK.worker_tables(pdev, slice(w, w + 1))
-    else:
-        def hop_tabs(w):
-            return {k: nul for k in HK.TABLE_KEYS}
 
     times = np.zeros((n_hops, W))
     channels = np.zeros((n_hops, len(CHANNELS)), np.int64)
@@ -1032,7 +916,7 @@ def measure_supersteps(
                     fns["one_worker_etr"], summ_w[w: w + 1], vm, vv_arg,
                     gdev["t_src"], wmask, ev_arg,
                     pdev["edge_ids"][w: w + 1], pdev["dst_local"][w: w + 1],
-                    hop_tabs(w), bedges)
+                    bedges)
                 times[i, w] += t_best
                 cnt_rows.append(cw)
                 arr_rows.append(aw)
@@ -1047,28 +931,20 @@ def measure_supersteps(
             if mch_w is not None:
                 mch_halo_w = fns["exchange_mch_fn"](mch_w, pdev)
                 channels[i, 1] = n_ghost
-            # on the kernel path, produce the per-edge counts only when the
-            # NEXT hop's ETR producer consumes them — matching the DCE the
-            # executor's jit applies, so the timing stays faithful
-            next_etr = i + 1 < n_hops and e_preds[i + 1].etr_op != -1
-            hop_fn = (fns["one_worker_hop"]
-                      if (not SS.use_pallas(impl) or next_etr)
-                      else fns["one_worker_hop_light"])
             for w in range(W):
                 mh = mch_halo_w if not mch_halo_w.ndim else \
                     mch_halo_w[w: w + 1]
                 t_best, (cw, aw, mw) = _timed(
-                    hop_fn, halo_w[w: w + 1], wmask, ev_arg,
+                    fns["one_worker_hop"], halo_w[w: w + 1], wmask, ev_arg,
                     pdev["edge_ids"][w: w + 1], pdev["dst_local"][w: w + 1],
-                    hop_tabs(w), pdev["src_halo"][w: w + 1], mh, bedges)
+                    pdev["src_halo"][w: w + 1], mh, bedges)
                 times[i, w] = t_best
                 cnt_rows.append(cw)
                 arr_rows.append(aw)
                 mch_rows.append(mw)
             if mch_w is not None:
                 mch_w = jnp.concatenate(mch_rows, axis=0)
-        cnt_w = (jnp.concatenate(cnt_rows, axis=0)
-                 if cnt_rows[0].ndim else None)
+        cnt_w = jnp.concatenate(cnt_rows, axis=0)
         arrivals_w = jnp.concatenate(arr_rows, axis=0)
 
     # final join: apply the segment-final vertex predicate, total (sanity)
@@ -1080,7 +956,7 @@ def measure_supersteps(
                                float(total.sum()))
     if tracer is not None and getattr(tracer, "enabled", False):
         root = tracer.start("measure_supersteps", n_workers=W,
-                            n_hops=n_hops, impl=impl, mode=mode,
+                            n_hops=n_hops, mode=mode,
                             backward=backward)
         for i in range(n_hops):
             ss = tracer.start(

@@ -22,11 +22,9 @@ core → dense / sliced / partitioned executors); all hop primitives come from
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..obs.trace import scope
 from . import query as Q
@@ -49,44 +47,6 @@ class SliceBounds:
         v = tuple((int(a), int(b)) for a, b in tr)
         e = tuple((int(ptr[a]), int(ptr[b])) for a, b in tr)
         return SliceBounds(v, e)
-
-
-def slice_layouts_for(graph: TemporalGraph, qry: Q.PathQuery,
-                      sb: SliceBounds, impl: str = "xla",
-                      block_v: Optional[int] = None,
-                      block_e_mult: int = 512) -> dict:
-    """Per-arrival-type HopLayouts for a query's hops (the sliced twin of
-    ``engine.hop_layout_for``): the traversal edges arriving at one vertex
-    type are one contiguous slice, so each type gets its own block layout
-    over slice-local destinations.  Cached on the graph; empty slices are
-    skipped (the sliced planner early-outs before delivering into them)."""
-    if not SS.use_pallas(impl):
-        return {}
-    from ..kernels.hop_scatter import build_hop_layout
-
-    cache = getattr(graph, "_hop_layout_cache", None)
-    if cache is None:
-        cache = {}
-        graph._hop_layout_cache = cache
-    t_dst = None
-    layouts = {}
-    for vp in qry.v_preds:
-        vt = vp.vtype
-        vlo, vhi = sb.v[vt]
-        if vt in layouts or vhi <= vlo:
-            continue
-        key = ("slice", vt, block_v, block_e_mult)
-        lay = cache.get(key)
-        if lay is None:
-            if t_dst is None:
-                t_dst = np.asarray(graph.traversal["t_dst"])
-            elo, ehi = sb.e[vt]
-            lay = build_hop_layout(t_dst[elo:ehi] - vlo, vhi - vlo,
-                                   block_v=block_v,
-                                   block_e_mult=block_e_mult)
-            cache[key] = lay
-        layouts[vt] = lay
-    return layouts
 
 
 @scope("vertex_pred")
@@ -168,10 +128,8 @@ class _SegResult:
 
 
 def _run_segment_sliced(gdev, v_preds, e_preds, params, pv, pe, mode,
-                        n_buckets, backward, sb: SliceBounds,
-                        impl: str = "xla", layouts=None):
+                        n_buckets, backward, sb: SliceBounds):
     bedges = SS.current_bedges()
-    fused = SS.use_pallas(impl) and layouts
     vb0 = sb.v[v_preds[0].vtype]
     vm, vv = _vertex_eval_sliced(gdev, v_preds[0], params, pv[0], mode, bedges, vb0)
     state_v = SS.init_state(vm, vv, mode, n_buckets)   # on slice of type σ0
@@ -224,20 +182,10 @@ def _run_segment_sliced(gdev, v_preds, e_preds, params, pv, pe, mode,
         else:
             cnt_e = SS.apply_validity(src_val, wmask, evalid, mode)
         nvlo, nvhi = nxt_vb
-        lay = layouts.get(v_preds[i + 1].vtype) if layouts else None
-        if fused and ep.etr_op == -1 and lay is not None:
-            # fused kernel hop on the arrival-type slice: the out-of-slice
-            # sources point at the layout's zero row instead of clip+mask
-            src_slot = jnp.where(src_in, src - vlo, vhi - vlo)
-            arrivals_v, _ = SS.fused_hop_deliver(
-                sv, src_slot, wmask, evalid, mode, lay.tables, lay.block_v,
-                nvhi - nvlo, impl=impl)
-        else:
-            seg = gdev["t_dst"][lo:hi] - nvlo
-            # the slice's CSR offsets, rebased: arr_ptr[nvlo] == lo
-            ptr = gdev["arr_ptr"][nvlo:nvhi + 1] - lo
-            arrivals_v = SS.deliver(cnt_e, seg, nvhi - nvlo, impl=impl,
-                                    layout=lay, ptr=ptr)
+        seg = gdev["t_dst"][lo:hi] - nvlo
+        # the slice's CSR offsets, rebased: arr_ptr[nvlo] == lo
+        ptr = gdev["arr_ptr"][nvlo:nvhi + 1] - lo
+        arrivals_v = SS.deliver(cnt_e, seg, nvhi - nvlo, ptr=ptr)
         arrivals_e = cnt_e
         prev_raw = cnt_e
         prev_eb = cur_eb
@@ -247,15 +195,10 @@ def _run_segment_sliced(gdev, v_preds, e_preds, params, pv, pe, mode,
 
 
 def execute_plan_sliced(gdev, qry: Q.PathQuery, split: int, mode: int,
-                        n_buckets: int, params, bedges, sb: SliceBounds,
-                        impl: str = "xla", layouts=None):
-    """Sliced twin of engine._execute_plan_inner (counts + count-aggregates).
-
-    ``impl``/``layouts`` (per-arrival-type HopLayouts from
-    ``slice_layouts_for``) select the fused hop-kernel delivery."""
+                        n_buckets: int, params, bedges, sb: SliceBounds):
+    """Sliced twin of engine._execute_plan_inner (counts + count-aggregates)."""
     with SS.bucket_scope(bedges):
-        return _inner(gdev, qry, split, mode, n_buckets, params, sb,
-                      impl=impl, layouts=layouts)
+        return _inner(gdev, qry, split, mode, n_buckets, params, sb)
 
 
 def _zero_output(qry, mode, n_buckets, sb, want_agg):
@@ -273,8 +216,7 @@ def _zero_output(qry, mode, n_buckets, sb, want_agg):
     return ExecOutput(total, pv, None, [])
 
 
-def _inner(gdev, qry, split, mode, n_buckets, params, sb, impl: str = "xla",
-           layouts=None):
+def _inner(gdev, qry, split, mode, n_buckets, params, sb):
     n = qry.n_vertices
     pv, pe = _pbases(qry)
     bedges = SS.current_bedges()
@@ -296,7 +238,7 @@ def _inner(gdev, qry, split, mode, n_buckets, params, sb, impl: str = "xla",
         left = _run_segment_sliced(gdev, qry.v_preds[: split + 1],
                                    qry.e_preds[:split], params,
                                    pv[: split + 1], pe[:split], mode,
-                                   n_buckets, False, sb, impl, layouts)
+                                   n_buckets, False, sb)
     right = None
     m_hops = (n - 1) - split
     if m_hops > 0:
@@ -305,7 +247,7 @@ def _inner(gdev, qry, split, mode, n_buckets, params, sb, impl: str = "xla",
         right = _run_segment_sliced(gdev, rev.v_preds[: m_hops + 1],
                                     rev.e_preds[:m_hops], params,
                                     rpv[: m_hops + 1], rpe[:m_hops], mode,
-                                    n_buckets, True, sb, impl, layouts)
+                                    n_buckets, True, sb)
 
     with scope("join"):
         vb = sb.v[qry.v_preds[split].vtype]

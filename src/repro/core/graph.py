@@ -14,8 +14,9 @@ Layout decisions (see DESIGN.md Sec. 2/5):
   same edge traversed backward.  Directed/undirected hops become weight masks
   over the same arrays, so ETR rank tables and segment offsets are built once.
 * Traversal arrays are sorted by arrival vertex (``t_dst``); ``arr_ptr`` gives
-  the CSR-style segment offsets.  Per-superstep message delivery is then a
-  sorted segment-sum — the shape `bucket_scatter` Pallas kernel accelerates.
+  the CSR-style segment offsets.  Per-superstep message delivery is then
+  prefix differences of a running sum read at those offsets
+  (``superstep.deliver``) — no scatter.
 * **ETR rank tables**: for the edge-temporal-relationship operator we need,
   per candidate edge e', the weighted count of accumulated edges at a vertex
   whose lifespan stat (start/end) compares against a threshold taken from e'.

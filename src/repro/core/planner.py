@@ -68,30 +68,15 @@ DEFAULT_COEFFS = {
     "theta_net": 8.0e-5,  # ms per boundary vertex-state entry (plain/extremum
                           # channels of the point-to-point exchange)
     "theta_net_etr": 8.0e-5,  # ms per boundary ETR rank summary (cut edges)
-    # per-impl hop-DELIVERY slope (ms per traversal edge in the hop slice):
-    # the measured cost of the gather → mask → segment-reduce step under
-    # each lowering (benchmarks/fit_cost_model fits both from hop-delivery
-    # micro-benches).  The estimate applies the DELTA from the xla slope, so
-    # impl='xla' plans cost exactly what the historical model says (theta_e
-    # already folds the xla delivery in) and the impl sweep discriminates on
-    # the fitted difference alone.  Defaults are 0 → tie → xla.
-    "theta_scatter_xla": 0.0,
-    "theta_scatter_pallas": 0.0,
 }
-
-#: the impl axis plan selection sweeps when asked to choose a lowering
-HOP_IMPL_CHOICES = ("xla", "pallas")
 
 #: canonical coefficient basis: a PlanEstimate's ``features`` vector is
 #: indexed by this tuple, and ``t_ms == features @ coeff_vector(coeffs)``
-#: EXACTLY (the scatter-delta trick is encoded as +e/w on the chosen impl's
-#: column and -e/w on the xla column, so impl='xla' contributes zero).  This
-#: is the contract the serving telemetry's online refit relies on: refitting
+#: EXACTLY.  This is the contract the serving telemetry's online refit relies on: refitting
 #: θ over recorded (features, measured) dispatch rows re-calibrates the very
 #: predictions admission control makes.
 COEFF_KEYS = ("theta0", "theta_init", "theta_v", "theta_e", "theta_etr",
-              "theta_m", "theta_net", "theta_net_etr",
-              "theta_scatter_xla", "theta_scatter_pallas")
+              "theta_m", "theta_net", "theta_net_etr")
 _CK = {k: i for i, k in enumerate(COEFF_KEYS)}
 
 
@@ -148,12 +133,11 @@ class PlanEstimate:
     split: int
     t_ms: float
     steps: List[StepEstimate]
-    impl: str = "xla"   # hop-delivery lowering the estimate was costed at
     #: summed step features over COEFF_KEYS (t_ms == features @ coeff_vector);
     #: for estimate_batch, the batch-summed features
     features: Optional[np.ndarray] = None
     #: the full sweep choose()/choose_batch() ran to pick this plan: one
-    #: dict(split, impl, t_ms, features) per candidate.  The flight
+    #: dict(split, t_ms, features) per candidate.  The flight
     #: recorder's plan span records these so obs/audit.plan_accuracy can
     #: re-cost the whole sweep under a trace-refit θ̂ offline (the paper's
     #: "% within X% of optimal plan" metric).  None when no sweep ran
@@ -203,16 +187,13 @@ def estimate_segment(
     exchange_volume: float = 0.0,
     etr_exchange_volume: float = 0.0,
     extremum_channel: bool = False,
-    impl: str = "xla",
 ) -> List[StepEstimate]:
     """Per-superstep estimates.  With ``n_workers > 1`` compute extents are
     divided over workers (balanced partitions) and each hop pays the θ_net
     exchange term: ``exchange_volume`` (halo ghost entries; doubled when the
     MIN/MAX ``extremum_channel`` rides along) on plain hops,
     ``etr_exchange_volume`` (boundary rank summaries — cut edges) on ETR
-    hops.  ``impl`` selects the hop-delivery lowering being costed: each hop
-    pays the fitted θ_scatter slope DELTA vs the xla baseline (zero for
-    impl='xla', so the historical model is unchanged)."""
+    hops."""
     steps: List[StepEstimate] = []
     prev_m_e = None
     w = max(1, int(n_workers))
@@ -276,15 +257,6 @@ def estimate_segment(
             feat[_CK["theta_etr"]] = e_slice / w
             feat[_CK["theta_net_etr"]] = m_net
         else:
-            # fused-hop saving applies to plain hops only: ETR hops
-            # materialise per-edge counts by construction and only swap
-            # the delivery step, which the fitted full-hop slope would
-            # over-credit.  The delta-vs-xla encoding keeps impl='xla'
-            # contributing exactly zero (historical model unchanged).
-            base = ("pallas" if impl in ("pallas", "pallas_interpret")
-                    else "xla")
-            feat[_CK[f"theta_scatter_{base}"]] += e_slice / w
-            feat[_CK["theta_scatter_xla"]] -= e_slice / w
             feat[_CK["theta_net"]] = m_net
         feat[_CK["theta_m"]] = max(m_e, 0.0) / w
         t = float(feat @ theta)
@@ -344,8 +316,7 @@ class Planner:
             return [0]
         return list(range(qry.n_vertices))
 
-    def estimate(self, qry: Q.PathQuery, split: int,
-                 impl: str = "xla") -> PlanEstimate:
+    def estimate(self, qry: Q.PathQuery, split: int) -> PlanEstimate:
         n = qry.n_vertices
         steps: List[StepEstimate] = []
         # MIN/MAX aggregates thread the extremum channel through the (right,
@@ -358,7 +329,6 @@ class Planner:
                 n_workers=self.n_workers,
                 exchange_volume=self.exchange_volume,
                 etr_exchange_volume=self.etr_exchange_volume,
-                impl=impl,
             )
         if (n - 1) - split > 0:
             rev = qry.reversed()
@@ -370,37 +340,31 @@ class Planner:
                 exchange_volume=self.exchange_volume,
                 etr_exchange_volume=self.etr_exchange_volume,
                 extremum_channel=extremum,
-                impl=impl,
             )
         t = sum(s.t_ms for s in steps)
         feats = [s.features for s in steps if s.features is not None]
         features = (np.sum(feats, axis=0) if feats
                     else np.zeros(len(COEFF_KEYS)))
-        return PlanEstimate(split, t, steps, impl, features)
+        return PlanEstimate(split, t, steps, features)
 
-    def choose(self, qry: Q.PathQuery,
-               impls: Sequence[str] = ("xla",)) -> PlanEstimate:
-        """Best (split, impl) over the plan space.  The default sweeps only
-        the xla lowering (the historical behaviour); pass
-        ``impls=HOP_IMPL_CHOICES`` to let the fitted per-impl θ_scatter term
-        route hops onto the fused kernel where it wins — ties break toward
-        the first entry (xla).  The swept candidates are recorded on the
-        returned estimate (``candidates``) for the flight recorder."""
+    def choose(self, qry: Q.PathQuery) -> PlanEstimate:
+        """Best split over the plan space; ties break toward the first.
+        The swept candidates are recorded on the returned estimate
+        (``candidates``) for the flight recorder."""
         best = None
         cands: List[dict] = []
         for split in self.enumerate_plans(qry):
-            for impl in impls:
-                est = self.estimate(qry, split, impl)
-                cands.append(dict(split=split, impl=impl, t_ms=est.t_ms,
-                                  features=est.features))
-                if best is None or est.t_ms < best.t_ms:
-                    best = est
+            est = self.estimate(qry, split)
+            cands.append(dict(split=split, t_ms=est.t_ms,
+                              features=est.features))
+            if best is None or est.t_ms < best.t_ms:
+                best = est
         best.candidates = cands
         return best
 
     # ------------------------------------------------------- batched serving
-    def estimate_batch(self, queries: Sequence[Q.PathQuery], split: int,
-                       impl: str = "xla") -> PlanEstimate:
+    def estimate_batch(self, queries: Sequence[Q.PathQuery],
+                       split: int) -> PlanEstimate:
         """Cost a whole same-shape batch at one split point.
 
         Instances share the traced structure but not their parameter values,
@@ -410,21 +374,17 @@ class Planner:
         instance's (for introspection); ``t_ms`` covers the batch.
         """
         assert queries, "empty batch"
-        ests = [self.estimate(q, split, impl) for q in queries]
+        ests = [self.estimate(q, split) for q in queries]
         return PlanEstimate(split, sum(e.t_ms for e in ests), ests[0].steps,
-                            impl, np.sum([e.features for e in ests], axis=0))
+                            np.sum([e.features for e in ests], axis=0))
 
-    def choose_batch(self, queries: Sequence[Q.PathQuery],
-                     impls: Sequence[str] = ("xla",)) -> PlanEstimate:
-        """One (split, impl) for a same-shape batch, minimising whole-batch
-        cost.
+    def choose_batch(self, queries: Sequence[Q.PathQuery]) -> PlanEstimate:
+        """One split for a same-shape batch, minimising whole-batch cost.
 
         This is the planner the batch scheduler uses: a vmapped group runs
         every instance at ONE split, so the right objective is the batch sum
         — picking the first instance's best split can lose when selectivities
-        differ across instances (the old run_workload_batched bug).  The
-        ``impls`` sweep mirrors ``choose()``: a group is dispatched on one
-        hop-delivery lowering, so the impl is chosen batch-wide too."""
+        differ across instances (the old run_workload_batched bug)."""
         assert queries, "empty batch"
         shape0 = queries[0].shape_key()
         for q in queries[1:]:
@@ -433,12 +393,11 @@ class Planner:
         best = None
         cands: List[dict] = []
         for split in self.enumerate_plans(queries[0]):
-            for impl in impls:
-                est = self.estimate_batch(queries, split, impl)
-                cands.append(dict(split=split, impl=impl, t_ms=est.t_ms,
-                                  features=est.features))
-                if best is None or est.t_ms < best.t_ms:
-                    best = est
+            est = self.estimate_batch(queries, split)
+            cands.append(dict(split=split, t_ms=est.t_ms,
+                              features=est.features))
+            if best is None or est.t_ms < best.t_ms:
+                best = est
         best.candidates = cands
         return best
 

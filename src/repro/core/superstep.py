@@ -36,12 +36,6 @@ all three:
                                                    sum scatter where edges
                                                    are unsorted or padded
                                                    (delta, partitioned)
-                         fused_hop_deliver()     — the fused kernel hop
-                                                   (gather → temporal mask →
-                                                   segment-reduce in VMEM via
-                                                   kernels.hop_scatter; the
-                                                   impl='pallas' hot path of
-                                                   every plain hop)
   extremum channel       minmax_seed(), minmax_edge(), deliver_extremum()
                          — the MIN/MAX aggregate's per-hop DP channel
                            (a segmented min/max scan read at segment ends
@@ -82,7 +76,7 @@ helpers stay signature-stable).
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -90,8 +84,6 @@ import numpy as np
 
 from . import intervals as iv
 from . import query as Q
-from ..kernels import hop_scatter as HK
-from ..kernels.common import check_impl, resolve_interpret, use_pallas
 from ..obs.trace import scope
 
 MODE_STATIC = 0
@@ -455,24 +447,18 @@ def p2p_exchange(rows_w, local_src, send_slot, recv_slot, n_slots: int,
 # =========================================================================
 @scope("hop_deliver")
 def deliver(cnt_e, seg_ids, num_segments: int, indices_are_sorted: bool = True,
-            impl: str = "xla", layout=None, ptr=None):
+            ptr=None):
     """Per-arrival-vertex sum of per-edge counts — the message delivery of
     one superstep.
 
-    ``impl`` selects the lowering.  ``'pallas'``/``'pallas_interpret'`` with
-    a ``kernels.hop_scatter`` ``HopLayout`` over the same (static, sorted)
-    seg_ids runs the blocked scatter-as-matmul kernel.  Otherwise, given
-    ``ptr`` (int32 ``[num_segments + 1]``, the CSR offsets of arrival-sorted
-    edges), the sums are prefix differences with no scatter: an int32
-    running sum along the edge axis read at the segment bounds.  Without
-    ``ptr`` (unsorted delta edges, padded per-worker slots) it is the XLA
-    segment-sum scatter.  All three agree bit for bit while counts are
+    Given ``ptr`` (int32 ``[num_segments + 1]``, the CSR offsets of
+    arrival-sorted edges), the sums are prefix differences with no scatter:
+    an int32 running sum along the edge axis read at the segment bounds.
+    Without ``ptr`` (unsorted delta edges, padded per-worker slots) it is
+    the XLA segment-sum scatter.  Both agree bit for bit while counts are
     exact integers in float32, the engine's invariant: int32 addition wraps
     exactly, so each segment's difference is exact whenever its sum fits
     int32, which covers every sum float32 holds exactly."""
-    if use_pallas(check_impl(impl)) and layout is not None:
-        return HK.scatter_deliver(cnt_e, layout.tables, num_segments,
-                                  layout.block_v, impl=impl)
     if ptr is not None:
         return _prefix_deliver(cnt_e, ptr)
     return jax.ops.segment_sum(
@@ -491,95 +477,6 @@ def _prefix_deliver(cnt_e, ptr):
     at = run[jnp.maximum(ptr - 1, 0)]            # S[ptr], less the zero row
     at = jnp.where((ptr > 0).reshape((-1,) + (1,) * len(ts)), at, 0)
     return (at[1:] - at[:-1]).astype(cnt_e.dtype)
-
-
-@scope("hop_deliver")
-def fused_hop_deliver(
-    state,                       # [N, *TS] source-state table
-    src_slot,                    # int32[E] — source row per edge; N = zero row
-    wmask,                       # bool[E] edge-predicate ∧ direction match
-    evalid,                      # temporal validity: None / bool[E, B] /
-                                 # int32[E, 2] interval (per mode)
-    mode: int,
-    lt: Dict,                    # HopLayout.tables (or a worker-sliced row of
-                                 #   stacked tables — a uniform array pytree,
-                                 #   so executors can vmap it with in_axes=0)
-    block_v: int,
-    num_segments: int,
-    impl: str = "pallas",
-    mch=None,                    # optional extremum channel table [N]
-    minmax_op: int = Q.AGG_MIN,
-) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
-    """One fused traversal hop: gather → temporal mask → segment-reduce.
-
-    Pallas-only twin of the three-step XLA hop (``state[src]`` gather,
-    ``apply_edge``, ``deliver``) that never materialises the per-edge
-    ``[E, *TS]`` state: the ``kernels.hop_scatter`` kernel gathers, weights
-    and prefix-reduces per destination block in VMEM.  When ``mch`` is
-    given, the MIN/MAX extremum channel is gathered, liveness-gated by the
-    in-VMEM contributions, and min/max-reduced alongside (the
-    ``minmax_edge`` + ``deliver_extremum`` pair of the XLA path).
-
-    ``evalid``/``mch`` may be 0-d placeholders for "absent" (the profiling
-    and vmap call sites can't pass None through mapped axes).
-
-    Returns (arrivals [num_segments, *TS], mch_out [num_segments] | None).
-    """
-    assert use_pallas(check_impl(impl)), "fused_hop_deliver is the kernel path"
-    interpret = resolve_interpret(None, impl)
-    if evalid is not None and getattr(evalid, "ndim", 1) == 0:
-        evalid = None
-    if mch is not None and getattr(mch, "ndim", 1) == 0:
-        mch = None
-    N = state.shape[0]
-    ts = state.shape[1:]
-    gather_idx, valid = lt["gather"], lt["valid"]
-    n_blocks, block_e = lt["ldst"].shape
-    src_sl = HK.slots(src_slot.astype(jnp.int32), gather_idx, valid,
-                      N).reshape(n_blocks, block_e)
-    mch_p = None
-    neutral = 0.0
-    op_is_min = minmax_op == Q.AGG_MIN
-    if mch is not None:
-        neutral = float(np.inf if op_is_min else -np.inf)
-        mch_p = jnp.concatenate(
-            [mch.astype(jnp.float32), jnp.full((1,), neutral, jnp.float32)]
-        )[:, None]
-    if mode == MODE_INTERVAL:
-        B = state.shape[-2]
-        state_p = jnp.concatenate(
-            [state.reshape(N, B * (B + 1)),
-             jnp.zeros((1, B * (B + 1)), state.dtype)], axis=0)
-        w = HK.slots(wmask.astype(jnp.float32), gather_idx, valid,
-                     0.0).reshape(n_blocks, block_e)
-        sb, eb = _interval_to_cells(evalid, B)
-        sb_sl = HK.slots(sb.astype(jnp.int32), gather_idx, valid,
-                         0).reshape(n_blocks, block_e)
-        eb_sl = HK.slots(eb.astype(jnp.int32), gather_idx, valid,
-                         0).reshape(n_blocks, block_e)
-        out, mch_out = HK.fused_hop_interval_pallas(
-            state_p, src_sl, w, sb_sl, eb_sl, lt["sstart"], lt["send"],
-            lt["ldst"], block_v, B, interpret=interpret, mch_p=mch_p,
-            neutral=neutral, op_is_min=op_is_min)
-        arrivals = out[:num_segments].reshape(num_segments, B, B + 1)
-    else:
-        C = 1 if mode == MODE_STATIC else state.shape[1]
-        state_p = jnp.concatenate(
-            [state.reshape(N, C), jnp.zeros((1, C), state.dtype)], axis=0)
-        if mode == MODE_STATIC:
-            wv = wmask.astype(jnp.float32)[:, None]
-        else:
-            wv = (wmask[:, None] & evalid).astype(jnp.float32)
-        w_cols = HK.slots(wv, gather_idx, valid, 0.0).reshape(
-            n_blocks, block_e, C)
-        out, mch_out = HK.fused_hop_cols_pallas(
-            state_p, src_sl, w_cols, lt["sstart"], lt["send"], lt["ldst"],
-            block_v, interpret=interpret, mch_p=mch_p, neutral=neutral,
-            op_is_min=op_is_min)
-        arrivals = out[:num_segments].reshape((num_segments,) + ts)
-    if mch_out is not None:
-        mch_out = mch_out[:num_segments]
-    return arrivals, mch_out
 
 
 # =========================================================================
@@ -607,21 +504,14 @@ def minmax_edge(mch_src, cnt_e, op: int, mode: int):
 
 @scope("hop_deliver")
 def deliver_extremum(m_e, seg_ids, num_segments: int, op: int,
-                     indices_are_sorted: bool = True, impl: str = "xla",
-                     layout=None, ptr=None):
+                     indices_are_sorted: bool = True, ptr=None):
     """Extremum twin of ``deliver``: per-arrival-vertex min/max of the
     per-edge channel, the neutral ±inf on empty segments.  Min/max is
-    order-independent, so every lowering, and per-worker deliveries over
-    owned segments, match exactly.  The lowerings mirror ``deliver``'s: the
-    blocked masked-extremum kernel with a layout; given ``ptr``, a
-    segmented scan over the arrival-sorted edges read at each segment's
-    last edge; else the XLA segment_min/segment_max scatter."""
-    if use_pallas(check_impl(impl)) and layout is not None:
-        # m_e is already liveness-gated by minmax_edge: every slot is "alive"
-        return HK.scatter_extremum(
-            m_e, jnp.ones_like(m_e), layout.tables, num_segments,
-            layout.block_v, neutral=float(minmax_neutral(op)),
-            op_is_min=(op == Q.AGG_MIN), impl=impl)
+    order-independent, so both lowerings, and per-worker deliveries over
+    owned segments, match exactly.  The lowerings mirror ``deliver``'s:
+    given ``ptr``, a segmented scan over the arrival-sorted edges read at
+    each segment's last edge; else the XLA segment_min/segment_max
+    scatter."""
     if ptr is not None:
         return _scan_extremum(m_e, seg_ids, ptr, op)
     seg = jax.ops.segment_min if op == Q.AGG_MIN else jax.ops.segment_max

@@ -299,36 +299,6 @@ class PartitionArrays:
         """Boundary messages per plain superstep: ghost-state entries received."""
         return int(self.n_ghost.sum())
 
-    def worker_hop_layouts(self, block_v=None,
-                           block_e_mult: int = 512) -> tuple:
-        """Stacked per-worker hop-kernel layouts over ``dst_local``.
-
-        Each worker's owned edges are already sorted by local arrival slot
-        (canonical order restricted to the shard) with pads on the trash
-        segment ``v_max`` — exactly a sorted seg_ids array per worker — so
-        each shard gets its own ``kernels.hop_scatter`` block layout over
-        ``v_max + 1`` local destinations, built with a COMMON slot shape so
-        the executor can vmap/shard_map the fused kernel over the worker
-        axis.  Returns ({hop_gather, hop_valid, hop_ldst} [W, ...] tables,
-        block_v); cached on the arrays object.
-        """
-        from ..kernels.hop_scatter import (build_worker_layouts,
-                                           stack_layout_tables)
-
-        cache = getattr(self, "_hop_layout_cache", None)
-        if cache is None:
-            cache = {}
-            self._hop_layout_cache = cache
-        key = (block_v, block_e_mult)
-        hit = cache.get(key)
-        if hit is None:
-            layouts = build_worker_layouts(self.dst_local, self.v_max + 1,
-                                           block_v=block_v,
-                                           block_e_mult=block_e_mult)
-            hit = (stack_layout_tables(layouts), layouts[0].block_v)
-            cache[key] = hit
-        return hit
-
     def etr_exchange_volume(self) -> int:
         """Boundary messages per ETR superstep: rank summaries whose producer
         (source-segment owner) differs from their consumer (edge owner)."""

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Pallas TPU kernels for the model layers (``models/``).
 
 Each kernel package provides:
   <name>.py — the pl.pallas_call with explicit BlockSpec VMEM tiling
@@ -11,18 +11,12 @@ that defaults to AUTO — interpreter mode only when the backend is CPU, so a
 GPU/TPU run can never silently execute a kernel in interpreter mode.
 
 Hot-spots covered:
-  hop_scatter     — FUSED traversal-hop delivery: gather source state →
-                    temporal mask (static/bucket/interval cells) →
-                    segment-reduce (sum or min/max) per destination block,
-                    with no materialised per-edge state (the engine's query
-                    hot path; see core/superstep.fused_hop_deliver)
-  bucket_scatter  — scatter-as-matmul segment reduction (the delivery-only
-                    building block hop_scatter extends; GNN aggregation)
-  interval_warp   — fused TimeWarp bucket alignment (engine temporal modes)
   flash_attention — blocked online-softmax GQA attention w/ causal + sliding
                     window (LM train/prefill)
   embedding_bag   — fused gather + segment-reduce over huge tables (DLRM)
+
+The query engine has no kernel here: its hop delivery is plain XLA
+(``core/superstep.deliver``).
 """
-from . import (bucket_scatter, embedding_bag, flash_attention,  # noqa: F401
-               hop_scatter, interval_warp)
+from . import embedding_bag, flash_attention  # noqa: F401
 from .common import IMPLS, resolve_interpret, use_pallas  # noqa: F401

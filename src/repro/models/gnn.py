@@ -2,7 +2,7 @@
 
 All four are message-passing networks built on the same primitive the query
 engine uses: gather-by-src → edge compute → segment-reduce-by-dst
-(`jax.ops.segment_sum` / the `bucket_scatter` Pallas kernel).  JAX has no
+(`jax.ops.segment_sum`).  JAX has no
 sparse message-passing op — this scatter substrate IS part of the system.
 
 Graphs are structure-of-arrays ``GraphBatch``; batched small graphs

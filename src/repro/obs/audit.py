@@ -133,8 +133,8 @@ def plan_accuracy(trace: TraceLike, within: float = 0.10,
                   coeffs: Optional[dict] = None) -> dict:
     """The paper's plan-quality metric from trace data alone.
 
-    The candidate sweep the batch planner ran (split × impl, with each
-    candidate's feature row) is one decision per dispatched GROUP — the
+    The candidate sweep the batch planner ran (one candidate per split,
+    with its feature row) is one decision per dispatched GROUP — the
     scheduler records it once, on the first member's plan span, and every
     member's plan span carries the group ``seq``.  Re-costing those
     candidates under the trace-refit θ̂ — the best post-hoc estimate of true
@@ -152,7 +152,7 @@ def plan_accuracy(trace: TraceLike, within: float = 0.10,
         if a.get("seq") is None:
             continue
         grp = groups.setdefault(
-            a["seq"], dict(cands=None, chosen=(a["split"], a["impl"]), n=0))
+            a["seq"], dict(cands=None, chosen=a["split"], n=0))
         grp["n"] += 1
         if a.get("candidates"):
             grp["cands"] = a["candidates"]
@@ -163,8 +163,7 @@ def plan_accuracy(trace: TraceLike, within: float = 0.10,
         if not cands:
             continue
         n_decisions += 1
-        costs = {(c["split"], c["impl"]):
-                 float(np.asarray(c["features"], float) @ theta)
+        costs = {c["split"]: float(np.asarray(c["features"], float) @ theta)
                  for c in cands}
         best = min(costs.values())
         chosen = costs.get(grp["chosen"])

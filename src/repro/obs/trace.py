@@ -5,7 +5,7 @@ Every query the serving runtime touches leaves one span TREE:
     query                       root: template, shape, deadline, final status
     ├─ admit                    verdict (admit/degrade/reject), ladder rungs
     └─ dispatch-side children, one set per member of the dispatched group:
-       ├─ plan                  split, impl, plan-cache hit, predicted
+       ├─ plan                  split, plan-cache hit, predicted
        │                        features·θ (the cost model's commitment)
        ├─ compile               executable-cache hit/miss + dispatch key
        └─ dispatch              group seq, batch size, EDF position,
@@ -66,7 +66,7 @@ from jax.profiler import TraceAnnotation
 SCHED_PHASES = (
     "sched.flush",        # the whole flush
     "sched.group",        # grouping and earliest-deadline-first ordering
-    "sched.plan",         # split/impl choice (plan cache or planner sweep)
+    "sched.plan",         # split choice (plan cache or planner sweep)
     "sched.plan_tensor",  # stacking the group's parameter rows
     "sched.launch",       # executable key, cache lookup, the call's return
     "sched.device_wait",  # block_until_ready on the answers

@@ -20,8 +20,8 @@ Layout:
                 goodput (the paper's Table 5 serving metrics, plus SLO
                 accounting)
   admission.py  deadline admission control: cost-model-predicted wait +
-                service vs deadline → admit / degrade (cheaper impl,
-                dense→sliced, bounded dispatch quantum) / reject
+                service vs deadline → admit / degrade (dense→sliced,
+                bounded dispatch quantum) / reject
   telemetry.py  (predicted, measured) dispatch-cost ring buffer + periodic
                 online θ refit — prediction error shrinks during serving
   faults.py     deterministic chaos injection (FaultPlan: seeded rates /
@@ -40,8 +40,7 @@ Layout:
 """
 from .admission import (AdmissionController, AdmissionDecision,
                         AdmissionPolicy)
-from .cache import (ExecutableCache, PlanCache, graph_fingerprint,
-                    layout_signature)
+from .cache import ExecutableCache, PlanCache, graph_fingerprint
 from .compile import PlanTensor, bucket_key, compile_plan_tensor
 from .epochs import Epoch, EpochManager
 from .faults import (CompileError, FaultError, FaultPlan, PoisonQueryError,
@@ -54,7 +53,7 @@ from .testing import FakeDispatcher
 
 __all__ = [
     "BatchScheduler", "ServedResult", "GroupDispatch", "PlanCache",
-    "ExecutableCache", "graph_fingerprint", "layout_signature", "PlanTensor",
+    "ExecutableCache", "graph_fingerprint", "PlanTensor",
     "bucket_key", "compile_plan_tensor", "ReplayReport", "replay_workload",
     "AdmissionController", "AdmissionDecision", "AdmissionPolicy",
     "TelemetryBuffer", "FakeDispatcher", "Epoch", "EpochManager",

@@ -12,12 +12,11 @@ backlog already admitted ahead of it, and the controller decides:
   admit     predicted completion (wait + service) fits inside the deadline
             with ``headroom`` to spare;
   degrade   it does not fit as-is, but a rung of the degradation ladder
-            makes it fit: a cheaper hop-delivery impl (the fitted per-impl
-            θ_scatter slopes say which), a dense→sliced engine downgrade
-            (smaller typed extents — same bit-identical answer), and a
-            bounded dispatch quantum (``degrade_max_batch`` caps the group
-            chunk the query rides in, so EDF can interleave urgent work
-            instead of waiting out one huge vmapped call);
+            makes it fit: a dense→sliced engine downgrade (smaller typed
+            extents — same bit-identical answer), and a bounded dispatch
+            quantum (``degrade_max_batch`` caps the group chunk the query
+            rides in, so EDF can interleave urgent work instead of waiting
+            out one huge vmapped call);
   reject    no rung fits — refuse NOW, at predicted cost zero, rather than
             burn service time on a query that will miss its deadline anyway
             (goodput over throughput).
@@ -39,8 +38,6 @@ import math
 from typing import Optional, Tuple
 
 from ..core import engine_sliced as ES
-from ..core.planner import HOP_IMPL_CHOICES
-from ..kernels.hop_scatter import available_impls
 
 ADMIT, DEGRADE, REJECT = "admit", "degrade", "reject"
 
@@ -55,17 +52,13 @@ class AdmissionPolicy:
     headroom: float = 0.8
     #: hard bound on predicted queued work (seconds); None = deadline-driven
     max_backlog_s: Optional[float] = None
-    #: ladder rung 1 — sweep these impls for a cheaper lowering (fitted
-    #: per-impl θ_scatter slopes); () disables the rung.  Only those the
-    #: backend can run (``hop_scatter.available_impls``) are offered.
-    degrade_impls: Tuple[str, ...] = HOP_IMPL_CHOICES
-    #: ladder rung 2 — dense→sliced downgrade when the query is sliceable
+    #: ladder rung 1 — dense→sliced downgrade when the query is sliceable
     allow_engine_downgrade: bool = True
     #: predicted-cost multiplier of the sliced downgrade (typed extents are
     #: strictly smaller than whole-graph extents; refit-calibrated hosts can
     #: tighten this)
     sliced_discount: float = 0.7
-    #: ladder rung 3 — cap the dispatch quantum of degraded queries so EDF
+    #: ladder rung 2 — cap the dispatch quantum of degraded queries so EDF
     #: interleaves at finer grain; None disables the rung
     degrade_max_batch: Optional[int] = 8
 
@@ -78,11 +71,10 @@ class AdmissionDecision:
     deadline: float               # absolute deadline assigned (inf = none)
     predicted_s: float            # predicted service cost of this query
     predicted_wait_s: float       # predicted backlog ahead of it
-    impl: Optional[str] = None    # degradation overrides (None = scheduler
-    engine: Optional[str] = None  # defaults)
-    max_batch: Optional[int] = None
-    #: degradation-ladder rungs taken, in order (e.g. "impl=pallas",
-    #: "engine=sliced") — the flight recorder's admit-span and
+    engine: Optional[str] = None  # degradation overrides (None = scheduler
+    max_batch: Optional[int] = None  # defaults)
+    #: degradation-ladder rungs taken, in order (e.g. "engine=sliced",
+    #: "quantum=8") — the flight recorder's admit-span and
     #: admission-metric labels; empty for plain admits and rejects
     rungs: Tuple[str, ...] = ()
 
@@ -99,10 +91,9 @@ class AdmissionController:
     plan it *would* run at → predict its service cost with the scheduler's
     live (possibly refit) cost model → compare ``predicted wait + service``
     against ``headroom · deadline``.  If it fits, ADMIT; otherwise walk the
-    degradation ladder (cheaper hop impl → dense→sliced downgrade → bounded
-    dispatch quantum) and admit DEGRADEd at the first fitting rung; if no
-    rung fits, REJECT at submit time — zero service cost spent, goodput
-    over throughput.
+    degradation ladder (dense→sliced downgrade → bounded dispatch quantum)
+    and admit DEGRADEd at the first fitting rung; if no rung fits, REJECT
+    at submit time — zero service cost spent, goodput over throughput.
 
     State is one number: ``backlog_ms``, the summed predicted cost admitted
     since the last flush (``on_flush`` zeroes it).  That makes decisions
@@ -130,22 +121,17 @@ class AdmissionController:
         self.backlog_ms = 0.0
 
     # -------------------------------------------------------------- decision
-    def _planned(self, sched, qry, engine: str, mode: int):
-        """(split, impl) the group would run at — the cached batch-aware plan
-        when one exists, the scheduler's defaults otherwise (admission never
+    def _planned(self, sched, qry, engine: str, mode: int) -> int:
+        """The split the group would run at — the cached batch-aware plan
+        when one exists, the scheduler's default otherwise (admission never
         writes the plan cache)."""
         from .compile import bucket_key
-        fixed = None if sched.impl == "auto" else sched.impl
-        plan = sched.plan_cache.peek(
-            sched._plan_key(bucket_key(qry), mode, engine, sched.impl))
-        if plan is not None:
-            return plan[0], plan[1]
+        split = sched.plan_cache.peek(
+            sched._plan_key(bucket_key(qry), mode, engine))
+        if split is not None:
+            return split
         import repro.core.query as Q
-        split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
-        return split, fixed or "xla"
-
-    def _cost_ms(self, sched, qry, engine: str, split: int, impl: str) -> float:
-        return float(sched._planner_for(engine).estimate(qry, split, impl).t_ms)
+        return 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
 
     def decide(self, sched, inst, now: float,
                deadline_s: Optional[float]) -> AdmissionDecision:
@@ -155,8 +141,8 @@ class AdmissionController:
         qry = inst.qry
         engine = sched._engine_for(qry)
         mode = sched._mode_for(qry)
-        split, impl = self._planned(sched, qry, engine, mode)
-        cost_ms = self._cost_ms(sched, qry, engine, split, impl)
+        split = self._planned(sched, qry, engine, mode)
+        cost_ms = float(sched._planner_for(engine).estimate(qry, split).t_ms)
         wait_s = self.backlog_ms / 1e3
 
         def fits(c_ms: float) -> bool:
@@ -171,22 +157,11 @@ class AdmissionController:
             return AdmissionDecision(ADMIT, "fits", deadline, cost_ms / 1e3,
                                      wait_s)
 
-        # ---- degradation ladder: cheaper impl → sliced engine → bounded
-        # dispatch quantum; taken cumulatively, first fitting rung wins
-        deg_impl: Optional[str] = None
+        # ---- degradation ladder: sliced engine → bounded dispatch quantum;
+        # taken cumulatively, first fitting rung wins
         deg_engine: Optional[str] = None
         best_ms = cost_ms
         rungs = []
-        if pol.degrade_impls:
-            offered = available_impls()
-            for cand in pol.degrade_impls:
-                if cand == impl or cand not in offered:
-                    continue
-                c = self._cost_ms(sched, qry, engine, split, cand)
-                if c < best_ms:
-                    best_ms, deg_impl = c, cand
-            if deg_impl is not None:
-                rungs.append(f"impl={deg_impl}")
         if (pol.allow_engine_downgrade and engine == "dense"
                 and ES.sliceable(qry)):
             best_ms *= pol.sliced_discount
@@ -199,7 +174,7 @@ class AdmissionController:
             self.backlog_ms += best_ms
             return AdmissionDecision(
                 DEGRADE, "degraded: " + ",".join(rungs), deadline,
-                best_ms / 1e3, wait_s, impl=deg_impl, engine=deg_engine,
+                best_ms / 1e3, wait_s, engine=deg_engine,
                 max_batch=pol.degrade_max_batch, rungs=tuple(rungs))
 
         self.n_rejected += 1

@@ -5,14 +5,12 @@ invariant the scheduler can assert on, with hit/miss counters the replay
 harness reports:
 
   PlanCache        (shape bucket, graph fingerprint, mode, engine[, workers])
-                   → chosen (split, hop impl).  The first batch of a bucket
-                   pays one batch-aware planner pass; every later batch
-                   reuses it.
-  ExecutableCache  full dispatch key (plan key + hop-layout signature +
-                   padded batch size) → the bound batched executable from
-                   the engines.  Together with pow-2 size buckets
-                   (compile.py) this caps compilations per shape bucket at
-                   log2(max batch size).
+                   → chosen split.  The first batch of a bucket pays one
+                   batch-aware planner pass; every later batch reuses it.
+  ExecutableCache  full dispatch key (plan key + split + padded batch size)
+                   → the bound batched executable from the engines.
+                   Together with pow-2 size buckets (compile.py) this caps
+                   compilations per shape bucket at log2(max batch size).
 
 The graph fingerprint keys cache entries to graph *content* rather than
 object identity, so a regenerated-but-identical graph still hits while a
@@ -24,10 +22,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 from typing import Callable, Dict, Optional
-
-from ..core import engine as _E
-from ..core import engine_partitioned as _EP
-from ..core import engine_sliced as _ES
 
 
 def graph_fingerprint(graph) -> str:
@@ -56,32 +50,6 @@ def graph_fingerprint(graph) -> str:
     return fp
 
 
-def layout_signature(graph, engine: str, qry, n_workers: int,
-                     impl: str) -> tuple:
-    """The static hop-kernel layout identity a compiled executable binds.
-
-    On the kernel path (``impl != 'xla'``) an executable closes over a
-    ``kernels.hop_scatter`` block layout — dense whole-graph, per-arrival-
-    type slices, or stacked per-worker shards — so the layout's shape is
-    part of the dispatch key: two graphs may share a content fingerprint yet
-    be served by different block shapes only if the key says so.  Building
-    the signature warms the same per-graph layout caches the engine
-    executable will read (layouts are host-static: cached alongside the
-    plan, never retraced)."""
-    if impl == "xla":
-        return ()
-    if engine == "partitioned":
-        _, arrays, _ = _EP.partition_for(graph, n_workers)
-        tables, block_v = arrays.worker_hop_layouts()
-        return ("worker_hop_layout", tuple(tables["hop_ldst"].shape), block_v)
-    if engine == "sliced":
-        sb = _ES.SliceBounds.from_graph(graph)
-        layouts = _ES.slice_layouts_for(graph, qry, sb, impl)
-        return tuple(sorted(
-            (vt,) + lay.signature() for vt, lay in layouts.items()))
-    return _E.hop_layout_for(graph).signature()
-
-
 @dataclasses.dataclass
 class CacheStats:
     hits: int = 0
@@ -100,13 +68,13 @@ class CacheStats:
 
 
 class PlanCache:
-    """(shape bucket, graph fingerprint, ...) → (split point, hop impl)."""
+    """(shape bucket, graph fingerprint, ...) → split point."""
 
     def __init__(self):
-        self._plans: Dict[tuple, tuple] = {}
+        self._plans: Dict[tuple, int] = {}
         self.stats = CacheStats()
 
-    def get(self, key: tuple) -> Optional[tuple]:
+    def get(self, key: tuple) -> Optional[int]:
         plan = self._plans.get(key)
         if plan is None:
             self.stats.misses += 1
@@ -114,10 +82,10 @@ class PlanCache:
             self.stats.hits += 1
         return plan
 
-    def put(self, key: tuple, plan: tuple) -> None:
+    def put(self, key: tuple, plan: int) -> None:
         self._plans[key] = plan
 
-    def peek(self, key: tuple) -> Optional[tuple]:
+    def peek(self, key: tuple) -> Optional[int]:
         """Lookup WITHOUT touching the hit/miss counters — for admission
         control, which consults the cache but must not skew the steady-state
         no-replan invariant the counters assert."""

@@ -27,16 +27,11 @@ sliceable``: every vertex typed, aggregate NONE or COUNT): under
 MIN/MAX groups, wildcard-typed queries, base+delta groups and the
 partitioned engine read the whole graph (``GroupDispatch.extents``).
 
-Hop-delivery lowering: the ``impl`` knob (``HOP_IMPLS``) pins every group on
-one lowering (``'xla'`` or the fused ``'pallas'`` hop kernel), or
-``'auto'`` lets the batch-aware planner sweep (split × impl) with the
-fitted per-impl θ_scatter slopes and dispatch each group on the winner.
-Only lowerings the backend can run are offered (``hop_scatter.
-available_impls``: the kernel where it compiles), and an explicit
-``'pallas'`` the backend's compiler refuses raises at construction.
-The chosen impl and its static hop-layout signature are part of the
-compiled-executable key (sharing a graph fingerprint is not enough — a
-kernel executable binds its block layout).
+Hop delivery has one lowering per edge layout, chosen by the data and not
+by an option: the dense and sliced executables deliver over arrival-sorted
+edges by prefix differences and a segmented min/max scan at the CSR
+offsets; base+delta edges and the partitioned engine's padded per-worker
+slots deliver by segment-sum/min/max scatters (``superstep.deliver``).
 
 The partitioned engine's dispatch is shard_map-native: when >1 JAX devices
 exist and divide ``n_workers`` (CI forces this with
@@ -57,7 +52,7 @@ SLO layer (serving/admission.py, serving/telemetry.py):
   admission  with an ``admission`` controller attached, ``submit`` predicts
              wait + service from the live cost model and returns an
              AdmissionDecision — rejected queries never enter the queue,
-             degraded ones carry per-entry impl/engine/batch-cap overrides
+             degraded ones carry per-entry engine/batch-cap overrides
              that join the group key (degraded groups dispatch separately,
              in bounded chunks the EDF order can interleave);
   telemetry  every timed dispatch records (features, predicted, measured)
@@ -145,20 +140,15 @@ from ..core.planner import Planner, coeff_vector
 from ..core.stats import GraphStats
 from ..faults_common import backoff_delay
 from ..graphdata.queries import QueryInstance
-from ..kernels.hop_scatter import available_impls, require_impl
 from ..obs.trace import NULL_TRACER, phase
 from .admission import AdmissionController, AdmissionDecision, AdmissionPolicy
-from .cache import (ExecutableCache, PlanCache, graph_fingerprint,
-                    layout_signature)
+from .cache import ExecutableCache, PlanCache, graph_fingerprint
 from .compile import bucket_key, compile_plan_tensor
 from .faults import (CompileError, FaultError, FaultPlan, PoisonQueryError,
                      RetryPolicy, TransientDispatchError, WorkerLostError)
 from .telemetry import TelemetryBuffer
 
 ENGINES = ("auto", "dense", "sliced", "partitioned")
-#: hop-delivery lowering knob: fixed, or "auto" = the batch-aware planner
-#: picks per group from the fitted per-impl θ_scatter slopes
-HOP_IMPLS = ("auto", "xla", "pallas", "pallas_interpret")
 
 
 @dataclasses.dataclass
@@ -186,16 +176,15 @@ class QueueEntry:
     inst: QueryInstance
     deadline: float = math.inf   # absolute
     arrival: float = 0.0
-    impl: Optional[str] = None   # admission-degradation overrides (None =
-    engine: Optional[str] = None  # scheduler defaults)
-    max_batch: Optional[int] = None
+    engine: Optional[str] = None  # admission-degradation overrides (None =
+    max_batch: Optional[int] = None  # scheduler defaults)
     span: object = None          # root "query" span (flight recorder)
 
 
 @dataclasses.dataclass
 class GroupDispatch:
     """One vmapped engine call: the scheduler's unit of work."""
-    key: tuple                   # (bucket, mode, engine, impl override)
+    key: tuple                   # (bucket, mode, engine)
     engine: str
     split: int
     n_real: int
@@ -204,7 +193,6 @@ class GroupDispatch:
     indices: List[int]           # queue positions served by this dispatch
     plan_cached: bool
     exec_cached: bool
-    impl: str = "xla"            # hop-delivery lowering the group ran on
     deadline: float = math.inf   # most urgent member's deadline (EDF key)
     predicted_ms: float = 0.0    # cost-model prediction (telemetry rows)
     delta: bool = False          # served on the base+delta executable path
@@ -228,7 +216,7 @@ class BatchScheduler:
 
     Life of a query: ``submit`` admits it (optionally through the SLO
     admission controller) into the queue; ``flush`` groups the queue by
-    (shape bucket, temporal mode, engine, impl override), plans each group
+    (shape bucket, temporal mode, engine), plans each group
     once through the batch-aware cost model (memoised in ``plan_cache``),
     and dispatches ONE vmapped engine call per group through ``exec_cache``
     — earliest-deadline-first, results in submission order.
@@ -283,15 +271,12 @@ class BatchScheduler:
     ):
         if engine not in ENGINES:
             raise ValueError(f"engine must be one of {ENGINES}")
-        if impl not in HOP_IMPLS:
-            raise ValueError(f"impl must be one of {HOP_IMPLS}")
-        if impl != "auto":
-            # an explicit kernel impl the backend cannot compile fails here,
-            # with the compiler's reason, never as per-query failed rows
-            require_impl(impl)
+        if impl != "xla":
+            # the benchmark's configurations still name the one hop-delivery
+            # lowering; nothing else is accepted
+            raise ValueError(f"impl must be 'xla', got {impl!r}")
         self.graph = graph
         self.engine = engine
-        self.impl = impl
         self.n_buckets = n_buckets
         self.n_workers = n_workers
         self.use_shard_map = use_shard_map
@@ -416,7 +401,7 @@ class BatchScheduler:
                 return dec
             if dec.action == "degrade":
                 self.n_degraded += 1
-            self._queue.append(QueueEntry(inst, dec.deadline, now, dec.impl,
+            self._queue.append(QueueEntry(inst, dec.deadline, now,
                                           dec.engine, dec.max_batch,
                                           span=root))
             if self.metrics is not None:
@@ -474,51 +459,39 @@ class BatchScheduler:
             self._planner_part = self._new_planner(self.graph, arrays)
         return self._planner_part
 
-    def _plan_key(self, bucket: tuple, mode: int, engine: str,
-                  impl_choice: str) -> tuple:
+    def _plan_key(self, bucket: tuple, mode: int, engine: str) -> tuple:
         # plans are keyed by the BASE fingerprint: split choice comes from
         # base statistics and stays optimal-enough across edge-append epochs
         # (any split is result-identical); compaction retires the key
         return (bucket, self._plan_fp, mode, engine, self.n_buckets,
-                self.n_workers if engine == "partitioned" else 0, impl_choice)
+                self.n_workers if engine == "partitioned" else 0)
 
     def _plan_group(self, queries: List[Q.PathQuery], bucket: tuple,
-                    mode: int, engine: str,
-                    impl_override: Optional[str] = None):
-        """(split, hop impl, plan_cached, candidates) for one group.  A
-        fixed ``impl`` (the scheduler's, or a per-group admission-
-        degradation override) pins the lowering and the planner only picks
-        the split; ``'auto'`` sweeps (split × impl) with the fitted per-impl
-        θ_scatter slopes.  ``candidates`` is the fresh sweep's candidate
-        list (None on a cache hit or without the planner) — the plan span's
-        audit payload."""
+                    mode: int, engine: str):
+        """(split, plan_cached, candidates) for one group.  ``candidates``
+        is the fresh sweep's candidate list (None on a cache hit or without
+        the planner) — the plan span's audit payload."""
         qry = queries[0]
         default = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
-        impl_choice = impl_override or self.impl
-        fixed_impl = None if impl_choice == "auto" else impl_choice
         if not self.use_planner:
-            return default, fixed_impl or "xla", True, None
-        key = self._plan_key(bucket, mode, engine, impl_choice)
-        plan = self.plan_cache.get(key)
-        if plan is not None:
-            return plan[0], plan[1], True, None
-        impls = available_impls() if fixed_impl is None else (fixed_impl,)
-        est = self._planner_for(engine).choose_batch(queries, impls=impls)
-        split, impl = est.split, fixed_impl or est.impl
-        self.plan_cache.put(key, (split, impl))
-        return split, impl, False, est.candidates
+            return default, True, None
+        key = self._plan_key(bucket, mode, engine)
+        split = self.plan_cache.get(key)
+        if split is not None:
+            return split, True, None
+        est = self._planner_for(engine).choose_batch(queries)
+        self.plan_cache.put(key, est.split)
+        return est.split, False, est.candidates
 
     # ------------------------------------------------------------- dispatch
     def _build_executable(self, qry: Q.PathQuery, split: int, mode: int,
-                          engine: str, impl: str):
+                          engine: str):
         if engine == "partitioned":
             return EP.batch_executable(self._serve_graph, qry, split, mode,
                                        self.n_buckets, self.n_workers,
-                                       use_shard_map=self.use_shard_map,
-                                       impl=impl)
+                                       use_shard_map=self.use_shard_map)
         return E.batch_executable(self._serve_graph, qry, split, mode,
-                                  self.n_buckets,
-                                  sliced=(engine == "sliced"), impl=impl)
+                                  self.n_buckets, sliced=(engine == "sliced"))
 
     def _extents(self, qry: Q.PathQuery, engine: str) -> bool:
         """Does this group run on typed arrival extents?  ``sliced`` forces
@@ -541,6 +514,8 @@ class BatchScheduler:
                       engine: str, impl: str, bucket: tuple, pt, warm: bool):
         """The real build-and-run step: executable cache → one vmapped call,
         timed.  Swapped out wholesale by an injected ``dispatcher``.
+        ``impl`` is the benchmark's fixed slot (always ``'xla'``); nothing
+        reads it.
 
         Delta-eligible groups run ``engine.batch_executable_delta`` against
         the compaction BASE: their cache key carries the base fingerprint
@@ -552,7 +527,6 @@ class BatchScheduler:
             use_delta = self._delta_eligible(queries[0], engine)
             self._last_used_delta = use_delta
             fp = ("delta", self._base_fp) if use_delta else self.fingerprint
-            lay_graph = self.graph if use_delta else self._serve_graph
             # the executor the group runs on: dense groups whose query
             # allows it share the sliced executable
             if self._extents(queries[0], engine):
@@ -561,16 +535,12 @@ class BatchScheduler:
                     self.n_buckets,
                     self.n_workers if engine == "partitioned" else 0,
                     self.n_devices if engine == "partitioned" else 0,
-                    impl,
-                    layout_signature(lay_graph, engine, queries[0],
-                                     self.n_workers, impl),
                     pt.params.shape[0])
             exec_cached = ekey in self.exec_cache
             if use_delta:
                 run0 = self.exec_cache.get_or_build(
                     ekey, lambda: E.batch_executable_delta(
-                        self.graph, queries[0], split, mode, self.n_buckets,
-                        impl=impl))
+                        self.graph, queries[0], split, mode, self.n_buckets))
                 delta = self._delta
                 run = lambda params: run0(params, delta)  # noqa: E731
                 # a cached delta executable still retraces when the padded
@@ -582,7 +552,7 @@ class BatchScheduler:
             else:
                 run = self.exec_cache.get_or_build(
                     ekey, lambda: self._build_executable(queries[0], split,
-                                                         mode, engine, impl))
+                                                         mode, engine))
                 warm_needed = not exec_cached
             if warm and warm_needed:
                 # first dispatch at this key: run once untimed so compile
@@ -603,7 +573,7 @@ class BatchScheduler:
         return res, t1 - t0, exec_cached
 
     def _dispatch(self, queries: List[Q.PathQuery], split: int, mode: int,
-                  engine: str, impl: str, bucket: tuple, pt, warm: bool):
+                  engine: str, bucket: tuple, pt, warm: bool):
         """One dispatch attempt with the named fault-injection points.
 
         This is the single funnel both the real JAX path and an injected
@@ -621,7 +591,7 @@ class BatchScheduler:
             if plan.should_fail("compile"):
                 raise CompileError(
                     f"injected compile failure (engine={engine}, "
-                    f"impl={impl}, split={split})")
+                    f"split={split})")
             if engine == "partitioned" and plan.should_fail("worker"):
                 raise WorkerLostError(
                     f"injected partition-worker loss "
@@ -632,12 +602,12 @@ class BatchScheduler:
         if self.dispatcher is not None:
             t_launch = self._clock()
             res, dt = self.dispatcher.dispatch(
-                self, queries, split, mode, engine, impl, pt, warm)
+                self, queries, split, mode, engine, "xla", pt, warm)
             exec_cached = True
             t_ready = t_launch + dt      # the dispatcher's accounted time
         else:
             res, dt, exec_cached = self._dispatch_jax(
-                queries, split, mode, engine, impl, bucket, pt, warm)
+                queries, split, mode, engine, "xla", bucket, pt, warm)
             t_launch, t_ready = self._stamps
         if plan is not None:
             factor = plan.straggle()
@@ -685,30 +655,27 @@ class BatchScheduler:
         """The currently pinned ``Epoch`` (None before any ``pin_epoch``)."""
         return self._epoch
 
-    def _estimate_query(self, qry: Q.PathQuery, split: int, engine: str,
-                        impl: str):
-        """Memoised per-query PlanEstimate at a concrete (split, impl).
+    def _estimate_query(self, qry: Q.PathQuery, split: int, engine: str):
+        """Memoised per-query PlanEstimate at a concrete split.
 
         Safe across refits: the estimate's FEATURES are θ-independent
         structural sums, and every prediction derived from a memo hit is
         recomputed as ``features @ live θ`` — only the stale ``t_ms`` on
         the cached object must not be read directly."""
-        key = (Q.query_params(qry).tobytes(), qry.shape_key(), split,
-               engine, impl)
+        key = (Q.query_params(qry).tobytes(), qry.shape_key(), split, engine)
         est = self._est_memo.get(key)
         if est is None:
-            est = self._planner_for(engine).estimate(qry, split, impl)
+            est = self._planner_for(engine).estimate(qry, split)
             self._est_memo[key] = est
         return est
 
     def _group_features(self, queries: List[Q.PathQuery], split: int,
-                        engine: str, impl: str, pt):
+                        engine: str, pt):
         """(batch-summed feature row, per-query estimates) for one dispatch
         — the same sums ``Planner.estimate_batch`` produces (identical
         np.sum reduction, so telemetry rows are bit-identical to the
         un-memoised path)."""
-        ests = [self._estimate_query(q, split, engine, impl)
-                for q in queries]
+        ests = [self._estimate_query(q, split, engine) for q in queries]
         feats = np.sum([e.features for e in ests], axis=0)
         if pt.n_pad:
             # padded rows run too: they repeat instance 0's parameters
@@ -735,7 +702,7 @@ class BatchScheduler:
                 self._mx_cache.inc(cache="plan", event="invalidation")
         return predicted_ms
 
-    def _trace_group(self, queue, idxs, ests, feats, split, engine, impl,
+    def _trace_group(self, queue, idxs, ests, feats, split, engine,
                      pt, dt, plan_cached, exec_cached, candidates, seq,
                      edf_pos, group_deadline, predicted_ms, t_launch,
                      t_ready, extents, out):
@@ -755,19 +722,18 @@ class BatchScheduler:
                       else float(feats @ theta))
         cand_attrs = None
         if candidates is not None:
-            cand_attrs = [dict(split=c["split"], impl=c["impl"],
-                               t_ms=float(c["t_ms"]),
+            cand_attrs = [dict(split=c["split"], t_ms=float(c["t_ms"]),
                                features=np.asarray(c["features"]).tolist())
                           for c in candidates]
         q_preds = [float(e.features @ theta) for e in ests]
         pred_sum = sum(q_preds)
         group_ms = dt * 1e3
-        key_repr = repr((engine, impl, split, pt.params.shape[0]))
+        key_repr = repr((engine, split, pt.params.shape[0]))
         for j, i in enumerate(idxs):
             root = queue[i].span
             est = ests[j]
             plan_span = tr.start("plan", parent=root, seq=seq, split=split,
-                                 impl=impl, engine=engine,
+                                 engine=engine,
                                  plan_cached=plan_cached,
                                  predicted_ms=q_preds[j],
                                  features=est.features)
@@ -787,8 +753,7 @@ class BatchScheduler:
             q_meas = group_ms * share
             disp = tr.start(
                 "dispatch", parent=root, seq=seq, batch=pt.n_real,
-                n_pad=pt.n_pad, edf_pos=edf_pos, engine=engine, impl=impl,
-                split=split,
+                n_pad=pt.n_pad, edf_pos=edf_pos, engine=engine, split=split,
                 deadline=(None if math.isinf(group_deadline)
                           else group_deadline),
                 predicted_ms=q_preds[j], measured_ms=q_meas,
@@ -816,7 +781,7 @@ class BatchScheduler:
 
     def flush(self, warm: bool = False) -> List[ServedResult]:
         """Drain the queue: one vmapped engine call per (bucket, mode,
-        engine, impl-override) group chunk, dispatched EARLIEST-DEADLINE-
+        engine) group chunk, dispatched EARLIEST-DEADLINE-
         FIRST (no-deadline entries all tie at +inf, so the historical
         arrival order is preserved); results return in submission order.
         ``warm=True`` runs each executable once untimed first (compile
@@ -864,7 +829,7 @@ class BatchScheduler:
         for i, entry in enumerate(queue):
             qry = entry.inst.qry
             key = (bucket_key(qry), self._mode_for(qry),
-                   entry.engine or self._engine_for(qry), entry.impl)
+                   entry.engine or self._engine_for(qry))
             groups.setdefault(key, []).append(i)
 
         # EDF at dispatch-chunk granularity: each group's members sort by
@@ -936,7 +901,7 @@ class BatchScheduler:
         machine (the historical one-attempt behaviour when no ``retry``
         policy is attached)."""
         t_start = self._clock()
-        bucket, mode, engine, impl_over = key
+        bucket, mode, engine = key
         fallback_from = ""
         # partitioned-path availability: while the planner holds the path
         # down, units re-plan onto the dense executor (bit-identical
@@ -958,13 +923,12 @@ class BatchScheduler:
         while True:
             try:
                 with phase("sched.plan"):
-                    split, impl, plan_cached, candidates = self._plan_group(
-                        queries, bucket, mode, engine,
-                        impl_override=impl_over)
+                    split, plan_cached, candidates = self._plan_group(
+                        queries, bucket, mode, engine)
                 with phase("sched.plan_tensor"):
                     pt = compile_plan_tensor(queries, pad=self.pad_batches)
                 res, dt_raw, exec_cached = self._dispatch(
-                    queries, split, mode, engine, impl, bucket, pt, warm)
+                    queries, split, mode, engine, bucket, pt, warm)
                 break
             except FaultError as e:
                 if self.retry is None:
@@ -1013,8 +977,8 @@ class BatchScheduler:
                 if t_now + delay > group_deadline:
                     # retry budget exhausted: a retry never fires past the
                     # EDF deadline — re-enter admission once with the
-                    # remaining budget (an admit earns one immediate,
-                    # possibly impl-degraded, attempt), else time out
+                    # remaining budget (an admit earns one immediate
+                    # attempt), else time out
                     if not readmitted and self.admission is not None:
                         i0 = min(idxs, key=lambda i: queue[i].deadline)
                         dec = self.admission.decide(
@@ -1022,8 +986,6 @@ class BatchScheduler:
                             max(group_deadline - t_now, 0.0))
                         if dec.admitted:
                             readmitted = True
-                            if dec.impl is not None:
-                                impl_over = dec.impl
                             attempt += 1
                             self._trace_fault(e, "readmit", attempt, idxs)
                             continue
@@ -1057,8 +1019,7 @@ class BatchScheduler:
         self._dispatch_seq += 1
         feats = ests = None
         if self.telemetry is not None or self.tracer.enabled:
-            feats, ests = self._group_features(queries, split, engine,
-                                               impl, pt)
+            feats, ests = self._group_features(queries, split, engine, pt)
         predicted_ms = 0.0
         if self.telemetry is not None:
             # θ refit sees the RAW dispatch time: retry backoff is queueing
@@ -1101,13 +1062,13 @@ class BatchScheduler:
             # JAX calls measurably pollutes the CPU caches the next
             # dispatch runs on (the bench obs leg gates this at ≤5%)
             traced_groups.append(
-                (idxs, ests, feats, split, engine, impl, pt, dt_raw,
+                (idxs, ests, feats, split, engine, pt, dt_raw,
                  plan_cached, exec_cached, candidates, seq, edf_pos,
                  group_deadline, predicted_ms, t_launch, t_ready,
                  extents))
         dispatches.append(GroupDispatch(
             key, engine, split, pt.n_real, pt.n_pad, dt_total, list(idxs),
-            plan_cached, exec_cached, impl, group_deadline, predicted_ms,
+            plan_cached, exec_cached, group_deadline, predicted_ms,
             delta=self._last_used_delta, n_retries=n_retries,
             fallback_from=fallback_from, penalty_s=penalty_s,
             t_start=t_start, t_launch=t_launch, t_ready=t_ready,

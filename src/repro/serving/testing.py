@@ -17,6 +17,10 @@ Everything downstream — EDF ordering, chunking, telemetry recording,
 admission backlog, replay accounting — runs EXACTLY the production code
 path; only the JAX call is swapped out.  Zero compilation, virtual time.
 
+Service models and ``dispatch`` take an ``impl`` argument: the benchmark's
+fixed slot for the one hop-delivery lowering, always ``'xla'``.  Nothing
+here reads it.
+
 Fault injection rides the same funnel: the scheduler consults its
 ``FaultPlan`` in ``BatchScheduler._dispatch`` BEFORE delegating here, so
 chaos tests (``tests/test_serving_faults.py``) exercise retry, quarantine,
@@ -64,10 +68,10 @@ def planner_service_model(true_coeffs: dict, scale: float = 1.0
         if theta_star is None:
             theta_star = coeff_vector(true_coeffs)
         planner = sched._planner_for(engine)
-        feats = planner.estimate_batch(queries, split, impl=impl).features
+        feats = planner.estimate_batch(queries, split).features
         if pt.n_pad:
             feats = feats + pt.n_pad * planner.estimate(
-                queries[0], split, impl).features
+                queries[0], split).features
         return float(feats @ theta_star) * scale / 1e3
 
     return model
@@ -90,7 +94,6 @@ class FakeCall:
     split: int
     mode: int
     engine: str
-    impl: str
     n_real: int
     n_pad: int
     service_s: float
@@ -100,7 +103,7 @@ class FakeDispatcher:
     """Drop-in for the scheduler's JAX dispatch: synthetic service times,
     deterministic outputs, optional injected failures.
 
-    ``fail``: predicate ``(queries, engine, impl) -> bool`` — a True return
+    ``fail``: predicate ``(queries, engine) -> bool`` — a True return
     raises inside dispatch, exercising the scheduler's failing-group
     isolation and the replay harness's failed-group accounting without
     needing a real trace-time error.
@@ -116,9 +119,9 @@ class FakeDispatcher:
 
     def dispatch(self, sched, queries, split, mode, engine, impl,
                  pt: PlanTensor, warm: bool):
-        if self.fail is not None and self.fail(queries, engine, impl):
+        if self.fail is not None and self.fail(queries, engine):
             raise RuntimeError(
-                f"injected dispatch failure (engine={engine}, impl={impl})")
+                f"injected dispatch failure (engine={engine})")
         service_s = float(self.service_model(
             sched, queries, split, mode, engine, impl, pt))
         b_pad = pt.params.shape[0]
@@ -127,6 +130,6 @@ class FakeDispatcher:
             total[j] = fake_count(q)
         total[len(queries):] = total[0] if queries else 0.0  # pad rows
         pv = (np.zeros((b_pad, 1), np.float64) if self.per_vertex else None)
-        self.calls.append(FakeCall(list(queries), split, mode, engine, impl,
+        self.calls.append(FakeCall(list(queries), split, mode, engine,
                                    pt.n_real, pt.n_pad, service_s))
         return FakeOutput(total, pv), service_s

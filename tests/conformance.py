@@ -16,11 +16,11 @@ are compared with ``np.array_equal`` — any divergence between executors is a
 hard failure, which is what makes the partitioned closure (MIN/MAX extremum
 exchange, rank-prefix ETR exchange) safe to ship.
 
-A second axis (``IMPLS``) reruns the engines with the fused hop-kernel
-delivery (``impl='pallas'``, interpreter mode on CPU CI): the kernel legs
-must be bit-identical to xla for every engine × mode × aggregate — exact
-because engine counts are integers in float32, so prefix-difference sums
-equal scatter sums bit for bit.
+The dense and sliced legs deliver hops by prefix differences over
+arrival-sorted edges, the partitioned legs by segment-sum scatters over
+padded per-worker slots; agreement between them is exact because engine
+counts are integers in float32, so prefix-difference sums equal scatter
+sums bit for bit.
 
 Oracle-leg scope (the oracle only *defines* a subset of the surface):
   * path counts: all three modes (float64 enumeration → tolerance compare
@@ -66,10 +66,6 @@ ALL_MODES = (E.MODE_STATIC, E.MODE_BUCKET, E.MODE_INTERVAL)
 WORKERS_FULL = (2, 4, 8)
 WORKERS_SMOKE = (2, 4)
 N_BUCKETS = 8
-#: the hop-delivery lowering axis: every matrix cell runs its engines under
-#: both and the kernel legs must be bit-identical to the xla legs (on CPU CI
-#: the kernels run in interpreter mode via the auto interpret default)
-IMPLS = ("xla", "pallas")
 
 
 def scale() -> str:
@@ -190,16 +186,9 @@ def _np(x):
 def engine_results(graph, qry: Q.PathQuery, mode: int,
                    workers: Sequence[int] = WORKERS_SMOKE,
                    n_buckets: int = N_BUCKETS,
-                   split: Optional[int] = None,
-                   impls: Sequence[str] = IMPLS) -> Dict[str, dict]:
+                   split: Optional[int] = None) -> Dict[str, dict]:
     """Run every applicable executor; returns name → {total, per_vertex,
-    minmax} numpy views.
-
-    ``impls`` adds the hop-delivery lowering axis: for every non-xla impl
-    the dense/sliced legs and the partitioned legs (first worker count at
-    smoke scale, the full sweep at ci scale) rerun through the fused kernel
-    path and are compared bit-for-bit against the xla dense leg like any
-    other executor."""
+    minmax} numpy views."""
     legs = {}
 
     def record(name, out):
@@ -215,21 +204,6 @@ def engine_results(graph, qry: Q.PathQuery, mode: int,
         record(f"partitioned-w{w}",
                EP.execute(graph, qry, split=split, mode=mode,
                           n_buckets=n_buckets, n_workers=w))
-    kernel_workers = workers if scale() == "ci" else tuple(workers)[:1]
-    for impl in impls:
-        if impl == "xla":
-            continue
-        record(f"dense+{impl}",
-               E.execute(graph, qry, split=split, mode=mode,
-                         n_buckets=n_buckets, sliced=False, impl=impl))
-        if ES.sliceable(qry):
-            record(f"sliced+{impl}",
-                   E.execute(graph, qry, split=split, mode=mode,
-                             n_buckets=n_buckets, sliced=True, impl=impl))
-        for w in kernel_workers:
-            record(f"partitioned-w{w}+{impl}",
-                   EP.execute(graph, qry, split=split, mode=mode,
-                              n_buckets=n_buckets, n_workers=w, impl=impl))
     return legs
 
 

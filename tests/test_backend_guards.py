@@ -1,18 +1,16 @@
 """No fallback hides the device, and the entry points' start-up rules.
 
-* ``impl='auto'`` and the admission ladder offer the fused hop kernel only
-  on a backend where it compiles, and an explicit ``impl='pallas'`` there
-  raises at construction with the compiler's reason;
+* the scheduler's ``impl`` slot accepts only ``'xla'``, the one
+  hop-delivery lowering, and the admission ladder decides the same way
+  whatever the backend is called;
 * ``launch/query.py`` exits non-zero when a dispatch raises a real error;
 * the persistent compilation cache sits where ``JAX_COMPILATION_CACHE_DIR``
   says, else at one fixed path inside the checkout;
 * ``chip_smoke.py`` refuses to run without a TPU or without the repo, and
   its phases run end to end at a tiny size.
 
-Tests that need another backend steer it by monkeypatching
-``jax.default_backend`` inside the test: the kernel probe then compiles the
-kernel for that backend on this host's CPU, which the compiler refuses just
-as the TPU compiler does today.
+Tests that need another backend name steer it by monkeypatching
+``jax.default_backend`` inside the test.
 """
 import importlib.util
 import os
@@ -23,10 +21,7 @@ import sys
 import jax
 import pytest
 
-from repro.core import engine as E
-from repro.core import engine_partitioned as EP
 from repro.graphdata.queries import make_workload
-from repro.kernels import hop_scatter as HK
 from repro.launch import compile_cache
 from repro.serving import AdmissionPolicy, BatchScheduler
 from repro.serving.testing import FakeDispatcher
@@ -40,67 +35,45 @@ def _on(monkeypatch, backend: str) -> None:
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
 
 
-def test_cpu_offers_the_kernel():
-    assert jax.default_backend() == "cpu"
-    assert HK.kernel_refusal() is None
-    assert HK.available_impls() == ("xla", "pallas")
+@pytest.mark.parametrize("value", ["xla", "auto", "pallas",
+                                   "pallas_interpret"])
+def test_scheduler_impl_slot(small_static_graph, value):
+    """``impl`` names the one hop-delivery lowering: ``'xla'`` is accepted
+    and every other value raises at construction."""
+    if value == "xla":
+        sched = BatchScheduler(small_static_graph, impl=value,
+                               dispatcher=FakeDispatcher())
+        wl = make_workload(small_static_graph, templates=("Q2",),
+                           n_per_template=1, seed=0)
+        assert [r.status for r in sched.run(wl)] == ["done"]
+        return
+    with pytest.raises(ValueError, match="impl must be 'xla'"):
+        BatchScheduler(small_static_graph, impl=value)
 
 
-@pytest.mark.parametrize("backend,want", [("cpu", "pallas"), ("tpu", "xla")])
-def test_auto_impl_offers_only_what_compiles(monkeypatch, medium_static_graph,
-                                             backend, want):
-    """With the kernel priced cheaper, 'auto' picks it where it compiles
-    and never where it does not."""
-    _on(monkeypatch, backend)
-    wl = make_workload(medium_static_graph, templates=("Q2", "Q4"),
-                       n_per_template=2, seed=7)
-    sched = BatchScheduler(medium_static_graph, engine="dense", impl="auto",
-                           dispatcher=FakeDispatcher())
-    sched._planner.coeffs["theta_scatter_xla"] = 10.0
-    res = sched.run(wl)
-    assert all(r.status == "done" for r in res)
-    assert {d.impl for d in sched.last_dispatches} == {want}
-
-
-@pytest.mark.parametrize("backend,want", [("cpu", "degrade"),
-                                          ("tpu", "reject")])
-def test_admission_ladder_offers_only_what_compiles(
-        monkeypatch, medium_static_graph, backend, want):
-    """The cheaper-impl rung is the only one enabled: it degrades onto the
-    kernel where the kernel compiles, and rejects where it does not."""
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_admission_ladder_backend_neutral(monkeypatch, medium_static_graph,
+                                          backend):
+    """The same overloaded submits decide the same way under either backend
+    name: the sliced and quantum rungs degrade the first, the second is
+    rejected, and no rung names a lowering."""
     _on(monkeypatch, backend)
     inst = make_workload(medium_static_graph, templates=("Q2",),
                          n_per_template=1, seed=46)[0]
-    pol = AdmissionPolicy(headroom=1.0, degrade_impls=("xla", "pallas"),
-                          allow_engine_downgrade=False,
-                          degrade_max_batch=None)
-    sched = BatchScheduler(medium_static_graph, dispatcher=FakeDispatcher(),
-                           admission=pol)
-    sched._planner.coeffs["theta_scatter_xla"] = 10.0
+    sched = BatchScheduler(medium_static_graph, engine="dense",
+                           dispatcher=FakeDispatcher(),
+                           admission=AdmissionPolicy(headroom=1.0))
     split = inst.qry.n_vertices - 1
-    c_xla = sched._planner.estimate(inst.qry, split, "xla").t_ms / 1e3
-    dec = sched.submit(inst, deadline_s=0.9 * c_xla, now=0.0)
-    assert dec.action == want
-    assert ("impl=pallas" in dec.rungs) == (want == "degrade")
-
-
-@pytest.mark.parametrize("entry", ["scheduler", "dense", "partitioned"])
-def test_explicit_pallas_raises_where_it_does_not_compile(
-        monkeypatch, small_static_graph, entry):
-    _on(monkeypatch, "tpu")
-    qry = make_workload(small_static_graph, templates=("Q2",),
-                        n_per_template=1, seed=0)[0].qry
-    build = {
-        "scheduler": lambda: BatchScheduler(small_static_graph,
-                                            impl="pallas"),
-        "dense": lambda: E.batch_executable(small_static_graph, qry,
-                                            impl="pallas"),
-        "partitioned": lambda: EP.batch_executable(small_static_graph, qry,
-                                                   impl="pallas"),
-    }[entry]
-    with pytest.raises(RuntimeError,
-                       match=r"does not compile on the tpu backend: \w+"):
-        build()
+    cost_s = sched._planner.estimate(inst.qry, split).t_ms / 1e3
+    first = sched.submit(inst, deadline_s=0.9 * cost_s, now=0.0)
+    second = sched.submit(inst, deadline_s=0.5 * cost_s, now=0.0)
+    got = [(d.action, d.rungs, d.engine, d.max_batch)
+           for d in (first, second)]
+    assert got == [("degrade", ("engine=sliced", "quantum=8"), "sliced", 8),
+                   ("reject", (), None, None)]
+    assert first.predicted_s == pytest.approx(0.7 * cost_s, rel=1e-12)
+    assert not any(r.startswith("impl=") for d in (first, second)
+                   for r in d.rungs)
 
 
 @pytest.mark.parametrize("mode,fail", [("--serve", True), ("--replay", True),

@@ -81,32 +81,40 @@ def test_serving_conformance_matrix(small_dynamic_graph, matrix, name, mode):
 
 @pytest.mark.parametrize("engine,n_workers", [("dense", 0),
                                               ("partitioned", 2)])
-def test_serving_kernel_impl_matches_xla(small_dynamic_graph, matrix, engine,
-                                         n_workers):
-    """Scheduler dispatches on the fused-kernel lowering are bit-identical
-    to the xla dispatches (representative cells; the multidevice leg and the
-    kernels leg cover the full matrix)."""
+def test_serving_one_program_per_shape(small_dynamic_graph, matrix, engine,
+                                       n_workers):
+    """Serving representative matrix cells at every batch size 1-8 builds
+    one executable per (shape, split, padded batch) and no other, and every
+    answer equals ``engine.execute``'s for the same query and split."""
     from repro.serving import BatchScheduler
+    from repro.serving.compile import bucket_key
 
+    sched = BatchScheduler(small_dynamic_graph, engine=engine,
+                           mode=E.MODE_BUCKET, n_buckets=C.N_BUCKETS,
+                           n_workers=max(n_workers, 1), keep_outputs=True)
+    programs = set()
     for name in ("plain-2hop", "agg-min"):
-        queries = C.perturbed_batch(matrix[name].qry, 3)
-        outs = {}
-        for impl in ("xla", "pallas"):
-            sched = BatchScheduler(small_dynamic_graph, engine=engine,
-                                   mode=E.MODE_BUCKET, n_buckets=C.N_BUCKETS,
-                                   n_workers=max(n_workers, 1),
-                                   keep_outputs=True, impl=impl)
+        qry = matrix[name].qry
+        mode = sched._mode_for(qry)
+        for b in range(1, 9):
+            queries = C.perturbed_batch(qry, b)
             res = sched.run(queries)
-            assert len(sched.last_dispatches) == 1
-            assert sched.last_dispatches[0].impl == impl
-            outs[impl] = res
-        for a, b in zip(outs["xla"], outs["pallas"]):
-            assert a.split == b.split, name
-            for field in ("total", "per_vertex", "minmax"):
-                x, y = getattr(a, field), getattr(b, field)
-                if x is None and y is None:
-                    continue
-                assert np.array_equal(x, y), (name, engine, field)
+            (d,) = sched.last_dispatches
+            assert d.n_real == b and (d.n_real + d.n_pad) in (1, 2, 4, 8)
+            programs.add((bucket_key(qry), d.split, d.n_real + d.n_pad))
+            for q, r in zip(queries, res):
+                out = E.execute(small_dynamic_graph, q, split=r.split,
+                                mode=mode, n_buckets=C.N_BUCKETS,
+                                sliced=False)
+                for field in ("total", "per_vertex", "minmax"):
+                    want, got = getattr(out, field), getattr(r, field)
+                    if want is None and got is None:
+                        continue
+                    assert np.array_equal(np.asarray(want), got), (
+                        name, b, field)
+    assert len(programs) == 8
+    assert len(sched.exec_cache) == len(programs)
+    assert sched.exec_cache.stats.misses == len(programs)
 
 
 def test_serving_empty_batch(small_dynamic_graph):

@@ -77,3 +77,24 @@ def test_bucket_mask_covers_interval(s, e, B):
     for b in range(B):
         expect = (s < edges[b + 1]) and (edges[b] < e)
         assert m[b] == expect
+
+
+@pytest.mark.parametrize("N,B", [(512, 8), (3000, 16), (100, 32)])
+def test_interval_bucket_mask_sweep(N, B):
+    """Counts times the bucket mask keep exactly the buckets an interval
+    overlaps (``s < hi and lo < e``), over random and inverted intervals;
+    where the interval is non-empty that is the exact intersection test."""
+    rng = np.random.default_rng(N + B)
+    counts = rng.normal(size=(N, B)).astype(np.float32)
+    ivl = np.stack([rng.integers(0, 500, N), rng.integers(0, 1100, N)],
+                   1).astype(np.int32)
+    edges = iv.bucket_edges(0, 1096, B)
+    got = np.asarray(jnp.asarray(counts) * iv.interval_to_bucket_mask(
+        jnp.asarray(ivl), jnp.asarray(edges)))
+    lo, hi = edges[:-1][None, :], edges[1:][None, :]
+    s, e = ivl[:, :1], ivl[:, 1:]
+    overlap = (s < hi) & (lo < e)
+    np.testing.assert_array_equal(got, counts * overlap)
+    nonempty = ivl[:, 0] < ivl[:, 1]
+    exact = np.maximum(s, lo) < np.minimum(e, hi)
+    np.testing.assert_array_equal(overlap[nonempty], exact[nonempty])

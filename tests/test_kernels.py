@@ -1,25 +1,12 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs jnp oracle.
-
-The ``kernels`` marker selects the hop-kernel equivalence leg (fused hop
-kernel vs the superstep XLA path across the temporal-mode × aggregate
-matrix, empty blocks, padded slots, layout invariants) that scripts/ci.sh
-runs as its own full-gate step.
-"""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs jnp oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import query as Q
-from repro.core import superstep as SS
-from repro.core.intervals import bucket_edges
-from repro.kernels import hop_scatter as HK
-from repro.kernels.bucket_scatter import bucket_scatter, bucket_scatter_ref
-from repro.kernels.bucket_scatter.ops import build_layout
 from repro.kernels.common import check_impl, resolve_interpret
 from repro.kernels.embedding_bag import embedding_bag, embedding_bag_ref
 from repro.kernels.flash_attention import attention_ref, flash_attention
-from repro.kernels.interval_warp import interval_warp, interval_warp_ref
 
 RNG = np.random.default_rng(42)
 
@@ -57,43 +44,6 @@ def test_flash_attention_decode_offset():
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-@pytest.mark.parametrize("E,V,C", [(1000, 100, 8), (5000, 700, 16), (300, 512, 4)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_bucket_scatter_sweep(E, V, C, dtype):
-    seg = np.sort(RNG.integers(0, V, size=E)).astype(np.int32)
-    contrib = jnp.asarray(RNG.normal(size=(E, C)), dtype)
-    lay = build_layout(seg, V, block_v=128, block_e_mult=128)
-    want = bucket_scatter_ref(contrib, jnp.asarray(seg), V)
-    got = bucket_scatter(contrib, jnp.asarray(seg), V, layout=lay,
-                         impl="pallas", interpret=True)
-    tol = 1e-4 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=tol, rtol=tol)
-
-
-def test_bucket_scatter_empty_segments():
-    seg = np.asarray([3, 3, 9], np.int32)
-    contrib = jnp.ones((3, 2), jnp.float32)
-    lay = build_layout(seg, 16, block_v=8, block_e_mult=8)
-    got = bucket_scatter(contrib, jnp.asarray(seg), 16, layout=lay,
-                         impl="pallas", interpret=True)
-    want = np.zeros((16, 2))
-    want[3] = 2
-    want[9] = 1
-    np.testing.assert_allclose(np.asarray(got), want)
-
-
-@pytest.mark.parametrize("N,B", [(512, 8), (3000, 16), (100, 32)])
-def test_interval_warp_sweep(N, B):
-    cnts = jnp.asarray(RNG.normal(size=(N, B)), jnp.float32)
-    ivl = np.stack([RNG.integers(0, 500, N), RNG.integers(0, 1100, N)], 1)
-    be = jnp.asarray(bucket_edges(0, 1096, B))
-    want = interval_warp_ref(cnts, jnp.asarray(ivl.astype(np.int32)), be)
-    got = interval_warp(cnts, jnp.asarray(ivl.astype(np.int32)), be,
-                        impl="pallas", interpret=True, block_n=256)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
 @pytest.mark.parametrize("V,D,Bb,L", [(1000, 32, 64, 8), (257, 16, 33, 3),
                                       (4096, 64, 16, 1)])
 @pytest.mark.parametrize("mode", ["sum", "mean"])
@@ -113,204 +63,6 @@ def test_embedding_bag_all_padding():
     np.testing.assert_array_equal(np.asarray(got), np.zeros((4, 4)))
 
 
-# =========================================================================
-# hop_scatter: the fused hop kernel vs the superstep XLA path
-# =========================================================================
-def _hop_problem(V=97, E=900, n_buckets=6, seed=0):
-    """A random one-hop problem with INTEGER counts (the engine's invariant
-    that makes kernel and XLA sums bit-identical)."""
-    rng = np.random.default_rng(seed)
-    t_dst = np.sort(rng.integers(0, V, E)).astype(np.int32)
-    t_src = jnp.asarray(rng.integers(0, V, E).astype(np.int32))
-    wmask = jnp.asarray(rng.random(E) < 0.6)
-    bedges = jnp.asarray(bucket_edges(0, 960, n_buckets))
-    return V, E, t_dst, t_src, wmask, bedges, rng
-
-
-def _mode_state(rng, V, E, mode, B):
-    if mode == SS.MODE_STATIC:
-        return (jnp.asarray(rng.integers(0, 9, V).astype(np.float32)), None)
-    if mode == SS.MODE_BUCKET:
-        return (jnp.asarray(rng.integers(0, 9, (V, B)).astype(np.float32)),
-                jnp.asarray(rng.random((E, B)) < 0.7))
-    ivl = np.sort(rng.integers(0, 960, (E, 2)), axis=1).astype(np.int32)
-    return (jnp.asarray(rng.integers(0, 4, (V, B, B + 1)).astype(np.float32)),
-            jnp.asarray(ivl))
-
-
-def _xla_hop(state, t_src, wmask, evalid, t_dst, V, mode, mch=None,
-             op=Q.AGG_MIN):
-    sv = state[t_src]
-    cnt = SS.apply_edge(sv, wmask, evalid, mode)
-    arr = SS.deliver(cnt, jnp.asarray(t_dst), V)
-    if mch is None:
-        return arr, None
-    m_e = SS.minmax_edge(mch[t_src], cnt, op, mode)
-    return arr, SS.deliver_extremum(m_e, jnp.asarray(t_dst), V, op)
-
-
-@pytest.mark.kernels
-@pytest.mark.parametrize("mode", [SS.MODE_STATIC, SS.MODE_BUCKET,
-                                  SS.MODE_INTERVAL])
-@pytest.mark.parametrize("agg", ["count", "min", "max"])
-@pytest.mark.parametrize("block_v", [None, 32])   # single-block & multi-block
-def test_hop_kernel_vs_deliver(mode, agg, block_v):
-    """The conformance cell of the kernel layer: fused gather→mask→reduce ≡
-    the three-step XLA hop, bit for bit, per temporal mode × aggregate."""
-    B = 6
-    V, E, t_dst, t_src, wmask, bedges, rng = _hop_problem()
-    state, evalid = _mode_state(rng, V, E, mode, B)
-    lay = HK.build_hop_layout(t_dst, V, block_v=block_v, block_e_mult=128)
-    mch = (None if agg == "count"
-           else jnp.asarray(rng.random(V).astype(np.float32)))
-    op = Q.AGG_MIN if agg == "min" else Q.AGG_MAX
-    with SS.bucket_scope(bedges):
-        want, want_m = jax.jit(
-            lambda s, w, e, m: _xla_hop(s, t_src, w, e, t_dst, V, mode, m, op)
-        )(state, wmask, evalid, mch)
-        got, got_m = jax.jit(
-            lambda s, w, e, m: SS.fused_hop_deliver(
-                s, t_src, w, e, mode, lay.tables, lay.block_v, V,
-                impl="pallas_interpret", mch=m, minmax_op=op)
-        )(state, wmask, evalid, mch)
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-    if mch is not None:
-        assert np.array_equal(np.asarray(want_m), np.asarray(got_m))
-
-
-@pytest.mark.kernels
-@pytest.mark.parametrize("mode", [SS.MODE_STATIC, SS.MODE_BUCKET])
-def test_hop_kernel_empty_blocks(mode):
-    """Whole destination blocks without edges (and trailing edgeless
-    destinations) deliver exact zeros / extremum neutrals."""
-    B = 4
-    V, E = 100, 60
-    rng = np.random.default_rng(3)
-    # all edges arrive in [0, 20) → blocks past the first are empty
-    t_dst = np.sort(rng.integers(0, 20, E)).astype(np.int32)
-    t_src = jnp.asarray(rng.integers(0, V, E).astype(np.int32))
-    wmask = jnp.asarray(np.ones(E, bool))
-    bedges = jnp.asarray(bucket_edges(0, 960, B))
-    state, evalid = _mode_state(rng, V, E, mode, B)
-    lay = HK.build_hop_layout(t_dst, V, block_v=16, block_e_mult=128)
-    mch = jnp.asarray(rng.random(V).astype(np.float32))
-    with SS.bucket_scope(bedges):
-        want, want_m = _xla_hop(state, t_src, wmask, evalid, t_dst, V, mode,
-                                mch)
-        got, got_m = SS.fused_hop_deliver(
-            state, t_src, wmask, evalid, mode, lay.tables, lay.block_v, V,
-            impl="pallas_interpret", mch=mch)
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-    assert np.array_equal(np.asarray(want_m), np.asarray(got_m))
-    assert float(np.abs(np.asarray(got)[20:]).sum()) == 0.0
-    assert (np.asarray(got_m)[20:] == np.inf).all()
-
-
-@pytest.mark.kernels
-def test_hop_kernel_padded_slots():
-    """Pad slots (forced-oversized block_e) read the zero row and contribute
-    nothing; src sentinels (out-of-table sources) do the same."""
-    V, E, t_dst, t_src, wmask, bedges, rng = _hop_problem(V=40, E=50)
-    state, evalid = _mode_state(rng, V, E, SS.MODE_BUCKET, 6)
-    # sentinel sources: point some edges at the zero row (slot V)
-    src_sentinel = jnp.where(jnp.arange(E) % 5 == 0, V, t_src)
-    lay = HK.build_hop_layout(t_dst, V, block_v=None, block_e_mult=512)
-    assert lay.block_e >= 512 > E    # real padding exercised
-    with SS.bucket_scope(bedges):
-        state_p = jnp.concatenate([state, jnp.zeros((1, 6), state.dtype)])
-        want, _ = _xla_hop(state_p, src_sentinel, wmask, evalid, t_dst, V,
-                           SS.MODE_BUCKET)
-        got, _ = SS.fused_hop_deliver(
-            state, src_sentinel, wmask, evalid, SS.MODE_BUCKET, lay.tables,
-            lay.block_v, V, impl="pallas_interpret")
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-
-
-@pytest.mark.kernels
-def test_scatter_deliver_and_extremum_vs_xla():
-    """Delivery-only entries (the ETR-hop path): blocked prefix reduce and
-    masked extremum ≡ segment_sum / segment_min over the same layout."""
-    V, E, t_dst, t_src, wmask, bedges, rng = _hop_problem(V=70, E=400)
-    cnt = jnp.asarray(rng.integers(0, 7, (E, 5)).astype(np.float32))
-    lay = HK.build_hop_layout(t_dst, V, block_v=32, block_e_mult=128)
-    want = SS.deliver(cnt, jnp.asarray(t_dst), V)
-    got = SS.deliver(cnt, jnp.asarray(t_dst), V, impl="pallas_interpret",
-                     layout=lay)
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-    m_e = jnp.asarray(rng.random(E).astype(np.float32))
-    for op in (Q.AGG_MIN, Q.AGG_MAX):
-        want_m = SS.deliver_extremum(m_e, jnp.asarray(t_dst), V, op)
-        got_m = SS.deliver_extremum(m_e, jnp.asarray(t_dst), V, op,
-                                    impl="pallas_interpret", layout=lay)
-        assert np.array_equal(np.asarray(want_m), np.asarray(got_m))
-
-
-@pytest.mark.kernels
-def test_worker_layouts_share_slot_shape():
-    """Per-worker layouts stack: one (n_blocks, block_e, block_v) across
-    ragged shards, pads delivering to the sliced-off trash segment."""
-    rng = np.random.default_rng(5)
-    v_max, W = 30, 3
-    rows = []
-    for w in range(W):
-        n = rng.integers(10, 60)
-        seg = np.sort(rng.integers(0, v_max, n)).astype(np.int32)
-        rows.append(np.concatenate([seg, np.full(80 - n, v_max, np.int32)]))
-    layouts = HK.build_worker_layouts(np.stack(rows), v_max + 1)
-    assert len({(l.n_blocks, l.block_e, l.block_v) for l in layouts}) == 1
-    tables = HK.stack_layout_tables(layouts)
-    assert tables["hop_ldst"].shape[0] == W
-    cnt = jnp.asarray(rng.integers(0, 5, (W, 80, 2)).astype(np.float32))
-    lt = {k[len("hop_"):]: v for k, v in tables.items()}
-    got = jax.vmap(lambda c, t: HK.scatter_deliver(
-        c, t, v_max + 1, layouts[0].block_v))(cnt, lt)
-    want = jax.vmap(lambda c, d: SS.deliver(c, d, v_max + 1))(
-        cnt, jnp.asarray(np.stack(rows)))
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-
-
-@pytest.mark.kernels
-def test_build_hop_layout_invariants_hypothesis():
-    """Property test: every edge lands in exactly one valid slot, block-local
-    destinations stay in range, and the boundary tables tile each block's
-    real slots exactly."""
-    hyp = pytest.importorskip("hypothesis")
-    from hypothesis import given, settings, strategies as st
-
-    @settings(max_examples=40, deadline=None)
-    @given(st.integers(1, 200), st.integers(0, 500), st.integers(0, 2 ** 31),
-           st.sampled_from([None, 16, 64]))
-    def check(num_segments, n_edges, seed, block_v):
-        rng = np.random.default_rng(seed)
-        seg = np.sort(rng.integers(0, num_segments, n_edges)).astype(np.int32)
-        lay = HK.build_hop_layout(seg, num_segments, block_v=block_v,
-                                  block_e_mult=128)
-        host = lay.host
-        # every edge placed exactly once, in ascending order per block
-        placed = np.sort(host.gather_idx[host.valid])
-        assert np.array_equal(placed, np.arange(n_edges))
-        valid2d = host.valid.reshape(host.n_blocks, host.block_e)
-        # valid slots are a prefix of each block; local dst within range
-        for b in range(host.n_blocks):
-            n = int(valid2d[b].sum())
-            assert valid2d[b, :n].all() and not valid2d[b, n:].any()
-            ld = host.local_dst[b, :n]
-            assert ((ld >= 0) & (ld < host.block_v)).all()
-            # boundary tables tile the block's real slots exactly
-            ss = np.asarray(lay.seg_start)[b]
-            se = np.asarray(lay.seg_end)[b]
-            assert (se >= ss).all()
-            assert int((se - ss).sum()) == n
-            # slot runs agree with the membership table
-            for v in range(min(host.block_v,
-                               num_segments - b * host.block_v)):
-                run = np.arange(ss[v], se[v])
-                assert (ld[run] == v).all()
-
-    check()
-
-
-@pytest.mark.kernels
 def test_impl_selection_idiom():
     """The shared impl/interpret idiom: auto-interpret only on CPU backends,
     pallas_interpret always forces the interpreter, bad impls fail loudly."""
@@ -322,35 +74,3 @@ def test_impl_selection_idiom():
     assert resolve_interpret(False, "pallas") is False
     with pytest.raises(ValueError):
         check_impl("cuda")
-    with pytest.raises(ValueError):
-        SS.deliver(jnp.zeros((4,)), jnp.zeros((4,), jnp.int32), 2,
-                   impl="nope")
-
-
-@pytest.mark.kernels
-def test_build_hop_layout_invariants_deterministic():
-    """The same invariants over a fixed seed sweep, so the leg keeps its
-    teeth on hosts without the optional hypothesis dep."""
-    for seed, num_segments, n_edges, block_v in [
-        (0, 1, 0, None), (1, 7, 13, 16), (2, 200, 500, 64),
-        (3, 129, 128, None), (4, 64, 300, 16), (5, 33, 1, 64),
-    ]:
-        rng = np.random.default_rng(seed)
-        seg = np.sort(rng.integers(0, num_segments, n_edges)).astype(np.int32)
-        lay = HK.build_hop_layout(seg, num_segments, block_v=block_v,
-                                  block_e_mult=128)
-        host = lay.host
-        placed = np.sort(host.gather_idx[host.valid])
-        assert np.array_equal(placed, np.arange(n_edges))
-        valid2d = host.valid.reshape(host.n_blocks, host.block_e)
-        for b in range(host.n_blocks):
-            n = int(valid2d[b].sum())
-            assert valid2d[b, :n].all() and not valid2d[b, n:].any()
-            ld = host.local_dst[b, :n]
-            assert ((ld >= 0) & (ld < host.block_v)).all()
-            ss = np.asarray(lay.seg_start)[b]
-            se = np.asarray(lay.seg_end)[b]
-            assert (se >= ss).all() and int((se - ss).sum()) == n
-            for v in range(min(host.block_v,
-                               num_segments - b * host.block_v)):
-                assert (ld[np.arange(ss[v], se[v])] == v).all()

@@ -41,17 +41,14 @@ def _fields(r):
             ("minmax", r.minmax))
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("mode", C.ALL_MODES)
 @pytest.mark.parametrize("name", _case_names())
 def test_batched_sharded_serving_matches_vmap_simulation(
-        small_dynamic_graph, matrix, name, mode, impl):
+        small_dynamic_graph, matrix, name, mode):
     """One shard_map dispatch (batch × workers on the device mesh, p2p
     boundary exchange) ≡ the vmap-simulated single-device leg, bit for bit,
     for every matrix cell — served through the batch scheduler with zero
-    per-query fallbacks.  The ``impl`` axis reruns the leg with the fused
-    hop kernel inside the shard_map body (per-worker layout tables sharded
-    over the mesh like the partitioner's other padded tensors)."""
+    per-query fallbacks."""
     assert N_WORKERS % jax.device_count() == 0
     case = matrix[name]
     queries = C.perturbed_batch(case.qry, 3)
@@ -60,10 +57,9 @@ def test_batched_sharded_serving_matches_vmap_simulation(
         sched = BatchScheduler(small_dynamic_graph, engine="partitioned",
                                mode=mode, n_buckets=C.N_BUCKETS,
                                n_workers=N_WORKERS, keep_outputs=True,
-                               use_shard_map=use_shard_map, impl=impl)
+                               use_shard_map=use_shard_map)
         res = sched.run(queries)
         assert len(sched.last_dispatches) == 1, (name, mode, use_shard_map)
-        assert sched.last_dispatches[0].impl == impl
         return sched, res
 
     sched_sh, shard = serve(True)

@@ -187,7 +187,7 @@ def test_every_query_gets_one_complete_span_tree(medium_static_graph):
     probe = _fake_sched(medium_static_graph)
     sched = _fake_sched(
         medium_static_graph, tracer=tr, pad_batches=False,
-        admission=AdmissionPolicy(headroom=0.5, degrade_impls=(),
+        admission=AdmissionPolicy(headroom=0.5,
                                   allow_engine_downgrade=False),
         dispatcher=FakeDispatcher(
             service_model=planner_service_model(probe._planner.coeffs)))
@@ -279,7 +279,7 @@ def test_failed_group_seals_root_spans(medium_static_graph):
     wl = make_workload(medium_static_graph, templates=("Q2",),
                        n_per_template=2, seed=43)
     tr = Tracer(clock=StepClock())
-    fd = FakeDispatcher(fail=lambda queries, engine, impl: True)
+    fd = FakeDispatcher(fail=lambda queries, engine: True)
     sched = BatchScheduler(medium_static_graph, dispatcher=fd, tracer=tr)
     res = sched.run(wl)
     assert all(not r.ok for r in res)
@@ -326,45 +326,6 @@ def test_ladder_rung_admit_metrics_and_span(medium_static_graph):
                for r in admits)
 
 
-def test_ladder_rung_cheaper_impl(medium_static_graph):
-    """Rung 1: with θ_scatter_xla inflated, the pallas lowering is strictly
-    cheaper, and a deadline between the two costs degrades with exactly the
-    impl rung (quantum disabled)."""
-    wl = make_workload(medium_static_graph, templates=("Q2",),
-                       n_per_template=2, seed=46)
-    mx = MetricsRegistry()
-    tr = Tracer(clock=StepClock())
-    pol = AdmissionPolicy(headroom=1.0, degrade_impls=("pallas",),
-                          allow_engine_downgrade=False,
-                          degrade_max_batch=None)
-    fd = FakeDispatcher()
-    sched = BatchScheduler(medium_static_graph, dispatcher=fd, metrics=mx,
-                           tracer=tr, admission=pol)
-    sched._planner.coeffs["theta_scatter_xla"] = 10.0
-    qry = wl[0].qry
-    split = qry.n_vertices - 1
-    c_xla = sched._planner.estimate(qry, split, "xla").t_ms / 1e3
-    c_pal = sched._planner.estimate(qry, split, "pallas").t_ms / 1e3
-    assert c_pal < c_xla
-    decs = []
-    for inst in wl:
-        sched.admission.on_flush()
-        decs.append(sched.submit(inst, deadline_s=0.9 * c_xla, now=0.0))
-    assert all(d.action == "degrade" and d.rungs == ("impl=pallas",)
-               for d in decs)
-    adm = mx["granite_admission_total"]
-    assert adm.value(verdict="degrade", rung="impl=pallas") == 2
-    assert adm.value(verdict="admit", rung="") == 0
-    res = sched.flush()
-    assert all(r.ok for r in res)
-    assert all(c.impl == "pallas" for c in fd.calls)
-    admits = [r for r in tr.records() if r["name"] == "admit"]
-    assert all(r["attrs"]["verdict"] == "degrade"
-               and r["attrs"]["rungs"] == ["impl=pallas"] for r in admits)
-    disp = [r for r in tr.records() if r["name"] == "dispatch"]
-    assert all(r["attrs"]["impl"] == "pallas" for r in disp)
-
-
 def test_ladder_rung_engine_downgrade_with_quantum(medium_static_graph):
     """Rungs 2+3: dense→sliced with a bounded dispatch quantum — exact
     counter increments under the compound rung label, chunk sizes capped,
@@ -378,9 +339,9 @@ def test_ladder_rung_engine_downgrade_with_quantum(medium_static_graph):
                            dispatcher=fd, metrics=mx, tracer=tr)
     from repro.serving import AdmissionController
     probe_cost = sched._planner.estimate(
-        wl[0].qry, wl[0].qry.n_vertices - 1, "xla").t_ms / 1e3
+        wl[0].qry, wl[0].qry.n_vertices - 1).t_ms / 1e3
     sched.admission = AdmissionController(AdmissionPolicy(
-        headroom=1.0, degrade_impls=(), allow_engine_downgrade=True,
+        headroom=1.0, allow_engine_downgrade=True,
         sliced_discount=0.5, degrade_max_batch=2))
     decs = []
     for inst in wl:
@@ -411,7 +372,7 @@ def test_ladder_rung_reject(medium_static_graph):
     tr = Tracer(clock=StepClock())
     sched = _fake_sched(medium_static_graph, metrics=mx, tracer=tr,
                         admission=AdmissionPolicy(
-                            headroom=1.0, degrade_impls=(),
+                            headroom=1.0,
                             allow_engine_downgrade=False))
     for inst in wl:
         dec = sched.submit(inst, deadline_s=0.0, now=0.0)
@@ -871,7 +832,7 @@ def test_dense_hop_delivery_does_not_scatter(small_static_graph, agg):
         E._prepare_gdev(g), params, be).compile().as_text()
     assert "hop_deliver" in dense
     assert _hop_deliver_scatters(dense) == []
-    gd, pd, _, _ = EP.device_tables(g, 2)
+    gd, pd, _ = EP.device_tables(g, 2)
     part = EP.batch_executable(g, q, split, n_workers=2).fn.lower(
         gd, pd, params, be).compile().as_text()
     assert _hop_deliver_scatters(part)

@@ -104,3 +104,40 @@ def test_cost_model_discriminates(medium_static_graph, planner):
         # allow ties within noise: chosen must be within 2x of best
         assert times[chosen] <= max(2.0 * times[best], times[worst] * 0.999), (
             inst.template, chosen, times)
+
+
+#: (split, batch t_ms) that ``choose_batch`` gave for each template's batch
+#: below with the default coefficients before the hop-delivery lowering
+#: lost its cost columns; the estimates and the splits did not move
+BATCH_PLANS = {
+    "Q1": (1, 4.125541661651531), "Q2": (1, 3.4168502371541507),
+    "Q3": (1, 6.085907083333334), "Q4": (2, 12.952839802742005),
+    "Q5": (2, 5.83985624749027), "Q6": (2, 7.18348),
+    "Q7": (2, 10.203591509360567), "Q2-min": (0, 4.2971657996617205),
+}
+
+
+@pytest.mark.parametrize("template", sorted(BATCH_PLANS))
+def test_batch_plan_unchanged(medium_static_graph, stats, template):
+    """A batch of four instances of each served template (and the MIN
+    variant of Q2) plans to the recorded split at the recorded cost, and
+    every candidate's cost is its feature row dotted with θ."""
+    from repro.core.planner import DEFAULT_COEFFS, coeff_vector
+    from repro.graphdata.queries import to_minmax
+
+    g = medium_static_graph
+    base = template.split("-")[0]
+    wl = make_workload(g, templates=(base,), n_per_template=4, seed=3)
+    if template.endswith("-min"):
+        wl = [to_minmax(inst, g) for inst in wl]
+    planner = Planner(g, stats, coeffs=dict(DEFAULT_COEFFS))
+    est = planner.choose_batch([inst.qry for inst in wl])
+    split, t_ms = BATCH_PLANS[template]
+    assert est.split == split
+    assert est.t_ms == pytest.approx(t_ms, rel=1e-12, abs=0.0)
+    theta = coeff_vector(planner.coeffs)
+    assert [c["split"] for c in est.candidates] == \
+        planner.enumerate_plans(wl[0].qry)
+    for c in est.candidates:
+        assert c["t_ms"] == pytest.approx(float(c["features"] @ theta),
+                                          rel=1e-12)

@@ -84,10 +84,10 @@ def test_mixed_deadline_and_plain_submissions(medium_static_graph):
 # ------------------------------------------------------------- admission
 def _plain_cost_s(sched, qry):
     """What the admission controller predicts for one query at the default
-    plan (no cached batch plan yet): default split, fixed impl."""
+    plan (no cached batch plan yet): the default split."""
     split = 0 if qry.agg_op != Q.AGG_NONE else qry.n_vertices - 1
     return sched._planner_for(sched._engine_for(qry)).estimate(
-        qry, split, sched.impl).t_ms / 1e3
+        qry, split).t_ms / 1e3
 
 
 def test_admission_admit_then_reject_on_backlog(medium_static_graph):
@@ -100,7 +100,7 @@ def test_admission_admit_then_reject_on_backlog(medium_static_graph):
     cost = _plain_cost_s(probe, wl[0].qry)
     # deadline fits ~3 predicted service times (headroom 1.0 for exactness)
     rel = 3.49 * cost
-    pol = AdmissionPolicy(headroom=1.0, degrade_impls=(),
+    pol = AdmissionPolicy(headroom=1.0,
                           allow_engine_downgrade=False)
     sched = _sched(medium_static_graph, admission=pol)
     actions = [sched.submit(inst, deadline_s=rel, now=0.0).action
@@ -128,7 +128,7 @@ def test_admission_degrades_to_sliced_with_bounded_chunks(
     fd = FakeDispatcher()
     sched = BatchScheduler(medium_static_graph, engine="dense", dispatcher=fd)
     cost = _plain_cost_s(sched, wl[0].qry)
-    pol = AdmissionPolicy(headroom=1.0, degrade_impls=(),
+    pol = AdmissionPolicy(headroom=1.0,
                           allow_engine_downgrade=True, sliced_discount=0.5,
                           degrade_max_batch=2)
     sched.admission = AdmissionController(pol)
@@ -179,7 +179,7 @@ def test_max_backlog_cap(medium_static_graph):
     probe = _sched(medium_static_graph)
     cost = _plain_cost_s(probe, wl[0].qry)
     pol = AdmissionPolicy(headroom=1.0, max_backlog_s=2.5 * cost,
-                          degrade_impls=(), allow_engine_downgrade=False)
+                          allow_engine_downgrade=False)
     sched = _sched(medium_static_graph, admission=pol)
     actions = [sched.submit(inst, deadline_s=600.0, now=0.0).action
                for inst in wl]
@@ -279,7 +279,7 @@ def test_replay_failed_group_not_counted_completed(medium_static_graph):
     NaN latency, and depress the completion rate."""
     wl = make_workload(medium_static_graph, templates=("Q2", "Q4"),
                        n_per_template=3, seed=15)
-    fd = FakeDispatcher(fail=lambda queries, engine, impl:
+    fd = FakeDispatcher(fail=lambda queries, engine:
                         queries[0].n_vertices == wl[-1].qry.n_vertices)
     sched = BatchScheduler(medium_static_graph, dispatcher=fd)
     rep = replay_workload(sched, wl, rate_qps=1000.0, seed=16)
@@ -372,7 +372,7 @@ def test_admission_holds_deadlines_under_overload(medium_static_graph):
                                mode="open", deadline_s=deadline)
 
     plain = run(None)
-    slo = run(AdmissionPolicy(headroom=0.5, degrade_impls=(),
+    slo = run(AdmissionPolicy(headroom=0.5,
                               allow_engine_downgrade=False))
     assert plain.deadline_hit_rate < 0.5          # overload: open loop sinks
     assert slo.n_rejected > 0 and slo.reject_rate > 0
@@ -397,7 +397,7 @@ def test_replay_rejected_queries_excluded(medium_static_graph):
     fd = FakeDispatcher(service_model=planner_service_model(
         probe._planner.coeffs))
     sched = _sched(medium_static_graph, dispatcher=fd, pad_batches=False,
-                   admission=AdmissionPolicy(headroom=1.0, degrade_impls=(),
+                   admission=AdmissionPolicy(headroom=1.0,
                                              allow_engine_downgrade=False))
     rep = replay_workload(sched, wl, rate_qps=10.0 / c, seed=26, mode="open",
                           deadline_s=2.0 * c)
@@ -419,7 +419,7 @@ def test_closed_loop_with_admission_frees_rejected_slots(
     wl = make_workload(medium_static_graph, templates=("Q2",),
                        n_per_template=6, seed=31)
     sched = _sched(medium_static_graph, admission=AdmissionPolicy(
-        headroom=1.0, degrade_impls=(), allow_engine_downgrade=False))
+        headroom=1.0, allow_engine_downgrade=False))
     rep = replay_workload(sched, wl, mode="closed", max_outstanding=2,
                           deadline_s=0.0)
     assert rep.n_rejected == len(wl) and rep.n_completed == 0
@@ -458,7 +458,7 @@ def test_slo_scheduler_bit_identical_answers(small_static_graph):
     probe = BatchScheduler(small_static_graph, engine="dense")
     deg_sched = BatchScheduler(
         small_static_graph, engine="dense", keep_outputs=True,
-        admission=AdmissionPolicy(headroom=1.0, degrade_impls=(),
+        admission=AdmissionPolicy(headroom=1.0,
                                   allow_engine_downgrade=True,
                                   sliced_discount=0.25,
                                   degrade_max_batch=None))

@@ -257,3 +257,174 @@ def test_deliver_ptr_lowerings_match_numpy(lengths, data):
                                   op, ptr=jnp.asarray(ptr))
         assert np.array_equal(np.asarray(got),
                               _numpy_extremum(vals, seg, nseg, op)), op
+
+
+# -------------------------------------------------------------------------
+# the whole hop: gather → apply_edge → deliver, with the extremum channel
+# (minmax_edge → deliver_extremum), on the ptr lowering the dense and
+# sliced executors run, against the scatter lowering and numpy
+# -------------------------------------------------------------------------
+HOP_B = 6
+HOP_BEDGES = jnp.asarray(iv.bucket_edges(0, 960, HOP_B))
+MODES = {"static": SS.MODE_STATIC, "bucket": SS.MODE_BUCKET,
+         "interval": SS.MODE_INTERVAL}
+AGGS = {"count": None, "min": Q.AGG_MIN, "max": Q.AGG_MAX}
+
+
+def _hop_problem(kind: str, mode: int, seed: int = 0):
+    """One hop with INTEGER counts (the engine's invariant).  ``random``:
+    97 vertices, 900 arrival-sorted edges, random sources.  ``sentinel``:
+    every fifth edge reads the appended zero row (source ``V``), and no
+    edge arrives at the last 10 vertices.  Returns (state rows incl. the
+    zero row, t_src, t_dst, ptr, wmask, evalid, extremum channel, V)."""
+    rng = np.random.default_rng(seed)
+    V, E = (97, 900) if kind == "random" else (40, 50)
+    hi = V if kind == "random" else V - 10
+    t_dst = np.sort(rng.integers(0, hi, E)).astype(np.int32)
+    t_src = rng.integers(0, V, E).astype(np.int32)
+    if kind == "sentinel":
+        t_src[::5] = V
+    ptr = np.searchsorted(t_dst, np.arange(V + 1)).astype(np.int32)
+    wmask = rng.random(E) < 0.6
+    if mode == SS.MODE_STATIC:
+        state, evalid = rng.integers(0, 9, V).astype(np.float32), None
+    elif mode == SS.MODE_BUCKET:
+        state = rng.integers(0, 9, (V, HOP_B)).astype(np.float32)
+        evalid = rng.random((E, HOP_B)) < 0.7
+    else:
+        state = rng.integers(0, 4, (V, HOP_B, HOP_B + 1)).astype(np.float32)
+        evalid = np.sort(rng.integers(0, 960, (E, 2)), axis=1).astype(
+            np.int32)
+    state = np.concatenate([state, np.zeros((1,) + state.shape[1:],
+                                            np.float32)])
+    mch = np.concatenate([rng.random(V).astype(np.float32), [0.5]])
+    return (state, t_src, t_dst, ptr, wmask, evalid,
+            mch.astype(np.float32), V)
+
+
+def _hop(state, t_src, t_dst, wmask, evalid, mch, V, mode, op, ptr):
+    """One hop as the executors run it; ``ptr=None`` is the scatter."""
+    with SS.bucket_scope(HOP_BEDGES):
+        cnt = SS.apply_edge(state[t_src], wmask, evalid, mode)
+        arr = SS.deliver(cnt, t_dst, V, ptr=ptr)
+        if op is None:
+            return cnt, arr, None
+        m_e = SS.minmax_edge(mch[t_src], cnt, op, mode)
+        return cnt, arr, SS.deliver_extremum(m_e, t_dst, V, op, ptr=ptr)
+
+
+def _hop_fns(t_src, t_dst, ptr, V, mode, op):
+    ts, td, pj = jnp.asarray(t_src), jnp.asarray(t_dst), jnp.asarray(ptr)
+    by_ptr = jax.jit(lambda s, w, e, m: _hop(s, ts, td, w, e, m, V, mode,
+                                             op, pj))
+    by_scatter = jax.jit(lambda s, w, e, m: _hop(s, ts, td, w, e, m, V, mode,
+                                                 op, None))
+    return by_ptr, by_scatter
+
+
+def _numpy_hop(cnt, state, t_src, t_dst, wmask, evalid, mch, V, mode, op):
+    """numpy delivery of the per-edge counts ``cnt`` (which it recomputes
+    itself in the static and bucket modes) and of the extremum channel."""
+    sv = state[t_src]
+    if mode == SS.MODE_STATIC:
+        np.testing.assert_array_equal(cnt, sv * wmask)
+    elif mode == SS.MODE_BUCKET:
+        np.testing.assert_array_equal(cnt, sv * (wmask[:, None] & evalid))
+    arr = _numpy_sum(cnt, t_dst, V)
+    if op is None:
+        return arr, None
+    alive = cnt.reshape(len(cnt), -1).sum(axis=1) > 0
+    m_e = np.where(alive, mch[t_src], np.asarray(SS.minmax_neutral(op)))
+    return arr, _numpy_extremum(m_e.astype(np.float32), t_dst, V, op)
+
+
+@pytest.mark.parametrize("problem", ["random", "sentinel"])
+@pytest.mark.parametrize("agg", sorted(AGGS))
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_hop_ptr_vs_scatter(mode, agg, problem):
+    """Prefix differences and the segmented scan deliver the same hop as
+    the segment-sum/min/max scatters and as numpy, bit for bit, per
+    temporal mode and aggregate; zero-row sources contribute nothing and
+    edgeless destinations get exact zeros and the neutral extremum."""
+    md, op = MODES[mode], AGGS[agg]
+    state, t_src, t_dst, ptr, wmask, evalid, mch, V = _hop_problem(
+        problem, md)
+    by_ptr, by_scatter = _hop_fns(t_src, t_dst, ptr, V, md, op)
+    args = (jnp.asarray(state), jnp.asarray(wmask),
+            None if evalid is None else jnp.asarray(evalid),
+            jnp.asarray(mch))
+    cnt, arr, mm = (None if x is None else np.asarray(x)
+                    for x in by_ptr(*args))
+    _, arr_s, mm_s = by_scatter(*args)
+    want, want_m = _numpy_hop(cnt, state, t_src, t_dst, wmask, evalid, mch,
+                              V, md, op)
+    assert np.array_equal(arr, want)
+    assert np.array_equal(arr, np.asarray(arr_s))
+    if op is not None:
+        assert np.array_equal(mm, want_m)
+        assert np.array_equal(mm, np.asarray(mm_s))
+    if problem == "sentinel":
+        assert (cnt[::5] == 0).all()
+        assert (arr[V - 10:] == 0).all()
+        if op is not None:
+            assert (mm[V - 10:] == np.asarray(SS.minmax_neutral(op))).all()
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_hop_ptr_batched_pad_rows(mode):
+    """Under the executors' batch vmap (8 rows, the last three all-zero
+    padding) each row equals its own unbatched hop, and the pad rows
+    deliver zeros and the neutral extremum."""
+    md, op = MODES[mode], Q.AGG_MIN
+    rows = [_hop_problem("random", md, seed=s) for s in range(5)]
+    _, t_src, t_dst, ptr, _, _, _, V = rows[0]
+    by_ptr, _ = _hop_fns(t_src, t_dst, ptr, V, md, op)
+    state = np.stack([r[0] for r in rows] + [np.zeros_like(rows[0][0])] * 3)
+    wmask = np.stack([r[4] for r in rows] + [rows[0][4]] * 3)
+    evalid = (None if md == SS.MODE_STATIC
+              else np.stack([r[5] for r in rows] + [rows[0][5]] * 3))
+    mch = np.stack([r[6] for r in rows] + [rows[0][6]] * 3)
+    batched = jax.vmap(by_ptr, in_axes=(0, 0, None if evalid is None else 0,
+                                        0))
+    _, arr, mm = batched(jnp.asarray(state), jnp.asarray(wmask),
+                         None if evalid is None else jnp.asarray(evalid),
+                         jnp.asarray(mch))
+    arr, mm = np.asarray(arr), np.asarray(mm)
+    for i in range(8):
+        _, a, m = by_ptr(jnp.asarray(state[i]), jnp.asarray(wmask[i]),
+                         None if evalid is None else jnp.asarray(evalid[i]),
+                         jnp.asarray(mch[i]))
+        assert np.array_equal(arr[i], np.asarray(a)), i
+        assert np.array_equal(mm[i], np.asarray(m)), i
+    assert (arr[5:] == 0).all()
+    assert (mm[5:] == np.asarray(SS.minmax_neutral(op))).all()
+    assert arr[:5].any()
+
+
+@pytest.mark.parametrize("channel", ["sum", "extremum"])
+@pytest.mark.parametrize("E,V,C", [(1000, 100, 8), (5000, 700, 16),
+                                   (300, 512, 4)])
+def test_deliver_ptr_width_sweep(E, V, C, channel):
+    """The ptr lowerings at wide channel counts and sparse or dense
+    segment maps: C columns of per-edge values over E edges sorted into V
+    segments, against numpy and the scatter."""
+    rng = np.random.default_rng(E + V + C)
+    seg = np.sort(rng.integers(0, V, E)).astype(np.int32)
+    ptr = np.searchsorted(seg, np.arange(V + 1)).astype(np.int32)
+    seg_j, ptr_j = jnp.asarray(seg), jnp.asarray(ptr)
+    if channel == "sum":
+        vals = rng.integers(0, 50, (E, C)).astype(np.float32)
+        got = SS.deliver(jnp.asarray(vals), seg_j, V, ptr=ptr_j)
+        scatter = SS.deliver(jnp.asarray(vals), seg_j, V)
+        want = _numpy_sum(vals, seg, V)
+    else:
+        vals = rng.normal(size=(E, C)).astype(np.float32)
+        col = lambda p: jax.vmap(  # noqa: E731
+            lambda m: SS.deliver_extremum(m, seg_j, V, Q.AGG_MIN, ptr=p),
+            in_axes=1, out_axes=1)
+        got = col(ptr_j)(jnp.asarray(vals))
+        scatter = col(None)(jnp.asarray(vals))
+        want = np.stack([_numpy_extremum(vals[:, c], seg, V, Q.AGG_MIN)
+                         for c in range(C)], axis=1)
+    assert np.array_equal(np.asarray(got), want)
+    assert np.array_equal(np.asarray(scatter), want)

@@ -12,9 +12,7 @@ memory) shows up here at no chip time:
       template that needs the most memory;
   (c) the partitioned executable over four described devices at 1k
       persons, whose compiled text holds the boundary exchange's
-      all-to-all;
-  (d) the fused hop kernel at a VMEM-shaped block, which does not compile
-      for the TPU yet.
+      all-to-all.
 
 Shapes for (a) and (b) come from a small proxy graph whose vertex and
 traversal-edge axes and property-slot counts are widened to those of
@@ -28,7 +26,6 @@ describing it loads the TPU library, which only one process may hold.
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
@@ -39,8 +36,6 @@ from repro.core import engine_partitioned as EP
 from repro.core import intervals as iv
 from repro.graphdata.ldbc import LdbcParams, generate_ldbc
 from repro.graphdata.queries import make_workload
-from repro.kernels.hop_scatter import fused_hop_cols_pallas
-from repro.kernels.hop_scatter.ops import PROBE_BLOCK_E, PROBE_BLOCK_V
 from repro.serving.compile import compile_plan_tensor
 
 HBM_BYTES = 16 * 2**30      # one v5e chip
@@ -121,7 +116,7 @@ def _dense_compile(full: dict, template: str, mode: int, sharding):
                         seed=0)[0].qry
     split = 0 if qry.agg_op != -1 else qry.n_vertices - 1
     fn = E.batch_executable(proxy, qry, split, mode, N_BUCKETS,
-                            sliced=False, impl="xla").fn
+                            sliced=False).fn
     gdev = widened(E._prepare_gdev(proxy), proxy, full, sharding)
     params, bedges = _batch_args(proxy, qry, sharding)
     return fn.lower(gdev, params, bedges).compile()
@@ -158,7 +153,7 @@ def test_partitioned_four_devices(topo, monkeypatch):
     proxy = _proxy(False)
     qry = make_workload(proxy, templates=("Q7",), n_per_template=1,
                         seed=0)[0].qry
-    gdev, pdev, _, _ = EP.device_tables(proxy, 4)
+    gdev, pdev, _ = EP.device_tables(proxy, 4)
     rep, wrk = NamedSharding(mesh, P()), NamedSharding(mesh, P("workers"))
     sds = lambda tree, sh: jax.tree_util.tree_map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
@@ -169,18 +164,3 @@ def test_partitioned_four_devices(topo, monkeypatch):
                         bedges).compile()
     _fits(compiled)
     assert "all-to-all" in compiled.as_text()
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "the fused hop kernel does not compile for the TPU: its one-row slot "
-    "blocks break the (8, 128) block rule, the in-kernel row gather is "
-    "refused, and cumsum has no Pallas TPU lowering (ROADMAP 1d)"))
-def test_hop_kernel_vmem_block(one_chip):
-    """(d) the fused hop kernel at a VMEM-shaped block."""
-    nb, bv, be = 4, PROBE_BLOCK_V, PROBE_BLOCK_E
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, dt, sharding=one_chip)
-    i32, f32 = jnp.int32, jnp.float32
-    jax.jit(lambda *a: fused_hop_cols_pallas(*a, block_v=bv)).lower(
-        sds((nb * bv + 1, 1), f32), sds((nb, be), i32), sds((nb, be, 1), f32),
-        sds((nb, bv), i32), sds((nb, bv), i32), sds((nb, be), i32)).compile()
